@@ -63,7 +63,8 @@ def run():
                  f";flops_ratio={c1.flops(P, solver='bcd')/ck.flops(P, solver='bcd'):.3f}")
 
     # --- structural HLO verification ---------------------------------------
-    env = dict(os.environ,
+    # the child runs on 8 host CPUs: the parent may hold the accelerator
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_SUB)],

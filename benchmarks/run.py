@@ -23,6 +23,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "fig2_convergence_b": "benchmarks.convergence_b",
@@ -74,6 +75,7 @@ def main(argv=None) -> None:
                     help="write per-suite BENCH_<suite>.json artifacts here "
                          "(always, with an error-sentinel row on failure)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     del common.RECORDS[:]        # main() is reentrant: one run, one trajectory
     picked = set(args.only.split(",")) if args.only else set(SUITES)
     unknown = picked - set(SUITES)

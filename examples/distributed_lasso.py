@@ -1,7 +1,8 @@
 """Distributed CA solvers exactly as the paper runs them (Algorithm V): X
 column-partitioned over processors, per-processor sampling, one Gram
 all-reduce every k iterations — plus the PDHG and BCD pairs through the
-same shard_map path. Runs on 8 simulated devices.
+same shard_map path. Runs on every device JAX finds: the chips of a TPU
+host, or 8 simulated CPU devices elsewhere.
 
   PYTHONPATH=src python examples/distributed_lasso.py
 """
@@ -23,7 +24,7 @@ from repro.roofline.hlo_cost import analyze_hlo
 
 def main():
     problem, _ = make_dataset_like("covtype", scale=0.05)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
     print(f"mesh: {mesh.shape}  problem: d={problem.d} n={problem.n}")
 
     Xs, ys = shard_problem(mesh, problem.X, problem.y)

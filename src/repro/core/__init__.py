@@ -39,7 +39,7 @@ from repro.core.bcd import bcd, ca_bcd
 from repro.core.distributed import make_distributed_solver
 from repro.core.cost_model import CostModel, MachineParams
 from repro.core.convergence import (relative_solution_error, solve_reference,
-                                    composite_reference)
+                                    composite_reference, lasso_reference_f64)
 
 __all__ = [
     "LassoProblem", "ElasticNetProblem", "DualSVMProblem", "SolverConfig",
@@ -49,4 +49,5 @@ __all__ = [
     "pdhg", "ca_pdhg", "bcd", "ca_bcd",
     "make_distributed_solver", "CostModel", "MachineParams",
     "relative_solution_error", "solve_reference", "composite_reference",
+    "lasso_reference_f64",
 ]

@@ -12,6 +12,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.soft_threshold import fista_momentum, prox_elem
 
@@ -53,6 +54,23 @@ def composite_reference(problem, iters: int = 4000, step_size=None):
 def solve_reference(problem, iters: int = 4000):
     """High-accuracy solution w_opt (the TFOCS stand-in)."""
     return composite_reference(problem, iters=iters)
+
+
+def lasso_reference_f64(problem, iters: int = 4000) -> np.ndarray:
+    """w_opt of a LASSO problem by full-batch FISTA in float64 NumPy on the
+    host: independent of the solvers' code and of the device's matmul
+    precision (a TPU multiplies f32 in bf16 passes unless told otherwise)."""
+    X = np.asarray(problem.X, np.float64)
+    y = np.asarray(problem.y, np.float64)
+    G, R = X @ X.T / X.shape[1], X @ y / X.shape[1]
+    t = 1.0 / np.linalg.eigvalsh(G)[-1]
+    w_prev = w = np.zeros(G.shape[0])
+    for j in range(1, iters + 1):
+        z = w + max((j - 2.0) / j, 0.0) * (w - w_prev)
+        u = z - t * (G @ z - R)
+        w_prev, w = w, np.sign(u) * np.maximum(np.abs(u) - problem.lam * t,
+                                               0.0)
+    return w
 
 
 def relative_solution_error(w, w_opt):

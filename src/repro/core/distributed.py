@@ -33,7 +33,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.problem import SolverConfig
 from repro.core.sampling import sample_index_batch
@@ -67,17 +66,16 @@ def _gram_local_solver(algorithm: str, cfg: SolverConfig, lam: float,
     init = init_pdhg_state if rule == "pdhg" else init_state
 
     def solve_local(X_local, y_local, w0, t, key):
-        from repro.dist.compat import axis_size
         d, n_local = X_local.shape
         m_local = max(int(cfg.b * n_local), 1)
         # Per-shard independent draws: fold the shard's linear index into key.
         idx_lin = jnp.int32(0)
         for ax in data_axes:
-            idx_lin = idx_lin * axis_size(ax) + jax.lax.axis_index(ax)
+            idx_lin = idx_lin * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         key = jax.random.fold_in(key, idx_lin)
         n_shards = 1
         for ax in data_axes:
-            n_shards *= axis_size(ax)
+            n_shards *= jax.lax.axis_size(ax)
         m_global = m_local * n_shards  # union of per-shard draws
         idx = sample_index_batch(key, cfg.T, n_local, m_local,
                                  cfg.with_replacement)
@@ -125,11 +123,10 @@ def _coord_local_solver(algorithm: str, cfg: SolverConfig, lam: float,
     blk = cfg.k if algorithm.startswith("ca_") else 1
 
     def solve_local(X_local, y_local, w0, t, key):
-        from repro.dist.compat import axis_size
         d, n_local = X_local.shape
         n_shards = 1
         for ax in data_axes:
-            n_shards *= axis_size(ax)
+            n_shards *= jax.lax.axis_size(ax)
         inv_rho = 1.0 / (n_local * n_shards)
         m_c = max(int(cfg.b * d), 1)
         # SHARED draws: every shard must update the same coordinates, so the
@@ -203,11 +200,11 @@ def make_distributed_solver(algorithm: str, mesh: Mesh, cfg: SolverConfig,
     spec_y = P(data_axes)
     rep = P()
 
-    solve = shard_map(
+    solve = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_X, spec_y, rep, rep, rep),
         out_specs=rep,
-        check_rep=False,
+        check_vma=False,
     )
     # Like the step builders in launch/steps.py, pin the registry backend at
     # build time: the trace runs under it, so the jitted solver cannot

@@ -24,12 +24,12 @@ from repro.core.problem import LassoProblem
 # LASSO problems (paper Table II stand-ins)
 # ---------------------------------------------------------------------------
 
-#: name -> (d features, n samples, lambda) mirroring the paper's datasets.
-#: Sizes are scaled for CPU CI; the generator accepts overrides for full size.
+#: name -> (d features, n samples, lambda) at the published sizes of the
+#: paper's datasets (Table II); callers cut n with ``scale``.
 PAPER_DATASETS = {
     "abalone": dict(d=8, n=4177, lam=0.1),
-    "covtype": dict(d=54, n=58_101, lam=0.01),   # 1/10 covtype rows for CI
-    "susy": dict(d=18, n=100_000, lam=0.01),     # subsampled susy for CI
+    "covtype": dict(d=54, n=581_012, lam=0.01),
+    "susy": dict(d=18, n=5_000_000, lam=0.01),
 }
 
 
@@ -54,7 +54,8 @@ def make_lasso_data(key: jax.Array, d: int, n: int, sparsity: float = 0.25,
 
 def make_dataset_like(name: str, key: Optional[jax.Array] = None,
                       scale: float = 1.0):
-    """A synthetic problem with the shape/lambda of a paper dataset."""
+    """A synthetic problem with the shape/lambda of a paper dataset, cut to
+    ``scale`` of its published sample count."""
     spec = PAPER_DATASETS[name]
     if key is None:
         # stable digest, NOT hash(): str hashing is salted per process, which

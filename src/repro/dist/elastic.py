@@ -16,8 +16,6 @@ import numpy as np
 
 from jax.sharding import Mesh
 
-from repro.dist.compat import spoof_mesh  # noqa: F401  (re-export for tests)
-
 
 def largest_mesh_shape(n_devices: int, model_size: int) -> Tuple[int, int]:
     """Largest (data, model) shape on ``n_devices`` that keeps the model axis.

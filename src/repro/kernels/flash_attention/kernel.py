@@ -92,9 +92,10 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
 def _paged_body(table_ref, valid_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                 o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                page_size: int, rows: int, pages: int):
+                page_size: int, pages: int):
     del table_ref                            # consumed by the index maps
     b = pl.program_id(0)
+    h = pl.program_id(1)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -105,20 +106,27 @@ def _paged_body(table_ref, valid_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     valid = valid_ref[b]
 
+    def head_scale(s_ref):
+        # the scale block holds every kv head of the page (rows, Hkv): pick
+        # this program's head column with a lane mask -> (rows, 1)
+        sc = s_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        return jnp.sum(jnp.where(lane == h, sc, 0.0), axis=1, keepdims=True)
+
     # Page pruning: pages whose first position is past this row's valid
     # length are never fetched into the softmax (their table entries may be
     # 0, the pool's scratch page — masked to exact zero weight regardless).
     @pl.when(ik * page_size < valid)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # (1, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (rows, d)
+        q = q_ref[0, 0].astype(jnp.float32)               # (group, d)
+        k = k_ref[0].astype(jnp.float32)                  # (rows, d)
         if ks_ref is not None:
             # int8 pool: per-(row, head) dequant rides the same
             # scalar-prefetched page address as the codes it scales
-            k = k * ks_ref[0, :, 0][:, None]
+            k = k * head_scale(ks_ref)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        j = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # rows >= page_size (sublane pad): mask both the pad rows and the
         # positions past the row's decode depth
         s = jnp.where((j < page_size) & (ik * page_size + j < valid),
@@ -126,12 +134,12 @@ def _paged_body(table_ref, valid_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                            # (1, rows)
+        p = jnp.exp(s - m_new)                            # (group, rows)
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
         if vs_ref is not None:
-            v = v * vs_ref[0, :, 0][:, None]
+            v = v * head_scale(vs_ref)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + pv
@@ -139,7 +147,7 @@ def _paged_body(table_ref, valid_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     @pl.when(ik == pages - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _paged_kernel(table_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
@@ -162,55 +170,62 @@ def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
     """Decode attention through a scalar-prefetched page table.
 
     q (B,Hq,D); pools (num_pages, rows, Hkv, D) with rows >= page_size
-    (sublane pad); table (B, npages) int32; valid (B,) int32. The table and
-    valid vector ride the scalar-prefetch lane so the k/v BlockSpec index
-    maps can compute HBM page addresses before the body runs — the gather
-    never materialises in HBM.
+    (sublane pad) and D a lane multiple; table (B, npages) int32; valid (B,)
+    int32. The table and valid vector ride the scalar-prefetch lane so the
+    k/v BlockSpec index maps can compute HBM page addresses before the body
+    runs — the gather never materialises in HBM.
+
+    Grid (B, Hkv, npages): one program serves all ``Hq/Hkv`` query heads of
+    a kv head, so each page is read once per kv head. The pools are viewed
+    as (num_pages, rows, Hkv*D) — a free reshape — so a K/V block is one
+    head's (rows, D) tile of a page, which the TPU tiles natively.
 
     Quantized pools additionally pass ``k_scale``/``v_scale``
-    (num_pages, rows, Hkv) f32; the scale tiles ride the same prefetched
-    page addresses and dequantization happens in-register before the MXU.
+    (num_pages, rows, Hkv) f32; a page's whole scale tile rides the same
+    prefetched page address and dequantization happens in-register before
+    the MXU.
     """
     B, Hq, D = q.shape
-    rows, Hkv = k_pool.shape[1], k_pool.shape[2]
+    num_pages, rows, Hkv = k_pool.shape[:3]
     group = Hq // Hkv
     npages = table.shape[1]
     quant = k_scale is not None
 
     body = _paged_kernel_quant if quant else _paged_kernel
     kernel = functools.partial(body, scale=scale, page_size=page_size,
-                               rows=rows, pages=npages)
+                               pages=npages)
     # index maps receive (*grid_indices, *scalar_prefetch_refs)
-    kv_spec = pl.BlockSpec(
-        (1, rows, 1, D), lambda b, h, ik, t, n: (t[b, ik], 0, h // group, 0))
-    scale_spec = pl.BlockSpec(
-        (1, rows, 1), lambda b, h, ik, t, n: (t[b, ik], 0, h // group))
-    in_specs = [
-        pl.BlockSpec((1, 1, D), lambda b, h, ik, t, n: (b, h, 0)),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [q, k_pool, v_pool]
+    q_spec = pl.BlockSpec((1, 1, group, D),
+                          lambda b, h, ik, t, n: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((1, rows, D),
+                           lambda b, h, ik, t, n: (t[b, ik], 0, h))
+    scale_spec = pl.BlockSpec((1, rows, Hkv),
+                              lambda b, h, ik, t, n: (t[b, ik], 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [q.reshape(B, Hkv, group, D),
+                k_pool.reshape(num_pages, rows, Hkv * D),
+                v_pool.reshape(num_pages, rows, Hkv * D)]
     if quant:
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hq, npages),
+        grid=(B, Hkv, npages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, ik, t, n: (b, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),    # acc
-            pltpu.VMEM((1, 1), jnp.float32),    # running max m
-            pltpu.VMEM((1, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((group, D), jnp.float32),    # acc
+            pltpu.VMEM((group, 1), jnp.float32),    # running max m
+            pltpu.VMEM((group, 1), jnp.float32),    # running denom l
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
     )(table, valid, *operands)
+    return out.reshape(B, Hq, D)
 
 
 @functools.partial(jax.jit, static_argnames=(

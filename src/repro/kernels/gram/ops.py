@@ -57,12 +57,12 @@ def _make_inputs(shape, dtype=jnp.float32):
 
 
 def _candidates(backend, shape):
+    # the (bd, bd) output tile must be lane-aligned (bd % 128 == 0) or span
+    # the whole padded d, so the only feature tile is the default one
     if backend != "pallas":
         return []
     d, m = shape
-    return [dict(bd=bd, bm=bm)
-            for bd in (8, 32, 128) if bd <= pad.round_up(d, 8)
-            for bm in (128, 512) if bm <= pad.round_up(m, 128)]
+    return [dict(bm=bm) for bm in (128, 512) if bm <= pad.round_up(m, 128)]
 
 
 registry.describe("gram", shape_of=lambda Xs, **kw: tuple(Xs.shape),

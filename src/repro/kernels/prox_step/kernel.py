@@ -11,9 +11,9 @@ Layout: vectors are (d, 1) tiles (TPU needs >=2D); the scalar parameters ride
 as one (5, 1) tile ``[t; lam; mu; lo; hi]``; the element-wise prox ``variant``
 is a static kernel parameter, so each variant compiles its own branch-free
 body (see prox_step/ref.py for the variant table). The full H (d x d fp32)
-must fit VMEM => d <= ~1800 (ops.py falls back to the XLA path above that —
-the paper's d is 8..54, linear probes go to ~1k). With grid=() the default
-BlockSpec maps whole operands into VMEM, which is exactly the intent.
+must fit VMEM => d <= ops.VMEM_MAX_D (the registry routes larger d to the
+XLA path — the paper's d is 8..54, linear probes go to ~1k). With grid=() the
+default BlockSpec maps whole operands into VMEM, which is exactly the intent.
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """Public wrappers for the fused prox kernels: shape adaptation ((d,) vectors
--> (d,1) tiles), VMEM-fit dispatch, XLA fallback for large d.
+-> (d,1) tiles) and registration.
 
 Registers the ``prox_step`` / ``prox_loop`` ops: ``pallas`` keeps the Gram
 VMEM-resident across the fused update(s) (per-call ``supports`` rejects
@@ -32,8 +32,10 @@ from repro.kernels import registry
 from repro.kernels.prox_step import kernel as _k
 from repro.kernels.prox_step import ref as _ref
 
-#: fp32 Gram + vectors must fit v5e VMEM (16 MiB): d^2*4 <~ 13 MiB.
-VMEM_MAX_D = 1792
+#: largest d whose fused loop fits v5e VMEM: the (d, d) f32 Gram plus the
+#: (d, 1) vectors, each padded to (d, 128) lane tiles. The chip's compiler
+#: accepts ``prox_loop`` at d=1280 and runs out of VMEM at 1408.
+VMEM_MAX_D = 1280
 
 
 def _prep(G, R, v, t, lam, mu=0.0, lo=0.0, hi=0.0):
@@ -56,8 +58,6 @@ def _fits_vmem(G, *_args, **_kw) -> bool:
 def prox_step(G, R, v, t, lam, mu=0.0, lo=0.0, hi=0.0, *, variant="l1",
               interpret: bool | None = None):
     """w+ = prox(v - t*(G v - R)); accepts (d,) vectors."""
-    if not _fits_vmem(G):
-        return _ref.prox_step(G, R, v, t, lam, mu, lo, hi, variant=variant)
     interpret = _interpret_default() if interpret is None else interpret
     Gp, Rp, vp, scal = _prep(G, R, v, t, lam, mu, lo, hi)
     return _k.prox_step(Gp, Rp, vp, scal, variant=variant,
@@ -68,9 +68,6 @@ def prox_loop(G, R, z0, t, lam, Q: int, mu=0.0, lo=0.0, hi=0.0, *,
               variant="l1", interpret: bool | None = None):
     """z_Q from Q fused warm-started prox-gradient iterations; accepts (d,)
     vectors."""
-    if not _fits_vmem(G):
-        return _ref.prox_loop(G, R, z0, t, lam, Q, mu, lo, hi,
-                              variant=variant)
     interpret = _interpret_default() if interpret is None else interpret
     Gp, Rp, zp, scal = _prep(G, R, z0, t, lam, mu, lo, hi)
     return _k.prox_loop(Gp, Rp, zp, scal, Q=Q, variant=variant,

@@ -19,9 +19,11 @@ Dispatch semantics:
 
 * A requested backend whose impl is missing, unavailable on this process, or
   whose per-call ``supports`` predicate rejects the arguments falls back to
-  ``xla`` silently — forcing ``REPRO_BACKEND=pallas`` runs the Pallas kernels
+  ``xla`` — forcing ``REPRO_BACKEND=pallas`` runs the Pallas kernels
   wherever they apply and the XLA paths everywhere else (e.g. decode steps
   with a dynamic ``kv_valid_len``, which the static-masked kernel cannot do).
+  A fallback is never silent: the first one per (op, requested backend)
+  warns, and every one counts in ``repro_kernel_fallback_total``.
 * An impl registered with a ``vjp=(fwd, bwd)`` pair is wired through
   :func:`jax.custom_vjp` at registration (see :func:`custom_vjp_fn`), so the
   kernels are differentiable end-to-end — ``jax.grad`` through a dispatch
@@ -79,13 +81,12 @@ import jax.numpy as jnp
 from repro import obs
 
 #: dispatch observability (zero-cost while repro.obs is disabled): dispatches
-#: by op x backend, the silent xla fallbacks the policy docs promise, and
-#: autotune cache lookup outcomes (hit / miss / stale schema)
+#: by op x backend, the xla fallbacks the policy docs promise, and autotune
+#: cache lookup outcomes (hit / miss / stale schema)
 _M_DISPATCH = obs.counter("repro_kernel_dispatch_total",
                           "kernel dispatches by op and backend")
 _M_FALLBACK = obs.counter("repro_kernel_fallback_total",
-                          "silent fallbacks to xla by op and requested "
-                          "backend")
+                          "fallbacks to xla by op and requested backend")
 _M_TUNE_LOOKUP = obs.counter("repro_autotune_lookup_total",
                              "autotune cache lookups by outcome "
                              "(hit/miss/stale)")
@@ -163,6 +164,7 @@ _load_lock = threading.Lock()
 
 _tls = threading.local()            # .stack: list[str], .grad_depth: int
 _process_backend: Optional[str] = None
+_warned_fallbacks: set = set()      # (op, requested backend) already warned
 
 
 # --------------------------------------------------------------------------
@@ -389,6 +391,10 @@ def select(name: str, *args: Any, **kwargs: Any) -> Impl:
     fallback = op.impls.get("xla")
     if backend != "xla" and _usable(fallback, args, kwargs):
         _M_FALLBACK.inc(op=name, requested=backend)
+        if (name, backend) not in _warned_fallbacks:
+            _warned_fallbacks.add((name, backend))
+            warnings.warn(f"op {name!r}: the {backend} impl cannot serve "
+                          "this call; running its xla impl", stacklevel=3)
         return fallback
     raise NotImplementedError(
         f"op {name!r}: no usable implementation (policy={policy()!r}, "

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ssd import kernel as _k
+
 
 def _ssd_bwd_kernel(xdt_ref, a_ref, b_ref, c_ref, dy_ref, hins_ref, dhf_ref,
                     dxdt_ref, da_ref, db_ref, dc_ref, dh_ref, *,
@@ -51,16 +53,16 @@ def _ssd_bwd_kernel(xdt_ref, a_ref, b_ref, c_ref, dy_ref, hins_ref, dhf_ref,
     dh = dh_ref[...]                            # (P, N)
     L = xdt.shape[0]
 
-    cs = jnp.cumsum(a, axis=0)                  # (L, 1) inclusive
+    tril = _k.causal_mask(L)
+    cs = _k.tri_dot(tril, a, ((1,), (0,)))      # (L, 1) inclusive cumsum
+    cs_row = _k.tri_dot(a, tril, ((0,), (1,)))  # (1, L) the same, as a row
     cs_L = cs[L - 1, 0]
     e = jnp.exp(cs)                             # (L, 1)
     w = jnp.exp(cs_L - cs)                      # (L, 1)
 
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=f32)
-    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    decay = jnp.where(row >= col, jnp.exp(cs - cs.reshape(1, L)), 0.0)
+    decay = jnp.where(tril > 0, jnp.exp(cs - cs_row), 0.0)
     CB = dot(Cm, Bm, (((1,), (1,)), ((), ())))           # (L, L)
     DYX = dot(dy, xdt, (((1,), (1,)), ((), ())))         # (L, L)
     DD = decay * DYX
@@ -86,8 +88,8 @@ def _ssd_bwd_kernel(xdt_ref, a_ref, b_ref, c_ref, dy_ref, hins_ref, dhf_ref,
     dcs_L = jnp.sum(dw) + jnp.exp(cs_L) * jnp.sum(h_in * dh)
     ridx = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     dcs = dcs + jnp.where(ridx == L - 1, dcs_L, 0.0)
-    # da_r = Σ_{t>=r} dcs_t  ==  total - inclusive_cumsum + dcs
-    da = jnp.sum(dcs) - jnp.cumsum(dcs, axis=0) + dcs
+    # da_r = Σ_{t>=r} dcs_t: the reverse cumsum, tril^T @ dcs
+    da = _k.tri_dot(tril, dcs, ((0,), (0,)))
 
     dh_ref[...] = jnp.exp(cs_L) * dh + dot(e * dy, Cm,
                                            (((0,), (0,)), ((), ())))

@@ -22,6 +22,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def causal_mask(L: int):
+    """(L, L) f32 lower-triangular ones: ``mask[t, s] = 1[t >= s]``."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    return (row >= col).astype(jnp.float32)
+
+
+def tri_dot(x, y, dims):
+    """Full-f32 matmul for the prefix sums: Mosaic has no ``cumsum``, so
+    they run on the MXU as products with :func:`causal_mask`. Log-decays
+    enter ``exp``, so the sums keep full f32 precision."""
+    return jax.lax.dot_general(x, y, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _ssd_body(xdt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, hins_ref, h_ref,
               *, nchunks: int):
     ic = pl.program_id(1)
@@ -39,16 +55,15 @@ def _ssd_body(xdt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, hins_ref, h_ref,
     if hins_ref is not None:                      # residual for the backward
         hins_ref[0, 0, :, :] = h_in
 
-    cs = jnp.cumsum(a, axis=0)                    # (L, 1) inclusive
+    tril = causal_mask(L)
+    cs = tri_dot(tril, a, ((1,), (0,)))           # (L, 1) inclusive cumsum
+    cs_row = tri_dot(a, tril, ((0,), (1,)))       # (1, L) the same, as a row
     cs_L = cs[L - 1, 0]
 
     # intra-chunk: y_t += sum_{s<=t} exp(cs_t - cs_s) (C_t.B_s) xdt_s
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (L, L)
-    lmat = cs - cs.reshape(1, L)                  # cs_t - cs_s
-    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    decay = jnp.where(row >= col, jnp.exp(lmat), 0.0)
+    decay = jnp.where(tril > 0, jnp.exp(cs - cs_row), 0.0)
     y = jax.lax.dot_general(CB * decay, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)    # (L, P)
 

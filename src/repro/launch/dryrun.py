@@ -1,13 +1,17 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) cell on
 the production mesh with ShapeDtypeStruct inputs (no device allocation), and
 record memory_analysis / cost_analysis / collective schedule for §Roofline.
 
-The two lines above MUST precede any other import (jax locks the device count
-at first init). Do not set that flag globally — smoke tests and benches see
-the real single-device host.
+The lines above MUST precede any other import (jax locks the platform and
+the device count at first init): the 512 devices are host CPUs, so the
+dry-run never takes an accelerator, even on a host that has one. Do not set
+that flag globally — smoke tests and benches see the real host. The roofline
+terms are computed against the peaks of :data:`TARGET_DEVICE_KIND`, the
+chip the production mesh is made of.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod both]
@@ -26,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import (ARCHS, SHAPES, get_arch, get_shape, input_specs,
                            cell_applicable)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (make_train_step, make_serve_step,
                                 init_train_state, TrainState)
@@ -34,6 +39,9 @@ from repro.models import init_params, init_cache
 from repro.models.transformer import forward
 from repro.optim import adamw_init, OptState
 from repro.roofline.analysis import analyze_compiled, model_flops
+
+#: the chip of the production mesh (a TPU v5e pod), as jax names it
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _active_params(cfg, params_sds) -> int:
@@ -145,7 +153,7 @@ def run_cell(arch_name, shape_name, multi_pod, ca_k=8):
         t0 = time.time()
         compiled = lowered.compile()
         t_compile = time.time() - t0
-        roof = analyze_compiled(compiled)
+        roof = analyze_compiled(compiled, TARGET_DEVICE_KIND)
         mf = model_flops(cfg, shape, meta["n_active_params"], shape.kind)
         chips = 512 if multi_pod else 256
         rec = dict(meta, status="ok", t_lower_s=round(t_lower, 1),
@@ -179,6 +187,7 @@ def main():
     ap.add_argument("--out", default="results/dryrun.json")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import ARCHS, smoke_config
 from repro.kernels import registry
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params, loss_fn
 
 
@@ -68,6 +69,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=32)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     backend = registry.resolved_backend()
     print(f"# grad smoke: backend={backend} "

@@ -7,8 +7,27 @@ host platform to initialize first.
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
-from repro.dist.compat import make_mesh
+
+def make_mesh(shape, names):
+    """``jax.make_mesh`` with every axis ``Auto`` (compiler-sharded; its
+    default is ``Explicit``)."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
+
+
+def spoof_mesh(shape, names):
+    """Mesh of (possibly duplicated) host devices, for spec-only computation.
+
+    ``Mesh`` accepts any ndarray of devices, so PartitionSpec inference for a
+    512-chip production mesh runs on a 1-CPU host — nothing is ever placed on
+    a spoofed mesh.
+    """
+    n = int(np.prod(shape))
+    devs = np.array(list(jax.devices()) * n)[:n].reshape(shape)
+    return Mesh(devs, names)                 # axes default to Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,6 +21,8 @@ Paged extras: ``--kv-dtype int8`` stores pageable K/V as int8 codes with
 f32 row/head scales (about double the resident capacity at the same pool
 bytes); ``--n`` fans every synthetic request into n sampled streams that
 share its prompt pages, each stream seeded with ``fold_in_seed(seed, i)``.
+:func:`check_paged_logits` compares cached decode through a page pool with
+a full forward over the same tokens.
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.autotune_cli import (add_autotune_args, plan_shapes,
                                        run_autotune)
 from repro.launch.mesh import make_host_mesh
@@ -40,8 +43,17 @@ from repro.launch.obs_cli import add_obs_args, obs_begin, obs_end
 from repro.launch.steps import make_serve_step
 from repro.dist.sharding import make_rules
 from repro.models import init_params, init_cache
-from repro.models.transformer import prefill_audio_cache
-from repro.serve import Engine, Request, SamplingParams
+from repro.models.transformer import decode_step, forward, prefill_audio_cache
+from repro.serve import Engine, PagedCachePool, Request, SamplingParams
+
+#: bound on the relative L2 error, per position, of cached paged-decode
+#: logits against a full forward over the same tokens. Both paths carry bf16
+#: activations (unit roundoff 2^-9) through every layer, in different orders,
+#: and on a TPU f32 matmuls run in bf16 passes unless the precision says
+#: otherwise: on a v5e, internlm2-1.8b's full forward at default precision is
+#: 2.0e-2 from the same forward at "highest", and paged decode 1.96e-2. A
+#: wrong page or head mapping moves logits by O(1).
+LOGIT_RTOL = 3e-2
 
 
 def _synthetic_requests(cfg, n: int, max_prompt: int, new_tokens: int,
@@ -146,7 +158,34 @@ def serve_engine(cfg, rules, args):
               f"cow_copies={s.cow_copies} page_defrags={s.page_defrags}")
     for r in sorted(responses, key=lambda r: r.id)[:2]:
         print(f"  {r.id}: finish={r.finish_reason} tokens={r.tokens[:16]}")
-    return responses
+    return engine
+
+
+def check_paged_logits(params, cfg, *, page_size: int, kv_dtype: str = "f32",
+                       n_tokens: int = 32, seed: int = 0) -> float:
+    """Worst per-position relative L2 error of the logits of ``n_tokens``
+    seeded tokens decoded one at a time through a fresh page pool, against
+    one full forward over the same tokens at the highest matmul precision."""
+    pool = PagedCachePool(cfg, 1, n_tokens, page_size=page_size,
+                          kv_dtype=kv_dtype)
+    slot = pool.allocate("logit-check")
+    pool.reserve(slot, n_tokens)
+    table = jnp.asarray(pool.tables)
+    toks = jnp.asarray(np.random.RandomState(seed).randint(
+        0, cfg.vocab, (1, n_tokens)), jnp.int32)
+    step = jax.jit(lambda p, c, t, pos: decode_step(
+        p, cfg, c, t, positions=pos, page_table=table))
+    cache, got = pool.make_cache(), []
+    for i in range(n_tokens):
+        logits, cache = step(params, cache, toks[:, i:i + 1],
+                             jnp.full((1,), i, jnp.int32))
+        got.append(logits[0, 0].astype(jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p, t: forward(p, cfg, dict(tokens=t))[0])(
+            params, toks)[0].astype(jnp.float32)
+    err = (jnp.linalg.norm(jnp.stack(got) - want, axis=-1)
+           / jnp.linalg.norm(want, axis=-1))
+    return float(err.max())
 
 
 def serve_classic(cfg, rules, args):
@@ -237,6 +276,7 @@ def main(argv=None):
     add_autotune_args(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = smoke_config(arch) if args.preset == "tiny" else arch

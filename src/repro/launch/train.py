@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.autotune_cli import (add_autotune_args, plan_shapes,
                                        run_autotune)
 from repro.launch.mesh import make_host_mesh
@@ -56,6 +57,7 @@ def main(argv=None):
     add_autotune_args(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
     observing = obs_begin(args)
 
     cfg, batch, seq = build(args)

@@ -34,9 +34,8 @@ Counting semantics (the paper's alpha-beta cost split):
   :mod:`repro.obs.spans` span at the moment it was counted.
 
 Counting happens at dispatch boundaries only, never inside traced code: a
-read observed while jax is tracing (``jax.core.trace_state_clean()`` is
-False — e.g. constant folding during jit compilation) is ignored, because it
-happens once per compile, not once per execution.
+read whose argument is a ``jax.core.Tracer`` is ignored, because it happens
+once per trace, not once per execution.
 
 Patches are installed when the first audit enters and removed when the last
 exits — code outside any audit pays nothing. Nested audits each receive all
@@ -53,11 +52,6 @@ import numpy as np
 
 from repro.obs import spans
 
-try:                                    # the committed-array class jit returns
-    from jax._src.array import ArrayImpl as _ArrayImpl
-except Exception:                       # pragma: no cover - layout change
-    _ArrayImpl = None
-
 _audits: List["SyncAudit"] = []
 _patch_lock = threading.Lock()
 _saved: dict = {}
@@ -66,14 +60,20 @@ _dispatch_seq = 0                       # monotonic mark_dispatch ticket
 
 #: (holder, attribute) module-level functions to wrap; each call is one read
 _FN_PATCHES = (("block_until_ready", jax), ("device_get", jax))
-#: ArrayImpl conversion methods that block on device results. NOTE: numpy 2
-#: converts ArrayImpl via the buffer protocol and never calls ``__array__``,
-#: hence the additional ``_NP_PATCHES`` below; these dunders still matter for
-#: ``float(x)``/``int(x)``/``bool(x)`` and explicit ``x.__array__()``.
+#: conversion methods of the concrete array class that block on device
+#: results. NOTE: numpy 2 converts jax arrays via the buffer protocol and
+#: never calls ``__array__``, hence the additional ``_NP_PATCHES`` below;
+#: these dunders still matter for ``float(x)``/``int(x)``/``bool(x)`` and
+#: explicit ``x.__array__()``.
 _METHOD_PATCHES = ("__array__", "__float__", "__int__", "__bool__")
 #: numpy entry points that pull device arrays to host (counted only when the
-#: first argument is a committed jax array)
+#: first argument is a concrete jax array)
 _NP_PATCHES = ("asarray", "array")
+
+
+def _traced(tree) -> bool:
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree.leaves(tree))
 
 
 class SyncAudit:
@@ -138,10 +138,10 @@ class SyncAudit:
                     device_get=self.device_get, by_span=dict(self.by_span))
 
 
-def _count_read(kind: str) -> None:
+def _count_read(kind: str, arg=None) -> None:
     if not _audits or getattr(_tls, "in_read", False):
         return
-    if not jax.core.trace_state_clean():
+    if _traced(arg):
         return                      # inside a trace: per-compile, not per-run
     for a in _audits:
         a._read(kind)
@@ -192,7 +192,7 @@ def _reentrancy_guard():
 
 def _wrap_fn(orig, kind):
     def wrapper(*args, **kwargs):
-        _count_read(kind)
+        _count_read(kind, args)
         with _reentrancy_guard():   # device_get re-enters __array__ per leaf
             return orig(*args, **kwargs)
     wrapper.__wrapped__ = orig
@@ -213,7 +213,7 @@ def _wrap_np(orig):
     array (numpy 2 reads those through the buffer protocol, bypassing any
     ``__array__`` patch, so interception must happen at the numpy call)."""
     def wrapper(a, *args, **kwargs):
-        if (_ArrayImpl is not None and isinstance(a, _ArrayImpl)
+        if (isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
                 and not getattr(_tls, "in_read", False)):
             _count_read("convert")
             with _reentrancy_guard():
@@ -232,13 +232,13 @@ def _install() -> None:
         orig = getattr(np, name)
         _saved[(id(np), name)] = (np, orig)
         setattr(np, name, _wrap_np(orig))
-    if _ArrayImpl is not None:
-        for name in _METHOD_PATCHES:
-            orig = getattr(_ArrayImpl, name, None)
-            if orig is None:
-                continue
-            _saved[(id(_ArrayImpl), name)] = (_ArrayImpl, orig)
-            setattr(_ArrayImpl, name, _wrap_method(orig, "convert"))
+    array_type = type(jax.device_put(0))     # jax.Array's concrete class
+    for name in _METHOD_PATCHES:
+        orig = getattr(array_type, name, None)
+        if orig is None:
+            continue
+        _saved[(id(array_type), name)] = (array_type, orig)
+        setattr(array_type, name, _wrap_method(orig, "convert"))
 
 
 def _uninstall() -> None:

@@ -36,7 +36,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def ca_local_sgd_solver(loss_fn: Callable, mesh: Mesh, *, k: int, lr: float,
@@ -51,10 +50,9 @@ def ca_local_sgd_solver(loss_fn: Callable, mesh: Mesh, *, k: int, lr: float,
     axes = tuple(data_axes)
 
     def local(params, batches):
-        from repro.dist.compat import axis_size
         nshards = 1
         for ax in axes:
-            nshards *= axis_size(ax)
+            nshards *= jax.lax.axis_size(ax)
 
         def one(params, batch):
             loss, g = jax.value_and_grad(loss_fn)(params, batch)
@@ -69,11 +67,11 @@ def ca_local_sgd_solver(loss_fn: Callable, mesh: Mesh, *, k: int, lr: float,
         return params, loss
 
     batch_spec = P(None, axes)   # prefix spec: applies to every batch leaf
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), batch_spec),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -122,10 +120,9 @@ def ca_stale_k_solver(loss_fn: Callable, mesh: Mesh, *, k: int, lr: float,
     damping = float(damping)
 
     def local(params, inflight, batches):
-        from repro.dist.compat import axis_size
         nshards = 1
         for ax in axes:
-            nshards *= axis_size(ax)
+            nshards *= jax.lax.axis_size(ax)
         # the previous round's collective lands (one-round staleness)
         params = jax.tree.map(lambda p, d: p + damping * d, params, inflight)
 
@@ -143,11 +140,11 @@ def ca_stale_k_solver(loss_fn: Callable, mesh: Mesh, *, k: int, lr: float,
         return (params, delta), loss
 
     batch_spec = P(None, axes)
-    sharded = jax.jit(shard_map(
+    sharded = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), batch_spec),
         out_specs=((P(), P()), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
     def init(params):

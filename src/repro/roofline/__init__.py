@@ -1,4 +1,5 @@
-from repro.roofline.hw import TPU_V5E
+from repro.roofline.hw import CHIPS, TPU_V5E, chip_for
 from repro.roofline.analysis import analyze_compiled, roofline_terms
 
-__all__ = ["TPU_V5E", "analyze_compiled", "roofline_terms"]
+__all__ = ["CHIPS", "TPU_V5E", "chip_for", "analyze_compiled",
+           "roofline_terms"]

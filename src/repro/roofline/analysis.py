@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-from repro.roofline.hw import TPU_V5E, Chip
+from repro.roofline.hw import chip_for
 from repro.roofline.hlo_cost import analyze_hlo
 
 
@@ -39,8 +39,11 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze_compiled(compiled, chip: Chip = TPU_V5E) -> Roofline:
-    """Derive the three roofline terms from one compiled SPMD executable."""
+def analyze_compiled(compiled, device_kind: str) -> Roofline:
+    """Derive the three roofline terms from one compiled SPMD executable,
+    against the peaks of ``device_kind`` (the chip the program is meant
+    for; an unknown kind raises, see :func:`repro.roofline.hw.chip_for`)."""
+    chip = chip_for(device_kind)
     cost = analyze_hlo(compiled.as_text())
 
     xla_flops = xla_bytes = 0.0
@@ -80,8 +83,8 @@ def analyze_compiled(compiled, chip: Chip = TPU_V5E) -> Roofline:
                     hbm_top=cost.hbm_top)
 
 
-def roofline_terms(compiled, chip: Chip = TPU_V5E) -> dict:
-    return analyze_compiled(compiled, chip).as_dict()
+def roofline_terms(compiled, device_kind: str) -> dict:
+    return analyze_compiled(compiled, device_kind).as_dict()
 
 
 def model_flops(cfg, shape, n_params_active: int, kind: str) -> float:

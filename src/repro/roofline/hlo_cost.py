@@ -31,8 +31,11 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_ONE = re.compile(r"(\w+)\[([\d,]*)\]")
+#: a tuple shape may nest one level of parens: TPU layouts read
+#: ``{1,0:T(8,128)S(1)}``
 _INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^)]*\)|\S+?)\s+([\w\-]+)\((.*)$")
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\((?:[^()]|\([^()]*\))*\)|\S+?)"
+    r"\s+([\w\-]+)\((.*)$")
 _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->")
 _PARAM = re.compile(r"%([\w.\-]+)\s*=\s*(\S+?)\s+parameter\(")
 _TRIP = re.compile(r'known_trip_count[^0-9]*(\d+)')
@@ -131,6 +134,24 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
     return comps, entry
 
 
+def _cond_trip(cond: Optional[Computation]) -> Optional[int]:
+    """Trip count ``N`` of a counted loop whose condition is ``i < N`` — for
+    while loops the compiler left without ``known_trip_count`` (the TPU
+    backend's compiled HLO)."""
+    if cond is None or not cond.instrs:
+        return None
+    root = cond.instrs[-1]
+    if root.op != "compare" or "direction=LT" not in root.rest:
+        return None
+    consts = {i.name: i for i in cond.instrs if i.op == "constant"}
+    for name in root.operands():
+        m = re.match(r"(\d+)\)", consts[name].rest) if name in consts \
+            else None
+        if m:
+            return int(m.group(1))
+    return None
+
+
 def _multipliers(comps: Dict[str, Computation],
                  entry: Optional[str]) -> Tuple[Dict[str, float], set]:
     """Per-computation execution multiplier + the set of fused computations."""
@@ -158,10 +179,10 @@ def _multipliers(comps: Dict[str, Computation],
                 attrs = ins.attrs()
                 targets = []
                 if ins.op == "while":
-                    trip = 1
                     tm = _TRIP.search(attrs)
-                    if tm:
-                        trip = int(tm.group(1))
+                    cm = re.search(r"condition=%?([\w.\-]+)", attrs)
+                    trip = int(tm.group(1)) if tm else (_cond_trip(
+                        comps.get(cm.group(1)) if cm else None) or 1)
                     for key in ("body", "condition"):
                         m = re.search(key + r"=%?([\w.\-]+)", attrs)
                         if m:

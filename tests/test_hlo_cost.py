@@ -3,9 +3,11 @@ programs: exact dot FLOPs, loop trip multiplication, collective weighting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.roofline.hlo_cost import analyze_hlo, _shape_bytes
-from repro.roofline.analysis import model_flops
+from repro.roofline.analysis import analyze_compiled, model_flops
+from repro.roofline.hw import TPU_V5E, chip_for
 from repro.configs import get_arch, get_shape
 
 
@@ -82,3 +84,18 @@ def test_model_flops_helper():
     shape = get_shape("train_4k")
     mf = model_flops(arch, shape, 8_000_000_000, "train")
     assert mf == 6.0 * 8e9 * 256 * 4096
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """A device kind without an entry in the peak table raises: a roofline
+    share against another chip's peaks would be meaningless."""
+    assert chip_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no peak rates"):
+        chip_for(jax.devices()[0].device_kind)      # the test host's CPU
+    compiled = jax.jit(lambda x: x @ x).lower(
+        jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
+    roof = analyze_compiled(compiled, "TPU v5 lite")
+    assert roof.flops == 2 * 128 ** 3
+    assert roof.t_compute == roof.flops / TPU_V5E.peak_flops_bf16
+    with pytest.raises(KeyError):
+        analyze_compiled(compiled, "cpu")

@@ -56,12 +56,17 @@ def test_prox_loop_sweep(d, Q):
 
 
 def test_prox_step_large_d_fallback():
+    """Above the VMEM limit the registry's ``supports`` predicate is the one
+    gate: it routes the call to the XLA impl, even under a pallas policy."""
     d = prox_ops.VMEM_MAX_D + 64     # exceeds VMEM budget -> XLA path
     ks = jax.random.split(KEY, 3)
     G = jax.random.normal(ks[0], (d, d)) / d
     R = jax.random.normal(ks[1], (d,))
     v = jax.random.normal(ks[2], (d,))
-    got = prox_ops.prox_step(G, R, v, 0.1, 0.01)
+    with registry.use("pallas"):
+        assert registry.select("prox_step", G, R, v, 0.1, 0.01).backend \
+            == "xla"
+        got = registry.dispatch("prox_step", G, R, v, 0.1, 0.01)
     want = prox_ref.prox_step(G, R, v, 0.1, 0.01)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
@@ -333,8 +338,8 @@ def test_registry_rejects_unknown_backend():
 
 def test_forced_pallas_falls_back_for_dynamic_mask():
     """flash_attention's pallas impl only does static masks; a dynamic
-    kv_valid_len under a forced pallas policy must silently take the XLA
-    path and match it bitwise."""
+    kv_valid_len under a forced pallas policy must take the XLA path (with
+    a warning) and match it bitwise."""
     from repro.models.attention import attention
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 1, 4, 16))

@@ -14,6 +14,7 @@ metal instead of trusted.
 import json
 import re
 import sys
+import warnings
 from pathlib import Path
 
 import jax
@@ -252,6 +253,26 @@ def test_registry_dispatch_and_fallback_counters():
         registry.dispatch("obs_test_op", 1)
     assert disp.value(op="obs_test_op", backend="xla") == 2
     assert fall.total() == 1                        # direct hit: no fallback
+
+
+def test_registry_fallback_warns_once_per_op():
+    """A fallback is never silent: the first one per (op, requested
+    backend) warns; later ones only count."""
+    @registry.register("warn_test_op", "pallas",
+                       supports=lambda *a, **k: False)
+    def _p(x):                                      # pragma: no cover
+        return x
+
+    @registry.register("warn_test_op", "xla")
+    def _x(x):
+        return x + 1
+
+    with registry.use("pallas"):
+        with pytest.warns(UserWarning, match="'warn_test_op': the pallas"):
+            assert registry.dispatch("warn_test_op", 1) == 2
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert registry.dispatch("warn_test_op", 2) == 3
 
 
 def test_autotune_stale_schema_is_not_a_miss(tmp_path, monkeypatch):

@@ -365,3 +365,15 @@ def test_cachepool_free_heap_keeps_lowest_slot_first():
     for s in order:
         pool.free(s)
     assert [pool.allocate(f"q{i}") for i in range(4)] == sorted(order)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_paged_decode_logits_match_full_forward(backend):
+    """The serve CLI's logit check: cached decode through a fresh page pool
+    (odd page size 5, so the last page is ragged) against one full forward
+    over the same tokens, under each backend of the paged attention op."""
+    from repro.launch.serve import LOGIT_RTOL, check_paged_logits
+    params = init_params(CFG_TINY, jax.random.PRNGKey(0))
+    with registry.use(backend):
+        err = check_paged_logits(params, CFG_TINY, page_size=5, n_tokens=12)
+    assert 0.0 <= err <= LOGIT_RTOL

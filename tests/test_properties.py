@@ -210,7 +210,7 @@ def test_fit_spec_always_divides(a, b):
 
 
 def test_fit_spec_drops_and_degrades():
-    from repro.dist.compat import spoof_mesh
+    from repro.launch.mesh import spoof_mesh
     mesh = spoof_mesh((2, 16, 16), ("pod", "data", "model"))
     # 50280 % 16 != 0 -> model axis dropped on dim 0
     spec = fit_spec(P("model", "data"), (50280, 1536), mesh)

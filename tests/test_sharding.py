@@ -8,7 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, get_arch
-from repro.dist.compat import spoof_mesh as fake_mesh
+from repro.launch.mesh import spoof_mesh as fake_mesh
 from repro.dist.sharding import (make_rules, param_specs, cache_specs,
                                  fit_spec)
 from repro.models import init_params, init_cache

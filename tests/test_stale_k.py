@@ -173,7 +173,11 @@ def test_stale_k_matches_sync_on_lm():
         carry, lt = stale.step(carry, b)
         sync_losses.append(float(ls))
         stale_losses.append(float(lt))
-    np.testing.assert_allclose(stale_losses, sync_losses, rtol=1e-4)
+    # the two schedules apply the same updates in a different order through a
+    # bf16 model (unit roundoff 2^-9), so the losses match only as closely
+    # as the parameters do: the same rtol=1e-3 as the parameter check below
+    # (under jax 0.9 the worst round differs by 1.8e-4 relative)
+    np.testing.assert_allclose(stale_losses, sync_losses, rtol=1e-3)
     for a, b in zip(jax.tree.leaves(stale.finalize(carry)),
                     jax.tree.leaves(p_sync)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

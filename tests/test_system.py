@@ -5,6 +5,11 @@ and the LM training/serving stack end-to-end (paper's CA schedule inside the
 trainer) — including failure injection + checkpoint recovery, i.e. the whole
 production story in miniature.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,3 +71,34 @@ def test_lm_train_checkpoint_recover_serve(tmp_path):
         tok, logits, cache = serve(state.params, cache, tok)
     assert int(cache["pos"]) == 5
     assert np.isfinite(np.asarray(logits, np.float32)).all()
+
+
+def test_chip_smoke_refuses_a_host_without_tpu():
+    """With JAX held to the CPU the chip check exits nonzero before any
+    phase and prints no result line."""
+    root = Path(__file__).resolve().parents[1]
+    out = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                         capture_output=True, text=True, timeout=300,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode != 0
+    assert "platform=cpu" in out.stdout
+    assert "===" not in out.stdout and '"ok"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+def test_compile_cache_rule(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing else is set; otherwise
+    the cache is the fixed in-checkout directory."""
+    from repro.launch import compile_cache
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == root / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.use_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert compile_cache.use_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,0 +1,146 @@
+"""The main-path kernels compile for a TPU v5e chip, at real widths.
+
+Nothing runs: each test compiles for a described (not attached) v5e chip,
+so the chip's own compiler refuses what it would refuse on the machine — a
+block that does not tile, a primitive Mosaic cannot lower, more VMEM than a
+kernel may use — and the compiled text must hold the kernel
+(``tpu_custom_call``). The topology is described inside a module fixture,
+never at import: only the worker given this file loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import SolverConfig
+from repro.core.distributed import make_distributed_solver
+from repro.kernels import registry
+from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.gram import ops as gram_ops
+from repro.kernels.prox_step import ops as prox_ops
+from repro.roofline.hlo_cost import analyze_hlo
+
+#: internlm2-1.8b attention widths and a 4-slot engine on 16-token pages
+HQ, HKV, D = 16, 8, 128
+SLOTS, PAGE, PAGES_PER_SLOT = 4, 16, 8
+#: mamba2-780m SSD widths: 48 heads of 64 channels, state 128
+SSD_H, SSD_P, SSD_N = 48, 64, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Shape builder on one described chip; the persistent compilation
+    cache is off meanwhile (an entry compiled for a described chip cannot
+    be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_kernel(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _pallas(op):
+    return registry.get_op(op).impls["pallas"].fn
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles(chip, kv_dtype):
+    pages = 1 + SLOTS * PAGES_PER_SLOT
+    pool = chip((pages, PAGE, HKV, D),
+                jnp.int8 if kv_dtype == "int8" else jnp.bfloat16)
+    shapes = [chip((SLOTS, 1, HQ, D), jnp.bfloat16), pool, pool,
+              chip((SLOTS, PAGES_PER_SLOT), jnp.int32),
+              chip((SLOTS,), jnp.int32)]
+    if kv_dtype == "int8":
+        shapes += [chip((pages, PAGE, HKV)), chip((pages, PAGE, HKV))]
+    compile_kernel(lambda q, k, v, t, n, *s: fa_ops.paged_flash_decode(
+        q, k, v, t, n, k_scale=s[0] if s else None,
+        v_scale=s[1] if s else None, interpret=False), *shapes)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_ssd_compiles(chip, grad):
+    S = 512
+    shapes = (chip((1, S, SSD_H, SSD_P), jnp.bfloat16), chip((1, S, SSD_H)),
+              chip((SSD_H,)), chip((1, S, SSD_N), jnp.bfloat16),
+              chip((1, S, SSD_N), jnp.bfloat16))
+    ssd = lambda *a: _pallas("ssd")(*a, interpret=False)
+    if grad:
+        loss = lambda *a: ssd(*a)[0].astype(jnp.float32).sum()
+        compile_kernel(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *shapes)
+    else:
+        compile_kernel(ssd, *shapes)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_compiles(chip, grad):
+    S = 1024                                       # kernel layout (B,H,S,D)
+    shapes = (chip((1, HQ, S, D), jnp.bfloat16),
+              chip((1, HKV, S, D), jnp.bfloat16),
+              chip((1, HKV, S, D), jnp.bfloat16))
+    attn = lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True,
+                                                  interpret=False)
+    if grad:
+        loss = lambda *a: attn(*a).astype(jnp.float32).sum()
+        text = compile_kernel(jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+        assert text.count("tpu_custom_call") >= 3     # fwd, dq, dkv
+    else:
+        compile_kernel(attn, *shapes)
+
+
+def test_gram_compiles_at_covtype_width(chip):
+    compile_kernel(lambda X: gram_ops.gram(X, interpret=False),
+                   chip((54, 58_101)))
+
+
+@pytest.mark.parametrize("op", ["prox_loop", "prox_step"])
+def test_prox_compiles_at_vmem_limit(chip, op):
+    d = prox_ops.VMEM_MAX_D
+    fn = getattr(prox_ops, op)
+    kw = dict(Q=5) if op == "prox_loop" else {}
+    compile_kernel(lambda G, R, z: fn(G, R, z, 0.1, 0.01, interpret=False,
+                                      **kw),
+                   chip((d, d)), chip((d,)), chip((d,)))
+
+
+def test_distributed_solvers_all_reduce_t_over_k_on_four_chips(chip, topo):
+    """The paper's Table I on the chip's own compiled HLO: on a 4-chip
+    ``data`` mesh CA-sFISTA all-reduces T/k times, sFISTA T times, with the
+    same bytes in total (the TPU's HLO carries no known_trip_count, so the
+    analyzer reads the loop bounds from the loop conditions)."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    spec = lambda shape, p, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, p))
+    d, n, cfg = 54, 4096, SolverConfig(T=64, k=16, b=0.1)
+    args = (spec((d, n), P(None, "data")), spec((n,), P("data")),
+            spec((d,), P()), spec((), P()), spec((2,), P(), jnp.uint32))
+    ar = {}
+    for alg in ("ca_sfista", "sfista"):
+        solve = make_distributed_solver(alg, mesh, cfg, 0.01)
+        text = solve.lower(*args).compile().as_text()
+        ar[alg] = analyze_hlo(text).collectives["all-reduce"]
+    assert ar["ca_sfista"]["count"] == cfg.T // cfg.k
+    assert ar["sfista"]["count"] == cfg.T
+    assert ar["ca_sfista"]["bytes"] == ar["sfista"]["bytes"]
