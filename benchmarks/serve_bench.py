@@ -271,18 +271,22 @@ def _fanout_sweep(cfg, params, k=4):
 
 def _disabled_overhead_guard(us_per_sync: float, iters: int = 20_000):
     """The acceptance gate on zero-overhead-when-disabled: time the full
-    per-round instrumentation bundle the engine executes with obs off (one
-    ``mark_dispatch``, two no-op spans, the counter/histogram mutations and
-    ``enabled()`` checks) and assert it costs < 1% of a real k=1 sync."""
+    per-round instrumentation bundle the engine executes with obs off and
+    no profile running (one ``mark_dispatch``, the round's four phase spans,
+    the counter/histogram mutations and ``enabled()`` checks) and assert it
+    costs < 1% of a real k=1 sync."""
     assert not obs.enabled(), "guard must run with obs disabled"
     c = obs.counter("repro_serve_syncs_total")
     h = obs.histogram("repro_serve_ttft_seconds")
     t0 = time.perf_counter()
     for _ in range(iters):
-        obs.mark_dispatch("serve.decode_block")
         with obs.span("serve.admit"):
             pass
-        with obs.span("serve.decode_block", k=1, live=4):
+        with obs.span("serve.dispatch", k=1, live=4):
+            obs.mark_dispatch("serve.dispatch")
+        with obs.span("serve.fetch"):
+            pass
+        with obs.span("serve.complete"):
             pass
         c.inc()
         c.inc(4)

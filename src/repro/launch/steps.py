@@ -148,8 +148,9 @@ def make_serve_step(cfg, rules: Optional[Rules], *, greedy: bool = True):
                                         positions=positions,
                                         constrain=constrain,
                                         page_table=page_table)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        return nxt, logits, cache
+        with jax.named_scope("decode.head"):
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return nxt[:, None], logits, cache
 
     return serve_step
 

@@ -3,6 +3,8 @@ and the Zamba2-style hybrid superblock built from Mamba2 + shared attention.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Optional
 
 import jax
@@ -17,6 +19,32 @@ from repro.models.moe import init_moe, moe_ffn
 # ---------------------------------------------------------------------------
 # Attention block
 # ---------------------------------------------------------------------------
+
+_trace = threading.local()
+
+
+@contextlib.contextmanager
+def decoding():
+    """Mark the trace of one decode step (``models.decode_step`` opens it
+    once), so that ``decode_scope`` names the parts of that step."""
+    prev = getattr(_trace, "decoding", False)
+    _trace.decoding = True
+    try:
+        yield
+    finally:
+        _trace.decoding = prev
+
+
+def decode_scope(part: str):
+    """``jax.named_scope("decode.<part>")`` inside ``decoding()``, so that a
+    profile's readers can tell which part of the decode step an op belongs
+    to; nothing elsewhere (training, prefill). Scopes are compile-time
+    metadata (``op_name``): no run-time cost, and no HLO instruction is
+    renamed."""
+    if getattr(_trace, "decoding", False):
+        return jax.named_scope("decode." + part)
+    return contextlib.nullcontext()
+
 
 def _tp_size(constrain) -> Optional[int]:
     """Model-axis size behind a Rules.constrain bound method (None off-mesh)."""
@@ -123,18 +151,22 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
             # int8 pool: quantize on scatter — codes and their
             # per-(row, head) scales land in the same page/row, so a page is
             # self-describing and CoW/defrag/trie sharing move both together
-            kq, ks = quantize_kv(k[:, 0])
-            vq, vs = quantize_kv(v[:, 0])
-            kc = cache["k"].at[pidx, off].set(kq)
-            vc = cache["v"].at[pidx, off].set(vq)
-            kcs = cache["k_scale"].at[pidx, off].set(ks)
-            vcs = cache["v_scale"].at[pidx, off].set(vs)
+            with decode_scope("kv_write"):
+                kq, ks = quantize_kv(k[:, 0])
+                vq, vs = quantize_kv(v[:, 0])
+                kc = cache["k"].at[pidx, off].set(kq)
+                vc = cache["v"].at[pidx, off].set(vq)
+                kcs = cache["k_scale"].at[pidx, off].set(ks)
+                vcs = cache["v_scale"].at[pidx, off].set(vs)
             new_cache = dict(k=kc, v=vc, k_scale=kcs, v_scale=vcs)
             o = paged_attention(q, kc, vc, page_table, cache_pos + 1,
                                 k_scale=kcs, v_scale=vcs, chunk=attn_chunk)
         else:
-            kc = cache["k"].at[pidx, off].set(k[:, 0].astype(cache["k"].dtype))
-            vc = cache["v"].at[pidx, off].set(v[:, 0].astype(cache["v"].dtype))
+            with decode_scope("kv_write"):
+                kc = cache["k"].at[pidx, off].set(
+                    k[:, 0].astype(cache["k"].dtype))
+                vc = cache["v"].at[pidx, off].set(
+                    v[:, 0].astype(cache["v"].dtype))
             new_cache = dict(k=kc, v=vc)
             o = paged_attention(q, kc, vc, page_table, cache_pos + 1,
                                 chunk=attn_chunk)
@@ -142,16 +174,19 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
         out = jnp.einsum("bsh,hd->bsd", o, params["wo"].astype(x.dtype))
         return constrain(out, ("batch", None, None)), new_cache
     if cache is not None:
-        if getattr(cache_pos, "ndim", 0):      # (B,) per-slot write positions
-            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(
-                c, u, (p, 0, 0)))
-            k = upd(cache["k"], k.astype(cache["k"].dtype), cache_pos)
-            v = upd(cache["v"], v.astype(cache["v"].dtype), cache_pos)
-        else:
-            k = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, cache_pos, 0, 0))
-            v = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, cache_pos, 0, 0))
+        with decode_scope("kv_write"):
+            if getattr(cache_pos, "ndim", 0):  # (B,) per-slot write positions
+                upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(
+                    c, u, (p, 0, 0)))
+                k = upd(cache["k"], k.astype(cache["k"].dtype), cache_pos)
+                v = upd(cache["v"], v.astype(cache["v"].dtype), cache_pos)
+            else:
+                k = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype),
+                    (0, cache_pos, 0, 0))
+                v = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype),
+                    (0, cache_pos, 0, 0))
         new_cache = dict(k=k, v=v)
         kv_valid = cache_pos + S
         causal = False if S == 1 else causal    # single query: mask via kv_valid
@@ -186,15 +221,18 @@ def init_dense_block(key, cfg, dtype=jnp.float32):
 
 def dense_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
                 constrain=lambda x, s: x, page_table=None):
-    h, new_cache = attn_forward(
-        params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
-        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-        positions=pos_info.get("positions"), mrope_pos=pos_info.get("mrope"),
-        rope_theta=cfg.rope_theta, cache=cache, cache_pos=cache_pos,
-        constrain=constrain, page_table=page_table)
-    x = x + h
-    x = x + swiglu(params["mlp"], rms_norm(x, params["ln2"], cfg.norm_eps),
-                   constrain)
+    with decode_scope("attn"):
+        h, new_cache = attn_forward(
+            params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
+            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            positions=pos_info.get("positions"),
+            mrope_pos=pos_info.get("mrope"), rope_theta=cfg.rope_theta,
+            cache=cache, cache_pos=cache_pos, constrain=constrain,
+            page_table=page_table)
+        x = x + h
+    with decode_scope("mlp"):
+        x = x + swiglu(params["mlp"],
+                       rms_norm(x, params["ln2"], cfg.norm_eps), constrain)
     return x, new_cache
 
 
@@ -213,14 +251,20 @@ def init_moe_block(key, cfg, dtype=jnp.float32):
 
 def moe_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
               constrain=lambda x, s: x, page_table=None):
-    h, new_cache = attn_forward(
-        params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
-        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-        positions=pos_info.get("positions"), mrope_pos=pos_info.get("mrope"),
-        rope_theta=cfg.rope_theta, cache=cache, cache_pos=cache_pos,
-        constrain=constrain, page_table=page_table)
-    x = x + h
-    m, aux = moe_ffn(params["moe"], rms_norm(x, params["ln2"], cfg.norm_eps),
-                     top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-                     constrain=constrain)
-    return x + m, new_cache, aux
+    with decode_scope("attn"):
+        h, new_cache = attn_forward(
+            params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
+            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            positions=pos_info.get("positions"),
+            mrope_pos=pos_info.get("mrope"), rope_theta=cfg.rope_theta,
+            cache=cache, cache_pos=cache_pos, constrain=constrain,
+            page_table=page_table)
+        x = x + h
+    with decode_scope("mlp"):
+        m, aux = moe_ffn(params["moe"],
+                         rms_norm(x, params["ln2"], cfg.norm_eps),
+                         top_k=cfg.top_k,
+                         capacity_factor=cfg.capacity_factor,
+                         constrain=constrain)
+        x = x + m
+    return x, new_cache, aux

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from repro.models.layers import rms_norm, embed_init, dense_init
 from repro.models.blocks import (
     init_dense_block, dense_block, init_moe_block, moe_block,
-    init_attn, attn_forward, init_attn_cache)
+    init_attn, attn_forward, init_attn_cache, decoding)
 from repro.models.ssm import (
     init_mamba2, mamba2_forward, init_mamba2_state, mamba2_decode_step)
 from repro.models import encdec
@@ -393,7 +393,8 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
     else:
         raise ValueError(fam)
 
-    logits = _logits(params, cfg, x, constrain)
+    with jax.named_scope("decode.head"):
+        logits = _logits(params, cfg, x, constrain)
     cache = dict(cache, pos=cache["pos"] + 1)   # stays scalar in both modes
     return logits, cache
 
@@ -402,9 +403,12 @@ def decode_step(params, cfg, cache, tokens, *, positions=None,
                 constrain=_no_constrain, page_table=None):
     """One decode step (see ``_decode_step`` for shapes/positions semantics).
 
-    Kernels dispatch through ``repro.kernels.registry``."""
-    return _decode_step(params, cfg, cache, tokens, positions=positions,
-                        constrain=constrain, page_table=page_table)
+    Kernels dispatch through ``repro.kernels.registry``; the step's ops are
+    named ``decode.attn`` / ``decode.kv_write`` / ``decode.mlp`` /
+    ``decode.head`` (``jax.named_scope``)."""
+    with decoding():
+        return _decode_step(params, cfg, cache, tokens, positions=positions,
+                            constrain=constrain, page_table=page_table)
 
 
 def prefill_audio_cache(params, cfg, cache, enc_embeds, *,

@@ -2,8 +2,9 @@
 
 Three pillars, one switch:
 
-- ``spans``      — nested wall-clock spans + instant events, thread-safe,
-                   exported as Chrome-trace/Perfetto JSON (``write_trace``).
+- ``spans``      — nested host spans and instant events as
+                   ``jax.profiler.TraceAnnotation``s: on the profiler's
+                   clock, beside the device's ops, in any JAX profile.
 - ``metrics``    — labeled counter/gauge/histogram registry, exported as
                    Prometheus text (``write_prometheus``) or JSONL.
 - ``sync_audit`` — a context manager counting host<->device synchronization
@@ -16,15 +17,15 @@ Three pillars, one switch:
                    already in flight) are counted as ``overlap_epochs`` —
                    *hidden* syncs, as opposed to blocking pipeline stalls.
 
-``enable()`` turns span/metric recording on (the launch CLIs do this from
-``--metrics``/``--trace-out``); while disabled every instrumentation point
-costs one boolean check. ``sync_audit()`` is independent of the switch: the
+``enable()`` turns metric and instant recording on (the launch CLIs do this
+from ``--metrics``/``--trace-out``); while disabled every such point costs one
+boolean check. Spans do not read the switch: the profiler records them while
+a profile is being captured, and otherwise a span is a push and a pop of a
+thread-local name stack. ``sync_audit()`` is independent of the switch: the
 context itself opts in, and its jax patches exist only while it is active.
 """
 from repro.obs.state import enable, disable, enabled
-from repro.obs.spans import (NOOP, span, instant, current, to_chrome_trace,
-                             write_trace)
-from repro.obs import spans as _spans
+from repro.obs.spans import span, instant, current
 from repro.obs import metrics
 from repro.obs.metrics import (REGISTRY, counter, gauge, histogram,
                                to_prometheus, to_jsonl, write_prometheus,
@@ -39,14 +40,13 @@ def metrics_snapshot() -> dict:
 
 
 def reset() -> None:
-    """Clear collected spans and metric values (handles stay valid)."""
-    _spans.reset()
+    """Clear metric values (handles stay valid)."""
     REGISTRY.reset()
 
 
 __all__ = [
     "enable", "disable", "enabled", "reset",
-    "NOOP", "span", "instant", "current", "to_chrome_trace", "write_trace",
+    "span", "instant", "current",
     "metrics", "REGISTRY", "counter", "gauge", "histogram",
     "to_prometheus", "to_jsonl", "write_prometheus", "write_jsonl",
     "metrics_snapshot",

@@ -1,134 +1,74 @@
-"""Nested wall-clock spans with thread-safe context and Chrome-trace export.
+"""Nested host spans on the JAX profiler's clock.
 
-A span is one timed region of host code (``with span("serve.step"): ...``);
-spans nest through a thread-local stack, so a trace viewer reconstructs the
-flame graph from start/duration alone. Finished spans accumulate in a
-process-global bounded buffer and export as Chrome ``traceEvents`` JSON —
-loadable in ``chrome://tracing`` or Perfetto (https://ui.perfetto.dev).
-
-Zero-overhead-when-disabled contract: ``span()``/``instant()`` check the
-:mod:`repro.obs.state` switch first and return a shared no-op context
-manager (no allocation, no clock read) when it is off.
+A span is one region of host code (``with span("serve.fetch"): ...``). It
+pushes its name on a thread-local stack (``current()``, which
+:mod:`repro.obs.sync_audit` attributes syncs to) and opens a
+``jax.profiler.TraceAnnotation``. While a JAX profile is being captured
+(``jax.profiler.start_trace`` or ``trace``, the launch CLIs' ``--trace-out``,
+a benchmark's traced run), the span lands on the host timeline of that
+profile beside the device's ops, so an idle gap of the device can be put down
+to what the host was doing. Whether anything is recorded is the profiler's
+own switch, not :mod:`repro.obs.state`: with no profile running a span
+builds no annotation and costs the stack push and pop.
 """
 from __future__ import annotations
 
-import json
-import os
 import threading
-import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import state
 
-#: buffer cap — a runaway loop must not grow host memory without bound;
-#: overflow is counted and reported in the exported trace metadata
-MAX_EVENTS = 200_000
-
-_lock = threading.Lock()
-_events: List[dict] = []        # finished spans + instants, chrome-trace form
-_dropped = 0
-_epoch = time.perf_counter()    # trace time zero
-
-_tls = threading.local()        # .stack: list of active span names
+_recording = TraceAnnotation.is_enabled     # the profiler's TraceMe switch
 
 
-def _stack() -> List[str]:
-    if not hasattr(_tls, "stack"):
-        _tls.stack = []
-    return _tls.stack
+class _Local(threading.local):
+    def __init__(self):
+        self.stack: List[str] = []          # names of the active spans
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager returned while obs is disabled."""
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, *exc: Any) -> bool:
-        return False
-
-
-NOOP = _NoopSpan()
+_tls = _Local()
 
 
 class Span:
-    """One active timed region; records itself into the buffer on exit."""
-    __slots__ = ("name", "args", "_t0")
+    """One active region: a stack entry and, while profiling, an
+    annotation."""
+    __slots__ = ("name", "_stack", "_ann")
 
-    def __init__(self, name: str, args: Optional[Dict[str, Any]]):
+    def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
-        self.args = args
+        self._stack = _tls.stack
+        self._ann: Optional[TraceAnnotation] = \
+            TraceAnnotation(name, **args) if _recording() else None
 
     def __enter__(self) -> "Span":
-        _stack().append(self.name)
-        self._t0 = time.perf_counter()
+        self._stack.append(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
-    def __exit__(self, *exc: Any) -> bool:
-        t1 = time.perf_counter()
-        _stack().pop()
-        ev = dict(name=self.name, ph="X", pid=os.getpid(),
-                  tid=threading.get_ident(),
-                  ts=(self._t0 - _epoch) * 1e6, dur=(t1 - self._t0) * 1e6)
-        if self.args:
-            ev["args"] = self.args
-        _record(ev)
+    def __exit__(self, typ, val, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(typ, val, tb)
+        self._stack.pop()
         return False
 
 
-def _record(ev: dict) -> None:
-    global _dropped
-    with _lock:
-        if len(_events) >= MAX_EVENTS:
-            _dropped += 1
-        else:
-            _events.append(ev)
-
-
-def span(name: str, **args: Any):
-    """Open a nested wall-clock span; no-op (shared object) when disabled."""
-    if not state.enabled():
-        return NOOP
-    return Span(name, args or None)
+def span(name: str, **args: Any) -> Span:
+    """Open a nested span; ``args`` become the annotation's metadata."""
+    return Span(name, args)
 
 
 def instant(name: str, **args: Any) -> None:
-    """Record a zero-duration marker (e.g. a request lifecycle edge)."""
-    if not state.enabled():
-        return
-    ev = dict(name=name, ph="i", s="t", pid=os.getpid(),
-              tid=threading.get_ident(),
-              ts=(time.perf_counter() - _epoch) * 1e6)
-    if args:
-        ev["args"] = args
-    _record(ev)
+    """A zero-length annotation (e.g. a request lifecycle edge); only while
+    :mod:`repro.obs` is enabled."""
+    if state.enabled() and _recording():
+        with TraceAnnotation(name, **args):
+            pass
 
 
 def current() -> str:
     """Name of the innermost active span on this thread ("" outside any)."""
-    stack = _stack()
+    stack = _tls.stack
     return stack[-1] if stack else ""
-
-
-def reset() -> None:
-    """Drop all collected events (tests and CLI run boundaries)."""
-    global _dropped, _epoch
-    with _lock:
-        del _events[:]
-        _dropped = 0
-        _epoch = time.perf_counter()
-
-
-def to_chrome_trace() -> dict:
-    """The collected events as a Chrome-trace/Perfetto JSON object."""
-    with _lock:
-        events = list(_events)
-        dropped = _dropped
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"producer": "repro.obs", "dropped": dropped}}
-
-
-def write_trace(path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(), f)

@@ -78,6 +78,11 @@ class EngineStats:
     steps: int = 0                           # model decode steps (= syncs * k)
     tokens_out: int = 0                      # tokens delivered to responses
     prefill_tokens: int = 0                  # prompt tokens consumed in-loop
+    tokens_processed: int = 0                # tokens written to the cache by
+                                             # the blocks (prompt and output)
+    kv_token_reads: int = 0                  # K/V positions those tokens
+                                             # attended over (p + 1 for the
+                                             # token written at position p)
     admitted: int = 0
     retired: int = 0
     shed: int = 0
@@ -134,7 +139,9 @@ class EngineStats:
              f"tokens_per_sync={self.tokens_per_sync:.2f} "
              f"admitted={self.admitted} retired={self.retired} "
              f"shed={self.shed} rejected={self.rejected} "
-             f"occupancy={self.occupancy:.2f}")
+             f"occupancy={self.occupancy:.2f} "
+             f"tokens_processed={self.tokens_processed} "
+             f"kv_token_reads={self.kv_token_reads}")
         if self.prefix_hits or self.cow_copies or self.page_defrags:
             s += (f" prefix_hit_rate={self.prefix_hit_rate:.2f} "
                   f"prefix_tokens={self.prefix_tokens} "

@@ -120,43 +120,52 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
         # this guard retires a stray one at the next sync instead
         unservable = prompt_len > jnp.minimum(P, max_len - 1)
 
+        # named scopes: the step's own parts are decode.attn / decode.mlp /
+        # decode.head (models); the block's per-slot bookkeeping around it
+        # is decode.state
         def body(st: DecodeState, _):
-            done0 = st.done | (active & unservable)
-            live = active & ~done0
-            in_prefill = st.lengths < prompt_len
-            idx = jnp.clip(st.lengths, 0, P - 1)
-            ptok = jnp.take_along_axis(prompts, idx[:, None], axis=1)[:, 0]
-            tok = jnp.where(in_prefill, ptok, st.last_tok).astype(jnp.int32)
-            pos = jnp.minimum(st.lengths, max_len - 1)
+            with jax.named_scope("decode.state"):
+                done0 = st.done | (active & unservable)
+                live = active & ~done0
+                in_prefill = st.lengths < prompt_len
+                idx = jnp.clip(st.lengths, 0, P - 1)
+                ptok = jnp.take_along_axis(prompts, idx[:, None],
+                                           axis=1)[:, 0]
+                tok = jnp.where(in_prefill, ptok,
+                                st.last_tok).astype(jnp.int32)
+                pos = jnp.minimum(st.lengths, max_len - 1)
             nxt, logits, cache = serve(params, st.cache, tok[:, None], pos,
                                        page_table)
             nxt = nxt[:, 0]
             if samp is not None:
                 # all k draws live inside this scan — zero extra host syncs;
                 # greedy rows take the argmax above verbatim (bit parity)
-                nxt = sample_tokens(logits[:, -1], nxt, samp, st.n_out)
-            # the step consuming the LAST prompt token produces the first
-            # generated token; pure-prefill steps emit nothing
-            emit = live & (st.lengths >= prompt_len - 1)
-            n_out = st.n_out + emit.astype(jnp.int32)
-            # length causes (max_new, cache-full) take precedence over a
-            # coincident EOS draw: finish_reason is derived from eos_hit
-            len_done = (emit & (n_out >= max_new)) \
-                | (live & (st.lengths >= max_len - 1))
-            done = done0 | len_done
-            eos_hit = st.eos_hit
-            if eos_id is not None:
-                eos_now = emit & (nxt == eos_id)
-                done = done | eos_now
-                eos_hit = eos_hit | (eos_now & ~len_done & ~done0)
-            new = DecodeState(
-                cache=cache,
-                lengths=st.lengths + live.astype(jnp.int32),
-                last_tok=jnp.where(live, nxt, st.last_tok),
-                n_out=n_out,
-                done=done,
-                eos_hit=eos_hit)
-            return new, (jnp.where(emit, nxt, -1), emit)
+                with jax.named_scope("decode.head"):
+                    nxt = sample_tokens(logits[:, -1], nxt, samp, st.n_out)
+            with jax.named_scope("decode.state"):
+                # the step consuming the LAST prompt token produces the
+                # first generated token; pure-prefill steps emit nothing
+                emit = live & (st.lengths >= prompt_len - 1)
+                n_out = st.n_out + emit.astype(jnp.int32)
+                # length causes (max_new, cache-full) take precedence over a
+                # coincident EOS draw: finish_reason is derived from eos_hit
+                len_done = (emit & (n_out >= max_new)) \
+                    | (live & (st.lengths >= max_len - 1))
+                done = done0 | len_done
+                eos_hit = st.eos_hit
+                if eos_id is not None:
+                    eos_now = emit & (nxt == eos_id)
+                    done = done | eos_now
+                    eos_hit = eos_hit | (eos_now & ~len_done & ~done0)
+                new = DecodeState(
+                    cache=cache,
+                    lengths=st.lengths + live.astype(jnp.int32),
+                    last_tok=jnp.where(live, nxt, st.last_tok),
+                    n_out=n_out,
+                    done=done,
+                    eos_hit=eos_hit)
+                out = jnp.where(emit, nxt, -1)
+            return new, (out, emit)
 
         state, (toks, emitted) = jax.lax.scan(body, state, xs=None, length=k)
         return state, toks, emitted

@@ -72,6 +72,11 @@ _M_TOKENS = obs.counter("repro_serve_tokens_total",
                         "tokens delivered to responses")
 _M_PREFILL = obs.counter("repro_serve_prefill_tokens_total",
                          "prompt tokens consumed in-loop")
+_M_PROCESSED = obs.counter("repro_serve_tokens_processed_total",
+                           "tokens the decode blocks wrote to the cache "
+                           "(prompt and output)")
+_M_KV_READS = obs.counter("repro_serve_kv_token_reads_total",
+                          "K/V positions those tokens attended over")
 _M_REQS = obs.counter("repro_serve_requests_total",
                       "completed requests by finish reason")
 _M_PREFIX_HITS = obs.counter("repro_serve_prefix_hits_total",
@@ -381,7 +386,7 @@ class Engine:
                 self._slot_toks[slot] = []
                 self._slot_t0[slot] = now
                 if obs.enabled():
-                    obs.instant("serve.admit", id=r.id, slot=slot,
+                    obs.instant("serve.admit_request", id=r.id, slot=slot,
                                 prompt_len=n, prefix_reused=m0, stream=i)
             if pinned:
                 self.pool.unpin_page(cow[0])
@@ -422,47 +427,51 @@ class Engine:
         # defrag permutes slot rows / page tables in place: the overlapped
         # loop must flush its pipeline first (no block may own moved rows)
         assert not self._pipe, "defrag with a block in flight"
-        if self.pool.live_count and \
-                self.pool.fragmentation() >= self.defrag_threshold:
-            cache, perm, mapping = self.pool.defrag(self.state.cache)
-            take = lambda a: self.pool.take_rows(a, perm)
-            self.state = self.state._replace(
-                cache=cache, lengths=take(self.state.lengths),
-                last_tok=take(self.state.last_tok),
-                n_out=take(self.state.n_out),
-                done=take(self.state.done),
-                eos_hit=take(self.state.eos_hit))
-            hperm = np.asarray(perm)
-            self._prompt_buf = self._prompt_buf[hperm]
-            self._prompt_len = self._prompt_len[hperm]
-            self._len_host = self._len_host[hperm]
-            self._max_new = self._max_new[hperm]
-            self._active = self._active[hperm]
-            self._temp = self._temp[hperm]
-            self._top_p = self._top_p[hperm]
-            self._top_k = self._top_k[hperm]
-            self._slot_req = {mapping[s]: r
-                              for s, r in self._slot_req.items()}
-            self._slot_toks = {mapping[s]: t
-                               for s, t in self._slot_toks.items()}
-            self._slot_t0 = {mapping[s]: t
-                             for s, t in self._slot_t0.items()}
-            self._slot_prompt = {mapping[s]: p
-                                 for s, p in self._slot_prompt.items()}
-            self._slot_first = {mapping[s]: t
-                                for s, t in self._slot_first.items()}
-            self._slot_stream = {mapping[s]: i
-                                 for s, i in self._slot_stream.items()}
-            self.stats.defrags += 1
-            _M_DEFRAGS.inc(kind="slot")
-        if self.paged and \
-                self.pool.page_fragmentation() >= self.defrag_threshold:
-            # pure page permutation: slot contents (and the emission-count
-            # PRNG stream) are unchanged, so defrag stays invisible to tokens
-            self.state = self.state._replace(
-                cache=self.pool.defrag_pages(self.state.cache))
-            self.stats.page_defrags += 1
-            _M_DEFRAGS.inc(kind="page")
+        if not self._needs_defrag():
+            return
+        with obs.span("serve.defrag"):
+            if self.pool.live_count and \
+                    self.pool.fragmentation() >= self.defrag_threshold:
+                cache, perm, mapping = self.pool.defrag(self.state.cache)
+                take = lambda a: self.pool.take_rows(a, perm)
+                self.state = self.state._replace(
+                    cache=cache, lengths=take(self.state.lengths),
+                    last_tok=take(self.state.last_tok),
+                    n_out=take(self.state.n_out),
+                    done=take(self.state.done),
+                    eos_hit=take(self.state.eos_hit))
+                hperm = np.asarray(perm)
+                self._prompt_buf = self._prompt_buf[hperm]
+                self._prompt_len = self._prompt_len[hperm]
+                self._len_host = self._len_host[hperm]
+                self._max_new = self._max_new[hperm]
+                self._active = self._active[hperm]
+                self._temp = self._temp[hperm]
+                self._top_p = self._top_p[hperm]
+                self._top_k = self._top_k[hperm]
+                self._slot_req = {mapping[s]: r
+                                  for s, r in self._slot_req.items()}
+                self._slot_toks = {mapping[s]: t
+                                   for s, t in self._slot_toks.items()}
+                self._slot_t0 = {mapping[s]: t
+                                 for s, t in self._slot_t0.items()}
+                self._slot_prompt = {mapping[s]: p
+                                     for s, p in self._slot_prompt.items()}
+                self._slot_first = {mapping[s]: t
+                                    for s, t in self._slot_first.items()}
+                self._slot_stream = {mapping[s]: i
+                                     for s, i in self._slot_stream.items()}
+                self.stats.defrags += 1
+                _M_DEFRAGS.inc(kind="slot")
+            if self.paged and \
+                    self.pool.page_fragmentation() >= self.defrag_threshold:
+                # pure page permutation: slot contents (and the
+                # emission-count PRNG stream) are unchanged, so defrag stays
+                # invisible to tokens
+                self.state = self.state._replace(
+                    cache=self.pool.defrag_pages(self.state.cache))
+                self.stats.page_defrags += 1
+                _M_DEFRAGS.inc(kind="page")
 
     # ------------------------------------------------------ dispatch/fetch
     def _dispatch_block(self) -> _InFlight:
@@ -474,31 +483,34 @@ class Engine:
         admission functionally updates ``self.state`` on top of them.
         """
         live = int(self._active.sum())
-        samp = SlotSampling(temperature=jnp.asarray(self._temp),
-                            top_p=jnp.asarray(self._top_p),
-                            top_k=jnp.asarray(self._top_k),
-                            key=jnp.asarray(self.pool.slot_keys))
-        page_table = None
-        if self.paged:
-            # pre-reserve pages for every position this block can write, so
-            # the table is constant across the k in-scan steps; under
-            # overlap ``_len_host`` is one un-fetched block stale, so the
-            # horizon covers the in-flight block's k steps plus this one's
-            horizon = self.k * (2 if self.overlap else 1)
-            for slot in self._slot_req:
-                self.pool.reserve(slot, int(self._len_host[slot]) + horizon)
-            page_table = jnp.asarray(self.pool.tables)
-            self.stats.peak_live_pages = max(self.stats.peak_live_pages,
-                                             self.pool.live_page_count())
-        ticket = obs.mark_dispatch("serve.decode_block")
-        with obs.span("serve.decode_block", k=self.k, live=live):
+        with obs.span("serve.dispatch", k=self.k, live=live):
+            samp = SlotSampling(temperature=jnp.asarray(self._temp),
+                                top_p=jnp.asarray(self._top_p),
+                                top_k=jnp.asarray(self._top_k),
+                                key=jnp.asarray(self.pool.slot_keys))
+            page_table = None
+            if self.paged:
+                # pre-reserve pages for every position this block can write,
+                # so the table is constant across the k in-scan steps; under
+                # overlap ``_len_host`` is one un-fetched block stale, so the
+                # horizon covers the in-flight block's k steps plus this
+                # one's
+                horizon = self.k * (2 if self.overlap else 1)
+                for slot in self._slot_req:
+                    self.pool.reserve(slot,
+                                      int(self._len_host[slot]) + horizon)
+                page_table = jnp.asarray(self.pool.tables)
+                self.stats.peak_live_pages = max(
+                    self.stats.peak_live_pages, self.pool.live_page_count())
+            ticket = obs.mark_dispatch("serve.dispatch")
             self.state, toks, emitted = self._block(
                 self.params, self.state, jnp.asarray(self._prompt_buf),
                 jnp.asarray(self._prompt_len), jnp.asarray(self._max_new),
                 jnp.asarray(self._active), samp, page_table)
-        return _InFlight(toks, emitted, self.state.done, self.state.eos_hit,
-                         self.state.lengths, list(self._slot_req),
-                         self._active.copy(), live, ticket)
+            return _InFlight(toks, emitted, self.state.done,
+                             self.state.eos_hit, self.state.lengths,
+                             list(self._slot_req), self._active.copy(), live,
+                             ticket)
 
     def _complete_block(self, inf: _InFlight
                         ) -> Tuple[List[StreamDelta], List[Response]]:
@@ -506,20 +518,25 @@ class Engine:
         sync) and run the host half of the round: stats, prefix publishing,
         token extension, retirement. Completion only touches slots the block
         owned at dispatch — rows admitted after are left to their own block."""
+        obs.mark_fetch(inf.ticket)
+        t0 = time.perf_counter()
+        with obs.span("serve.fetch"):
+            # one coalesced device->host transfer: k tokens + per-slot masks
+            fetched = jax.device_get(
+                (inf.toks, inf.emitted, inf.done, inf.eos_hit, inf.lengths))
+        blocked = time.perf_counter() - t0
+        with obs.span("serve.complete"):
+            return self._finish_block(inf, *fetched, blocked)
+
+    def _finish_block(self, inf: _InFlight, toks, emitted, done, eos_hit,
+                      len_after, blocked: float
+                      ) -> Tuple[List[StreamDelta], List[Response]]:
         # fence release: slots retired while ``inf`` was in flight return to
         # the pool only now — nothing could reallocate them while the
         # block's device writes still targeted their rows/pages
         for slot in inf.deferred:
             self.pool.free(slot)
         overlapped = bool(self._pipe)   # a newer block is already in flight
-        obs.mark_fetch(inf.ticket)
-        t0 = time.perf_counter()
-        with obs.span("serve.decode_block", k=self.k, live=inf.live,
-                      fetch=1):
-            # one coalesced device->host transfer: k tokens + per-slot masks
-            toks, emitted, done, eos_hit, len_after = jax.device_get(
-                (inf.toks, inf.emitted, inf.done, inf.eos_hit, inf.lengths))
-        blocked = time.perf_counter() - t0
         out: List[Response] = []
         deltas: List[StreamDelta] = []
         self.stats.syncs += 1
@@ -535,12 +552,22 @@ class Engine:
         new_prefill = int(
             (np.minimum(len_after, plen) - np.minimum(len_before, plen))
             [inf.active].sum())
+        # work of the block's steps: each token written at position p
+        # attended over the p + 1 positions up to it
+        lb = len_before[inf.active].astype(np.int64)
+        la = len_after[inf.active].astype(np.int64)
+        processed = int((la - lb).sum())
+        kv_reads = int((la * (la + 1) - lb * (lb + 1)).sum()) // 2
+        self.stats.tokens_processed += processed
+        self.stats.kv_token_reads += kv_reads
         self._len_host = np.where(inf.active, len_after, self._len_host)
         self.stats.prefill_tokens += new_prefill
         if obs.enabled():
             _M_SYNCS.inc()
             _M_STEPS.inc(self.k)
             _M_PREFILL.inc(new_prefill)
+            _M_PROCESSED.inc(processed)
+            _M_KV_READS.inc(kv_reads)
             _M_BLOCKED.observe(blocked)
             if overlapped:
                 _M_HIDDEN.inc()

@@ -11,6 +11,7 @@ family and an SSM family. That is the serving-side measurement of the
 paper's CA-k claim: k fused steps per round trip, verified against the
 metal instead of trusted.
 """
+import glob
 import json
 import re
 import sys
@@ -49,37 +50,71 @@ def _obs_clean():
 # spans
 # ---------------------------------------------------------------------------
 
+def _profile(log_dir):
+    """A JAX profile of the enclosed block, host spans and device ops, with
+    no Python tracer."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def _host_events(log_dir, names=None):
+    """(name, start ns, end ns, args) of the host events in the profile
+    under ``log_dir`` (only ``names`` when given), outermost first at equal
+    starts."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if names is None or ev.name in names:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
 def test_span_nesting_and_chrome_trace_roundtrip(tmp_path):
+    """Spans are profiler annotations: they land in a JAX profile, nested
+    as they were opened, with their arguments."""
     obs.enable()
-    with obs.span("outer", phase="test"):
-        assert obs.current() == "outer"
-        with obs.span("inner"):
-            assert obs.current() == "inner"
-        obs.instant("marker", n=3)
-    assert obs.current() == ""
-    trace = obs.to_chrome_trace()
-    by_name = {e["name"]: e for e in trace["traceEvents"]}
-    assert set(by_name) == {"outer", "inner", "marker"}
-    assert by_name["outer"]["ph"] == "X" and by_name["marker"]["ph"] == "i"
-    # inner nests inside outer on the timeline
-    assert by_name["outer"]["ts"] <= by_name["inner"]["ts"]
-    assert by_name["inner"]["ts"] + by_name["inner"]["dur"] <= \
-        by_name["outer"]["ts"] + by_name["outer"]["dur"] + 1e-6
-    assert by_name["outer"]["args"] == {"phase": "test"}
-    path = tmp_path / "trace.json"
-    obs.write_trace(str(path))
-    assert json.loads(path.read_text())["traceEvents"] == \
-        trace["traceEvents"]
+    with _profile(tmp_path):
+        with obs.span("outer", phase="test"):
+            assert obs.current() == "outer"
+            with obs.span("inner"):
+                assert obs.current() == "inner"
+            obs.instant("marker", n=3)
+        assert obs.current() == ""
+    ev = {e[0]: e for e in _host_events(tmp_path,
+                                        {"outer", "inner", "marker"})}
+    assert set(ev) == {"outer", "inner", "marker"}
+    _, o0, o1, oargs = ev["outer"]
+    _, i0, i1, _ = ev["inner"]
+    _, m0, m1, margs = ev["marker"]
+    assert o0 <= i0 <= i1 <= o1
+    assert i1 <= m0 <= m1 <= o1           # the instant: an empty span
+    assert oargs == {"phase": "test"} and margs == {"n": 3}
 
 
-def test_disabled_spans_are_shared_noop_and_record_nothing():
+def test_disabled_spans_are_shared_noop_and_record_nothing(tmp_path):
+    """With no profile running a span records nothing: it only keeps the
+    name stack, which it leaves empty; instants also need obs enabled."""
     assert not obs.enabled()
-    s1, s2 = obs.span("a"), obs.span("b", x=1)
-    assert s1 is s2 is obs.NOOP
-    with s1:
-        assert obs.current() == ""     # noop spans never touch the stack
-    obs.instant("never")
-    assert obs.to_chrome_trace()["traceEvents"] == []
+    with obs.span("a"):
+        assert obs.current() == "a"
+        with obs.span("b", x=1):
+            assert obs.current() == "b"
+        obs.instant("never")
+    assert obs.current() == ""
+    with _profile(tmp_path):
+        with obs.span("kept"):
+            obs.instant("disabled")
+    names = {e[0] for e in _host_events(tmp_path)}
+    assert "kept" in names
+    assert not names & {"a", "b", "never", "disabled"}
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +258,99 @@ def test_engine_audit_attributes_syncs_to_decode_span():
     with obs.sync_audit() as audit:
         eng.run(_engine_requests(cfg, 2))
     assert audit.syncs == eng.stats.syncs
-    # with spans live, every sync lands inside the decode-block span
-    assert audit.by_span == {"serve.decode_block": audit.syncs}
+    # every sync lands inside the fetch span
+    assert audit.by_span == {"serve.fetch": audit.syncs}
+
+
+PHASES = ("serve.admit", "serve.dispatch", "serve.fetch", "serve.complete",
+          "serve.defrag")
+
+
+def test_profiled_engine_round_has_one_span_per_phase(tmp_path):
+    """A profiled drain of the blocking loop: each round is an admit, a
+    dispatch, a fetch and a complete span, in that order and side by side,
+    one of each per sync; the defrag span opens only in rounds that
+    compact the pool, after the complete."""
+    cfg = smoke_config(get_arch("internlm2-1.8b"))
+    params = init_params(cfg, KEY)
+    eng = Engine(params, cfg, num_slots=4, max_len=32, k=4, max_prompt=4,
+                 page_size=4)
+    # two of four requests retire after the first block: the holes left in
+    # the slot and page spans make the engine compact both
+    def reqs():
+        return [Request(id=f"r{i}", prompt=[1 + i, 2, 3],
+                        max_new_tokens=1 if i % 2 == 0 else 9)
+                for i in range(4)]
+    eng.run(reqs())                           # compile outside the profile
+    before = eng.stats.defrags + eng.stats.page_defrags
+    with _profile(tmp_path):
+        eng.run(reqs())
+    moved = eng.stats.defrags + eng.stats.page_defrags - before
+    ev = _host_events(tmp_path, PHASES)
+    names = [e[0] for e in ev]
+    assert eng.stats.syncs and moved
+    rounds = [n for n in names if n != "serve.defrag"]
+    syncs = rounds.count("serve.fetch")
+    assert rounds == list(PHASES[:4]) * syncs
+    assert 1 <= names.count("serve.defrag") <= moved
+    for a, b in zip(ev, ev[1:]):          # no phase nests in another
+        assert a[2] <= b[1]
+    for i, n in enumerate(names):
+        if n == "serve.defrag":
+            assert names[i - 1] == "serve.complete"
+    dispatch = [e[3] for e in ev if e[0] == "serve.dispatch"]
+    assert all(a.get("k") == 4 and 1 <= a.get("live") <= 4
+               for a in dispatch)
+
+
+def test_decode_block_hlo_carries_named_scopes():
+    """The compiled decode block's op metadata names the part of the step
+    each op belongs to; the block's own bookkeeping is ``decode.state``."""
+    cfg = smoke_config(get_arch("internlm2-1.8b"))
+    params = init_params(cfg, KEY)
+    eng = Engine(params, cfg, num_slots=2, max_len=32, k=4, max_prompt=4,
+                 page_size=4)
+    from repro.serve.sampling import SlotSampling
+    samp = SlotSampling(temperature=eng._temp, top_p=eng._top_p,
+                        top_k=eng._top_k, key=eng.pool.slot_keys)
+    text = eng._block.lower(
+        eng.params, eng.state, eng._prompt_buf, eng._prompt_len,
+        eng._max_new, eng._active, samp, eng.pool.tables).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("decode.attn", "decode.kv_write", "decode.mlp",
+                  "decode.head", "decode.state"):
+        assert any(scope in n for n in names), scope
+    # the K/V scatter into the pool is part of attention
+    assert all("decode.attn/" in n for n in names if "decode.kv_write" in n)
+
+
+def test_engine_work_counts_match_their_closed_form():
+    """``EngineStats.tokens_processed`` / ``kv_token_reads``, counted by the
+    engine from the lengths it fetches, equal their closed form over the
+    retired requests: a request of prompt ``P`` and ``n`` outputs writes
+    ``L = P + n - 1`` tokens at positions ``0 .. L-1``, and the token at
+    ``p`` attends over ``p + 1`` positions, ``L (L + 1) / 2`` in all. More
+    requests than slots, so rows are admitted and retired mid-stream."""
+    cfg = smoke_config(get_arch("internlm2-1.8b"))
+    params = init_params(cfg, KEY)
+    eng = Engine(params, cfg, num_slots=4, max_len=64, k=4, page_size=8)
+    rng = np.random.default_rng(1234)
+    reqs = [Request(id=str(i),
+                    prompt=rng.integers(1, cfg.vocab, rng.integers(4, 13))
+                    .tolist(),
+                    max_new_tokens=int(rng.integers(8, 25)))
+            for i in range(10)]
+    obs.enable()
+    out = eng.run(reqs)
+    assert len(out) == len(reqs)
+    lens = [r.prompt_len + len(r.tokens) - 1 for r in out]
+    assert eng.stats.tokens_processed == sum(lens)
+    assert eng.stats.kv_token_reads == sum(n * (n + 1) // 2 for n in lens)
+    assert f"tokens_processed={sum(lens)}" in eng.stats.summary()
+    r = obs.REGISTRY
+    assert r.get("repro_serve_tokens_processed_total").total() == sum(lens)
+    assert r.get("repro_serve_kv_token_reads_total").total() == \
+        eng.stats.kv_token_reads
 
 
 # ---------------------------------------------------------------------------
@@ -366,10 +492,10 @@ def test_engine_stats_derived_properties_and_summary():
 
 def test_serve_cli_metrics_and_trace_export(tmp_path, capsys):
     from repro.launch.serve import main as serve_main
-    mfile, tfile = tmp_path / "metrics.prom", tmp_path / "trace.json"
+    mfile, tdir = tmp_path / "metrics.prom", tmp_path / "profile"
     serve_main(["--preset", "tiny", "--batch", "2", "--requests", "2",
                 "--new-tokens", "8", "--k", "4",
-                "--metrics", str(mfile), "--trace-out", str(tfile)])
+                "--metrics", str(mfile), "--trace-out", str(tdir)])
     stdout = capsys.readouterr().out
     stats_syncs = int(re.search(r"stats: syncs=(\d+)", stdout).group(1))
     text = mfile.read_text()
@@ -377,9 +503,11 @@ def test_serve_cli_metrics_and_trace_export(tmp_path, capsys):
         r"^repro_serve_syncs_total (\d+)$", text, re.M).group(1))
     assert prom_syncs == stats_syncs
     assert "# TYPE repro_serve_ttft_seconds histogram" in text
-    trace = json.loads(tfile.read_text())
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert "serve.decode_block" in names and "serve.admit" in names
+    # one profile: the engine's phases on the host, a Perfetto trace
+    names = {e[0] for e in _host_events(tdir, PHASES)}
+    assert set(PHASES[:4]) <= names
+    assert glob.glob(str(tdir / "**" / "perfetto_trace.json.gz"),
+                     recursive=True)
     assert "summary: " in stdout
     # the CLI disabled obs on exit and left no residue for later runs
     assert not obs.enabled()

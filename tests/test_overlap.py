@@ -118,7 +118,7 @@ def test_hidden_syncs_audited(mode):
     # warm the jit caches outside the audit (compile-time constant folding
     # must not pollute the counts)
     _drain(cfg, params, k=4, sampling=sampling, overlap=True)
-    obs.enable()    # spans live -> by_span attribution is testable
+    obs.enable()
     try:
         with obs.sync_audit() as audit:
             _, eng = _drain(cfg, params, k=4, sampling=sampling,
@@ -128,7 +128,7 @@ def test_hidden_syncs_audited(mode):
         assert audit.overlap_epochs == eng.stats.hidden_syncs
         assert audit.overlap_epochs > 0
         assert audit.blocking_syncs == eng.stats.blocking_syncs
-        assert audit.by_span == {"serve.decode_block": audit.syncs}
+        assert audit.by_span == {"serve.fetch": audit.syncs}
 
         with obs.sync_audit() as audit:
             _, eng = _drain(cfg, params, k=4, sampling=sampling,

@@ -80,6 +80,38 @@ def test_paged_decode_compiles(chip, kv_dtype):
         v_scale=s[1] if s else None, interpret=False), *shapes)
 
 
+def test_engine_decode_block_names_its_parts_on_the_chip(chip,
+                                                         monkeypatch):
+    """The engine's k-step block compiled for the chip: the paged decode
+    kernel keeps the instruction name ``paged_flash_decode.*`` that the
+    benchmark's readers match, under the named scope ``decode.attn``."""
+    import re
+    from repro.configs import get_arch, smoke_config
+    from repro.models import init_params
+    from repro.serve import Engine
+    from repro.serve.sampling import SlotSampling
+    cfg = smoke_config(get_arch("internlm2-1.8b"))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    # steer the kernel choice to the chip's (the block is only compiled)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = Engine(params, cfg, num_slots=SLOTS, max_len=PAGE * PAGES_PER_SLOT,
+                 k=4, max_prompt=PAGE, page_size=PAGE)
+    samp = SlotSampling(temperature=eng._temp, top_p=eng._top_p,
+                        top_k=eng._top_k, key=eng.pool.slot_keys)
+    args = (eng.params, eng.state, eng._prompt_buf, eng._prompt_len,
+            eng._max_new, eng._active, samp, eng.pool.tables)
+    shapes = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    text = eng._block.lower(*shapes).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        name = ln.split(" = ", 1)[0].split()[-1].lstrip("%")
+        assert re.match(r"paged_flash_decode(\.|$)", name), name
+        assert "/decode.attn/" in re.search(r'op_name="([^"]*)"',
+                                            ln).group(1)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 def test_ssd_compiles(chip, grad):
     S = 512
