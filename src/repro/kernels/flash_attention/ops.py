@@ -123,16 +123,18 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, *,
 
     q (B,1,Hq,D); pools (num_pages, page_size, Hkv, D); page_table
     (B, npages) int32; kv_valid_len scalar or (B,) int32 -> (B,1,Hq,D).
-    Pads head dim to the 128-lane boundary and the page rows to the sublane
-    multiple (the kernel masks pad rows with the logical ``page_size``).
-    ``k_scale``/``v_scale`` (num_pages, page_size, Hkv) f32 switch the
-    kernel to the int8-dequantizing body (pad rows carry scale 0 — they are
-    masked before the softmax either way).
+    The kernel reads the pools in this layout. Only where the shapes ask
+    for it are they padded: the head dim to the 128-lane boundary, and the
+    kv heads (with zero K/V and zero queries, sliced off the output) up to
+    a count whose page rows fill the pool dtype's DMA tiles
+    (``kernel.heads_dma_whole``). ``k_scale``/``v_scale``
+    (num_pages, page_size, Hkv) f32 switch the kernel to the
+    int8-dequantizing body.
     """
     B, S, Hq, D = q.shape
     if S != 1:
         raise ValueError(f"paged decode expects a single query, got S={S}")
-    P, Hkv = k_pool.shape[1], k_pool.shape[2]
+    Hkv = k_pool.shape[2]
     if Hq % Hkv:
         raise ValueError(f"GQA requires Hq % Hkv == 0, got {Hq=} {Hkv=}")
     if (k_scale is None) != (v_scale is None):
@@ -140,19 +142,45 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, *,
     scale = (D ** -0.5) if scale is None else scale
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    group = Hq // Hkv
     Dp = pad.round_up(D, LANE)
-    rows = pad.round_up(P, 8)
-    qp = pad.pad_dims(q[:, 0], {2: Dp})
-    kp = pad.pad_dims(k_pool, {1: rows, 3: Dp})
-    vp = pad.pad_dims(v_pool, {1: rows, 3: Dp})
-    ksp = None if k_scale is None else pad.pad_dims(k_scale, {1: rows})
-    vsp = None if v_scale is None else pad.pad_dims(v_scale, {1: rows})
+    Hp = kv_heads_padded(Hkv, k_pool.dtype)
+    qp = pad.pad_dims(q.reshape(B, Hkv, group, D), {1: Hp, 3: Dp})
+    kp = pad.pad_dims(k_pool, {2: Hp, 3: Dp})
+    vp = pad.pad_dims(v_pool, {2: Hp, 3: Dp})
+    # a scale tile is copied whole only with its kv heads on full lanes
+    Lp = pad.round_up(Hp, LANE)
+    ksp = None if k_scale is None else pad.pad_dims(k_scale, {2: Lp})
+    vsp = None if v_scale is None else pad.pad_dims(v_scale, {2: Lp})
     table = jnp.asarray(page_table, jnp.int32)
     valid = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32), (B,))
-    out = _k.paged_flash_decode(qp, kp, vp, table, valid, ksp, vsp,
-                                scale=scale, page_size=P,
+    out = _k.paged_flash_decode(qp.reshape(B, Hp * group, Dp), kp, vp, table,
+                                valid, ksp, vsp, scale=scale,
                                 interpret=interpret)
-    return pad.unpad_dims(out, {2: D})[:, None]
+    out = out.reshape(B, Hp, group, Dp)[:, :Hkv, :, :D]
+    return out.reshape(B, 1, Hq, D)
+
+
+def kv_heads_padded(hkv: int, dtype) -> int:
+    """The kv-head count the kernel reads a pool of ``hkv`` heads at: the
+    smallest ``>= hkv`` whose page rows DMA whole."""
+    itemsize = jnp.dtype(dtype).itemsize
+    h = hkv
+    while not _k.heads_dma_whole(h, itemsize):
+        h += 1
+    return h
+
+
+def block_tokens(page_size: int, hkv: int, head_dim: int, dtype,
+                 npages: int) -> int:
+    """Tokens of one block of :func:`paged_flash_decode` over a pool of
+    ``(num_pages, page_size, hkv, head_dim)`` ``dtype`` pages and a table
+    of ``npages`` pages a row: the kernel fetches a row's ``valid``
+    positions rounded up to a multiple of this."""
+    ppb, _ = _k.decode_blocks(page_size, kv_heads_padded(hkv, dtype),
+                              pad.round_up(head_dim, LANE),
+                              jnp.dtype(dtype).itemsize, npages)
+    return ppb * page_size
 
 
 flash_attention = registry.custom_vjp_fn(

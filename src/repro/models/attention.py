@@ -252,8 +252,9 @@ def paged_attention(q, k_pool, v_pool, page_table, kv_valid_len, *,
 
     The xla impl gathers the table into dense rows and reuses
     :func:`chunked_attention` — bitwise the slot-engine decode path. The
-    pallas impl (decode-only, S == 1) indexes the pool directly through a
-    scalar-prefetched table, never materialising the gather.
+    pallas impl (decode-only, S == 1) copies each row's live blocks of
+    whole pages straight from the pool through a scalar-prefetched table,
+    never materialising the gather.
     """
     return registry.dispatch(
         "paged_attention", q, k_pool, v_pool, page_table, kv_valid_len,

@@ -83,6 +83,9 @@ class EngineStats:
     kv_token_reads: int = 0                  # K/V positions those tokens
                                              # attended over (p + 1 for the
                                              # token written at position p)
+    kv_fetched_tokens: int = 0               # K/V positions the paged
+                                             # kernel fetched for them: each
+                                             # p + 1 rounded up to its block
     admitted: int = 0
     retired: int = 0
     shed: int = 0
@@ -142,6 +145,8 @@ class EngineStats:
              f"occupancy={self.occupancy:.2f} "
              f"tokens_processed={self.tokens_processed} "
              f"kv_token_reads={self.kv_token_reads}")
+        if self.kv_fetched_tokens:
+            s += f" kv_fetched_tokens={self.kv_fetched_tokens}"
         if self.prefix_hits or self.cow_copies or self.page_defrags:
             s += (f" prefix_hit_rate={self.prefix_hit_rate:.2f} "
                   f"prefix_tokens={self.prefix_tokens} "

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels.flash_attention.ops import block_tokens
 from repro.models import init_cache
 from repro.models.transformer import prefill_audio_cache
 from repro.serve.api import (Request, Response, EngineStats, StreamDelta,
@@ -77,6 +78,9 @@ _M_PROCESSED = obs.counter("repro_serve_tokens_processed_total",
                            "(prompt and output)")
 _M_KV_READS = obs.counter("repro_serve_kv_token_reads_total",
                           "K/V positions those tokens attended over")
+_M_KV_FETCHED = obs.counter("repro_serve_kv_fetched_tokens_total",
+                            "K/V positions the paged decode kernel fetched "
+                            "for them (whole blocks)")
 _M_REQS = obs.counter("repro_serve_requests_total",
                       "completed requests by finish reason")
 _M_PREFIX_HITS = obs.counter("repro_serve_prefix_hits_total",
@@ -100,6 +104,15 @@ _M_HIDDEN = obs.counter("repro_serve_hidden_syncs_total",
                         "already in flight (double-buffered loop)")
 _M_BLOCKED = obs.histogram("repro_serve_host_blocked_seconds",
                            "host wall time blocked per k-block result fetch")
+
+
+def _fetched_upto(n: np.ndarray, block: int) -> np.ndarray:
+    """K/V positions the paged decode kernel fetches for the tokens written
+    at positions ``0 .. n-1`` of a row: the token at ``p`` attends over
+    ``p + 1`` positions, fetched in whole blocks of ``block`` tokens, so
+    the sum of ``ceil(v / block) * block`` over ``v = 1 .. n``."""
+    q, r = np.divmod(n.astype(np.int64), block)
+    return block * (block * q * (q + 1) // 2 + r * (q + 1))
 
 
 class _InFlight:
@@ -206,6 +219,13 @@ class Engine:
                              enc_len=enc_len)
         self.pool = pool
         self.paged = isinstance(pool, PagedCachePool)
+        # tokens of one block of the paged decode kernel (0: no pages)
+        self._kv_block = 0
+        if self.paged:
+            kv = pool.kv_leaf()
+            self._kv_block = block_tokens(pool.page_size, kv.shape[-2],
+                                          kv.shape[-1], kv.dtype,
+                                          pool.pages_per_slot)
         self.prefix_on = (bool(prefix_cache) and self.paged
                           and cfg.family in ("dense", "vlm", "moe"))
         self.scheduler = scheduler if scheduler is not None else Scheduler()
@@ -560,6 +580,11 @@ class Engine:
         kv_reads = int((la * (la + 1) - lb * (lb + 1)).sum()) // 2
         self.stats.tokens_processed += processed
         self.stats.kv_token_reads += kv_reads
+        kv_fetched = 0
+        if self._kv_block:
+            kv_fetched = int((_fetched_upto(la, self._kv_block)
+                              - _fetched_upto(lb, self._kv_block)).sum())
+            self.stats.kv_fetched_tokens += kv_fetched
         self._len_host = np.where(inf.active, len_after, self._len_host)
         self.stats.prefill_tokens += new_prefill
         if obs.enabled():
@@ -568,6 +593,7 @@ class Engine:
             _M_PREFILL.inc(new_prefill)
             _M_PROCESSED.inc(processed)
             _M_KV_READS.inc(kv_reads)
+            _M_KV_FETCHED.inc(kv_fetched)
             _M_BLOCKED.observe(blocked)
             if overlapped:
                 _M_HIDDEN.inc()

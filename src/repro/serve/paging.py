@@ -362,6 +362,16 @@ class PagedCachePool(CachePool):
             cache = jax.device_put(cache, cache_shardings(cache, self.rules))
         return cache
 
+    def kv_leaf(self) -> jax.ShapeDtypeStruct:
+        """Shape and dtype of a paged K leaf,
+        ``(..., num_pages, page_size, Hkv, head_dim)``: what the paged
+        decode kernel reads a page of."""
+        shapes = jax.tree_util.tree_leaves_with_path(
+            jax.eval_shape(self._pool_arrays))
+        return next(leaf for (path, leaf), pax
+                    in zip(shapes, jax.tree.leaves(self.page_axes))
+                    if self._quant_leaf(path[-1].key, pax))
+
     def page_bytes(self) -> int:
         """Bytes one pool page costs across every paged leaf — scale
         siblings included — i.e. pool bytes / num_pages for the paged part.

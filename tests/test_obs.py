@@ -27,6 +27,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # benchmarks/
 from repro import obs
 from repro.configs import get_arch, smoke_config
 from repro.kernels import registry
+from repro.kernels.flash_attention.ops import block_tokens
 from repro.models import init_params
 from repro.serve import Engine, Request
 from repro.serve.api import EngineStats
@@ -329,7 +330,8 @@ def test_engine_work_counts_match_their_closed_form():
     engine from the lengths it fetches, equal their closed form over the
     retired requests: a request of prompt ``P`` and ``n`` outputs writes
     ``L = P + n - 1`` tokens at positions ``0 .. L-1``, and the token at
-    ``p`` attends over ``p + 1`` positions, ``L (L + 1) / 2`` in all. More
+    ``p`` attends over ``p + 1`` positions, ``L (L + 1) / 2`` in all; the
+    paged kernel fetches each ``p + 1`` rounded up to its block. More
     requests than slots, so rows are admitted and retired mid-stream."""
     cfg = smoke_config(get_arch("internlm2-1.8b"))
     params = init_params(cfg, KEY)
@@ -346,11 +348,20 @@ def test_engine_work_counts_match_their_closed_form():
     lens = [r.prompt_len + len(r.tokens) - 1 for r in out]
     assert eng.stats.tokens_processed == sum(lens)
     assert eng.stats.kv_token_reads == sum(n * (n + 1) // 2 for n in lens)
+    # the paged kernel fetches each token's p + 1 positions in whole blocks
+    kv = eng.pool.kv_leaf()
+    bt = block_tokens(eng.pool.page_size, kv.shape[-2], kv.shape[-1],
+                      kv.dtype, eng.pool.pages_per_slot)
+    fetched = sum(-(-v // bt) * bt for n in lens for v in range(1, n + 1))
+    assert eng.stats.kv_fetched_tokens == fetched
+    assert eng.stats.kv_fetched_tokens >= eng.stats.kv_token_reads
     assert f"tokens_processed={sum(lens)}" in eng.stats.summary()
+    assert f"kv_fetched_tokens={fetched}" in eng.stats.summary()
     r = obs.REGISTRY
     assert r.get("repro_serve_tokens_processed_total").total() == sum(lens)
     assert r.get("repro_serve_kv_token_reads_total").total() == \
         eng.stats.kv_token_reads
+    assert r.get("repro_serve_kv_fetched_tokens_total").total() == fetched
 
 
 # ---------------------------------------------------------------------------

@@ -134,19 +134,54 @@ def test_prefix_cache_streams_bit_identical(family_setup):
         assert s_on.prefix_hits == 0 and s_on.prefix_tokens == 0
 
 
-def test_paged_attention_pallas_matches_xla():
-    """Op level: the scalar-prefetch Pallas kernel agrees with the XLA
-    gather+mask reference on an odd page size, GQA grouping, ragged valid
-    lengths, and table entries pointing at the scratch page."""
-    B, Hq, Hkv, D, npg, P = 2, 6, 2, 16, 3, 5
+#: op-level cases of the paged decode kernel against the XLA gather+mask
+#: impl: (B, Hq, Hkv, D, page_size, pages a row, valid lengths), the valid
+#: lengths given from the page size ``P``, the case's kernel block ``bt``
+#: (tokens) and the horizon ``full``, so boundaries stay boundaries
+PAGED_CASES = {
+    # odd page size, GQA 3, a row whose tail entries are the scratch page
+    "odd_page_scratch_tail": (2, 6, 2, 16, 5, 3, lambda P, bt, full: [13, 4]),
+    # valid 1, a page boundary, a block boundary and one past it, the
+    # full horizon; 22 pages a row is no multiple of the block (4 pages
+    # in f32, 16 in int8)
+    "ragged_valid": (5, 16, 8, 128, 16, 22,
+                     lambda P, bt, full: [1, P, bt, bt + 1, full]),
+    "gqa1": (3, 4, 4, 64, 32, 18, lambda P, bt, full: [bt, bt + 1, 97]),
+    "gqa2": (3, 8, 4, 32, 16, 36, lambda P, bt, full: [full, bt - 1, 5]),
+    "gqa6": (3, 12, 2, 128, 16, 40, lambda P, bt, full: [1, 300, full]),
+    "batch1": (1, 8, 2, 128, 16, 40, lambda P, bt, full: [bt + 1]),
+}
+
+
+def paged_case(name, dtype=jnp.float32):
+    """Inputs of ``PAGED_CASES[name]``: random pools, each row's live pages
+    distinct, and the table's tail past ``valid`` pointing at the scratch
+    page 0 (odd rows) or at stale pages of other rows (even rows)."""
+    from repro.kernels.flash_attention.ops import block_tokens
+    B, Hq, Hkv, D, P, npg, valid = PAGED_CASES[name]
+    valid = valid(P, block_tokens(P, Hkv, D, dtype, npg), npg * P)
+    assert max(valid) <= npg * P
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32)
     k_pool = jax.random.normal(ks[1], (1 + B * npg, P, Hkv, D), jnp.float32)
     v_pool = jax.random.normal(ks[2], (1 + B * npg, P, Hkv, D), jnp.float32)
-    # row 0 fully tabled; row 1's tail entries are 0 (the scratch page),
-    # masked out by its short valid length
-    table = jnp.asarray([[1, 2, 3], [4, 0, 0]], jnp.int32)
-    valid = jnp.asarray([2 * P + 3, 4], jnp.int32)
+    pages = 1 + np.random.default_rng(0).permutation(B * npg)
+    table = pages.reshape(B, npg)
+    live = np.arange(npg)[None] * P < np.asarray(valid)[:, None]
+    tail = np.where(np.arange(B)[:, None] % 2, 0, table[::-1])
+    table = np.where(live, table, tail).astype(np.int32)
+    return q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(valid,
+                                                              jnp.int32)
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_attention_pallas_matches_xla(case):
+    """Op level: the paged kernel, which walks each row's live blocks of
+    whole pages, agrees with the XLA gather+mask reference at ragged valid
+    lengths on page and block boundaries, a horizon its block does not
+    divide, GQA groups 1 to 6, one row, and table entries past ``valid``
+    pointing at the scratch page or at other rows' pages."""
+    q, k_pool, v_pool, table, valid = paged_case(case)
     with registry.use("xla"):
         ref = paged_attention(q, k_pool, v_pool, table, valid)
     with registry.use("pallas"):

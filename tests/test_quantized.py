@@ -32,6 +32,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.models.attention import paged_attention, quantize_kv
 from repro.serve import Engine, PagedCachePool, PageError, Request
+from test_paged import PAGED_CASES, paged_case
 
 MAX_LEN = 32
 PROMPTS = [[7], [3, 11, 5], [9, 2]]
@@ -65,16 +66,13 @@ def test_quantize_kv_roundtrip_within_half_scale():
     assert (err <= np.asarray(s)[..., None] / 2 + 1e-7).all()
 
 
-def test_paged_attention_quantized_parity():
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_attention_quantized_parity(case):
     """Both impls dequantize identically (pallas ≈ xla, tight) and land
-    within the propagated rounding budget of the f32 reference."""
-    B, Hq, Hkv, D, npg, P = 2, 6, 2, 16, 3, 5
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (1 + B * npg, P, Hkv, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (1 + B * npg, P, Hkv, D), jnp.float32)
-    table = jnp.asarray([[1, 2, 3], [4, 0, 0]], jnp.int32)
-    valid = jnp.asarray([2 * P + 3, 4], jnp.int32)
+    within the propagated rounding budget of the f32 reference, over the
+    same ragged/GQA/scratch-tail cases as the f32 kernel parity test
+    (an int8 pool of 2 kv heads also exercises the kernel's head padding)."""
+    q, k_pool, v_pool, table, valid = paged_case(case, jnp.int8)
     kq, kscale = quantize_kv(k_pool)
     vq, vscale = quantize_kv(v_pool)
     with registry.use("xla"):

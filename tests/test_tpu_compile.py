@@ -25,6 +25,10 @@ from repro.roofline.hlo_cost import analyze_hlo
 #: internlm2-1.8b attention widths and a 4-slot engine on 16-token pages
 HQ, HKV, D = 16, 8, 128
 SLOTS, PAGE, PAGES_PER_SLOT = 4, 16, 8
+#: the internlm2.decode_sat cell's pool: 32 slots of 36 pages
+CELL_SLOTS, CELL_PAGES = 32, 36
+#: zamba2-2.7b's shared attention: 32 q/kv heads of 80 channels
+ZAMBA_H, ZAMBA_D = 32, 80
 #: mamba2-780m SSD widths: 48 heads of 64 channels, state 128
 SSD_H, SSD_P, SSD_N = 48, 64, 128
 
@@ -78,6 +82,45 @@ def test_paged_decode_compiles(chip, kv_dtype):
     compile_kernel(lambda q, k, v, t, n, *s: fa_ops.paged_flash_decode(
         q, k, v, t, n, k_scale=s[0] if s else None,
         v_scale=s[1] if s else None, interpret=False), *shapes)
+
+
+@pytest.mark.parametrize("case", ["cell-bf16", "cell-int8",
+                                  "zamba2-head-blocks"])
+def test_paged_decode_compiles_at_cell_widths(chip, case):
+    """The paged decode kernel at the benchmark cell's widths (32 slots x
+    36 pages of 16, Hq 16, Hkv 8, D 128), bf16 and int8, and at zamba2's
+    32 kv heads on 64-token pages, where one page of K+V outgrows a block
+    and the kernel splits the heads over its grid. The kernel reads the
+    pools as they are: no copy, reshape or transpose of a pool-sized
+    array precedes the call."""
+    import re
+    from repro.kernels.flash_attention import kernel as fa_k
+    if case == "zamba2-head-blocks":
+        B, npg, P, hq, hkv, d = 4, 8, 64, ZAMBA_H, ZAMBA_H, ZAMBA_D
+        dt = jnp.bfloat16
+        _, hb = fa_k.decode_blocks(P, hkv, 128, 2, npg)
+        assert hb < hkv
+    else:
+        B, npg, P, hq, hkv, d = CELL_SLOTS, CELL_PAGES, PAGE, HQ, HKV, D
+        dt = jnp.int8 if case == "cell-int8" else jnp.bfloat16
+    pages = 1 + B * npg
+    pool = chip((pages, P, hkv, d), dt)
+    shapes = [chip((B, 1, hq, d), jnp.bfloat16), pool, pool,
+              chip((B, npg), jnp.int32), chip((B,), jnp.int32)]
+    if dt == jnp.int8:
+        shapes += [chip((pages, P, hkv)), chip((pages, P, hkv))]
+    text = compile_kernel(lambda q, k, v, t, n, *s: fa_ops.paged_flash_decode(
+        q, k, v, t, n, k_scale=s[0] if s else None,
+        v_scale=s[1] if s else None, interpret=False), *shapes)
+    entry = text[text.index("ENTRY"):]
+    assert re.search(r"%paged_flash_decode(\.\d+)? = ", entry)
+    pool_elems = pages * P * hkv * d
+    for ln in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* "
+                     r"(copy|copy-start|reshape|transpose)\(", ln)
+        if m:
+            n = int(np.prod([int(x) for x in m.group(1).split(",") if x]))
+            assert n < pool_elems, ln
 
 
 def test_engine_decode_block_names_its_parts_on_the_chip(chip,
