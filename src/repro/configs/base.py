@@ -32,6 +32,11 @@ class ArchConfig:
     capacity_factor: float = 1.25
     first_layer_dense: bool = False
     dense_d_ff: int = 0
+    norm_topk_prob: bool = True         # renormalise the top-k gate weights
+    # the routed experts this chip holds (expert parallelism):
+    # first_expert .. first_expert + n_experts_held - 1; 0 holds them all
+    first_expert: int = 0
+    n_experts_held: int = 0
     # SSM (mamba2 / hybrid)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -49,6 +54,11 @@ class ArchConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def attn_free(self) -> bool:
@@ -85,7 +95,7 @@ def cell_applicable(arch: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
     """Whether (arch, shape) is a runnable cell, with a reason when not.
 
     long_500k needs sub-quadratic attention: run for SSM/hybrid, skip for
-    pure full-attention archs (noted in DESIGN.md)."""
+    pure full-attention archs."""
     if shape.name == "long_500k" and not arch.subquadratic:
         return False, "full-attention arch: 500k decode KV cache/attention is " \
                       "quadratic-cost; cell assigned to SSM/hybrid archs only"

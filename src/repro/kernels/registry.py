@@ -1,12 +1,13 @@
 """Unified kernel registry + backend dispatch.
 
 One named-op table for every compute hot spot (``gram``, ``prox_step``,
-``prox_loop``, ``flash_attention``, ``ssd``). Each op registers one
-implementation per *backend* (``pallas`` — the TPU kernels, interpret-mode on
-CPU; ``xla`` — the pure-XLA/jnp paths that compile anywhere), together with
-capability predicates, and every layer of the repo (solvers, models, serve,
-launch) picks its implementation through :func:`dispatch` instead of threading
-``use_kernel``/``backend`` booleans through call signatures.
+``prox_loop``, ``flash_attention``, ``ssd``, ``held_experts_ffn``). Each op
+registers one implementation per *backend* (``pallas`` — the TPU kernels,
+interpret-mode on CPU; ``xla`` — the pure-XLA/jnp paths that compile
+anywhere), together with capability predicates, and every layer of the
+repo (solvers, models, serve, launch) picks its implementation through
+:func:`dispatch` instead of threading ``use_kernel``/``backend`` booleans
+through call signatures.
 
 Backend policy resolution order (first match wins):
 
@@ -103,6 +104,7 @@ _IMPL_MODULES = (
     "repro.kernels.gram.ops",       # registers "gram"
     "repro.kernels.prox_step.ops",  # registers "prox_step", "prox_loop"
     "repro.kernels.ssd.ops",        # registers "ssd"
+    "repro.kernels.moe.ops",        # registers "held_experts_ffn"
     "repro.models.attention",       # registers "flash_attention" (model
                                     # layout; wraps kernels/flash_attention)
 )

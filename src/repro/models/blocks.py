@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from repro.models.layers import (rms_norm, apply_rope, apply_mrope, dense_init)
 from repro.models.attention import attention, paged_attention, quantize_kv
 from repro.models.mlp import init_swiglu, swiglu
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import (init_moe, moe_ffn, route, held_experts,
+                              expert_counts)
 
 
 # ---------------------------------------------------------------------------
@@ -60,8 +61,8 @@ def _kv_factorizes(n_kv: int, group: int, tp: int) -> bool:
     and forcing a padded kv-head tiling only hurts (llama kv=8 on tp=16:
     collective term 3.2 s -> 20 s). When no factorization exists (phi3 10x4,
     qwen2-vl 2x6 on tp=16) GSPMD collapses to a 2-way attention split unless
-    we pad the kv-head axis explicitly (phi3 prefill: 3.6x memory-term win).
-    See EXPERIMENTS.md §Perf phi3 iterations 1-2."""
+    we pad the kv-head axis explicitly (phi3 prefill: 3.6x memory-term
+    win)."""
     for a in range(1, n_kv + 1):
         if n_kv % a == 0 and tp % a == 0:
             rest = tp // a
@@ -245,12 +246,19 @@ def init_moe_block(key, cfg, dtype=jnp.float32):
                        cfg.head_dim, dtype),
         ln2=jnp.ones((cfg.d_model,), dtype),
         moe=init_moe(km, cfg.d_model, cfg.moe_d_ff, cfg.n_experts,
-                     cfg.n_shared_experts, shared_ff, dtype),
+                     cfg.n_shared_experts, shared_ff, dtype,
+                     n_held=cfg.experts_held),
     )
 
 
 def moe_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
-              constrain=lambda x, s: x, page_table=None):
+              constrain=lambda x, s: x, page_table=None, layer=None):
+    """Returns (x, new_cache, aux, counts). Without a cache, over every
+    expert (training, prefill), the capacity formulation gives the
+    load-balance ``aux`` and no counts; decoding, or holding a share of the
+    experts, the dropless held-experts layer gives ``counts``
+    (``moe.expert_counts``) and a zero ``aux``. ``layer``: see
+    ``moe.held_experts``."""
     with decode_scope("attn"):
         h, new_cache = attn_forward(
             params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
@@ -261,10 +269,22 @@ def moe_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
             page_table=page_table)
         x = x + h
     with decode_scope("mlp"):
-        m, aux = moe_ffn(params["moe"],
-                         rms_norm(x, params["ln2"], cfg.norm_eps),
-                         top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         constrain=constrain)
-        x = x + m
-    return x, new_cache, aux
+        mp = params["moe"]
+        h = rms_norm(x, params["ln2"], cfg.norm_eps)
+        if cache is None and cfg.experts_held == cfg.n_experts:
+            m, aux = moe_ffn(mp, h, top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor,
+                             norm_topk_prob=cfg.norm_topk_prob,
+                             constrain=constrain)
+            return x + m, new_cache, aux, None
+        with decode_scope("router"):
+            gates, sel, _ = route(mp["router"], h, top_k=cfg.top_k,
+                                  norm_topk_prob=cfg.norm_topk_prob)
+            counts = expert_counts(sel, cfg.first_expert, cfg.experts_held)
+        with decode_scope("experts"):
+            m = held_experts(mp, h, gates, sel,
+                             first_expert=cfg.first_expert, layer=layer)
+        if "shared" in mp:
+            m = m + swiglu(mp["shared"], h, constrain)
+        x = x + m.astype(x.dtype)
+    return x, new_cache, jnp.zeros((), jnp.float32), counts

@@ -1,35 +1,93 @@
-"""Mixture-of-Experts FFN: token-choice top-k routing with capacity dropping,
-shared experts (DeepSeek-MoE style), expert-parallel sharding over the
-``model`` mesh axis.
+"""Mixture-of-Experts FFN: token-choice top-k routing, shared experts
+(DeepSeek-MoE style), in two formulations.
 
-Dispatch/combine use scatter/gather against an (E, C, d) expert buffer — the
-GSPMD-friendly formulation: tokens stay sharded over the data axes, the
-buffer is constrained to experts-over-model so XLA materializes the dispatch
-as an all-to-all style reshard rather than a full all-gather. Router runs in
-fp32 (standard practice for stability).
+- :func:`moe_ffn` (training, and prefill over every expert): capacity
+  dropping over an (E, C, d) expert buffer, expert-parallel over the
+  ``model`` mesh axis. Dispatch/combine use scatter/gather against the
+  buffer — the GSPMD-friendly formulation: tokens stay sharded over the
+  data axes, the buffer is constrained to experts-over-model so XLA
+  materializes the dispatch as an all-to-all style reshard rather than a
+  full all-gather.
+- :func:`route` + :func:`held_experts` (decode, and a chip's share of the
+  experts): dropless. The router scores every expert; the chip computes the
+  SwiGLU of each expert it holds over exactly the tokens routed to it
+  (``held_experts_ffn`` kernel) and weights each by its gate. What the
+  experts held elsewhere add is left out: their chips add it.
+
+The router runs in fp32 (standard practice for stability); the top-k gate
+weights are renormalised only where the configuration says so
+(``norm_topk_prob``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import registry
 from repro.models.layers import dense_init
 from repro.models.mlp import init_swiglu, swiglu
 
 
 def init_moe(key, d: int, moe_ff: int, n_experts: int, n_shared: int,
-             shared_ff: int, dtype=jnp.float32):
+             shared_ff: int, dtype=jnp.float32, n_held: int = 0):
+    """Router over all ``n_experts``; stacked weights of the ``n_held``
+    experts this chip holds (0: all of them)."""
+    n_held = n_held or n_experts
     ks = jax.random.split(key, 5)
     params = dict(
         router=dense_init(ks[0], (d, n_experts), dtype=dtype),
         # stacked expert weights (E, d, ff) / (E, ff, d)
-        w_gate=dense_init(ks[1], (n_experts, d, moe_ff), in_axis=1, dtype=dtype),
-        w_up=dense_init(ks[2], (n_experts, d, moe_ff), in_axis=1, dtype=dtype),
-        w_down=dense_init(ks[3], (n_experts, moe_ff, d), in_axis=1, dtype=dtype),
+        w_gate=dense_init(ks[1], (n_held, d, moe_ff), in_axis=1, dtype=dtype),
+        w_up=dense_init(ks[2], (n_held, d, moe_ff), in_axis=1, dtype=dtype),
+        w_down=dense_init(ks[3], (n_held, moe_ff, d), in_axis=1, dtype=dtype),
     )
     if n_shared:
         params["shared"] = init_swiglu(ks[4], d, shared_ff, dtype)
     return params
+
+
+def route(router, x, *, top_k: int, norm_topk_prob: bool = True):
+    """fp32 softmax over every expert, then top-k. Returns (gates (..., k)
+    float32, sel (..., k) int32, probs (..., E))."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        router.astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, sel = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, sel.astype(jnp.int32), probs
+
+
+def expert_counts(sel, first_expert: int, n_held: int) -> dict:
+    """The held experts' work for routing ``sel``: (token, held expert)
+    pairs, and held experts with at least one token."""
+    local = sel.reshape(-1, sel.shape[-1]) - first_expert
+    chose = local[..., None] == jnp.arange(n_held)           # (T, k, E)
+    return dict(assignments=chose.sum(dtype=jnp.int32),
+                experts_hit=chose.any((0, 1)).sum(dtype=jnp.int32))
+
+
+#: the held experts' weights, stacked (E, ...) per layer
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def held_experts(params, x, gates, sel, *, first_expert: int = 0,
+                 layer=None):
+    """x (B, S, d) -> (B, S, d) float32: each token's sum, over the experts
+    this chip holds that it chose, of gate x SwiGLU. Drops no token.
+
+    layer: None when ``params`` holds one layer's experts; else the index
+    of this layer in expert weights stacked over every layer (L, E, ...),
+    which the kernel then reads in place."""
+    B, S, d = x.shape
+    k = sel.shape[-1]
+    w = [params[n] for n in EXPERT_WEIGHTS]
+    if layer is None:
+        w, layer = [a[None] for a in w], jnp.int32(0)
+    out = registry.dispatch(
+        "held_experts_ffn", x.reshape(B * S, d), sel.reshape(B * S, k),
+        gates.reshape(B * S, k), *w, layer, first_expert=first_expert)
+    return out.reshape(B, S, d)
 
 
 @jax.custom_vjp
@@ -64,8 +122,9 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_ffn(params, x, *, top_k: int, capacity_factor: float = 1.25,
-            constrain=lambda x, spec: x):
-    """x (B, S, d) -> (B, S, d), plus the load-balance aux loss.
+            norm_topk_prob: bool = True, constrain=lambda x, spec: x):
+    """x (B, S, d) -> (B, S, d), plus the load-balance aux loss. Holds
+    every expert (the router's width equals the stacked experts).
 
     Each batch row is a routing group; capacity C = ceil(S*top_k/E * cf).
     Dropped tokens (over capacity) fall back to the shared experts/residual.
@@ -74,12 +133,8 @@ def moe_ffn(params, x, *, top_k: int, capacity_factor: float = 1.25,
     E = params["router"].shape[1]
     C = max(int(S * top_k / E * capacity_factor), 4)
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)                     # (B,S,E)
-    weights, sel = jax.lax.top_k(gates, top_k)                  # (B,S,k)
-    weights = weights / jnp.maximum(
-        weights.sum(-1, keepdims=True), 1e-9)                   # renormalize
+    weights, sel, gates = route(params["router"], x, top_k=top_k,
+                                norm_topk_prob=norm_topk_prob)  # (B,S,k)
 
     # position of each (token, slot) within its expert's capacity buffer
     oh = jax.nn.one_hot(sel, E, dtype=jnp.int32)                # (B,S,k,E)
@@ -93,7 +148,7 @@ def moe_ffn(params, x, *, top_k: int, capacity_factor: float = 1.25,
     # operand-batching dims, which GSPMD partitions along the data axes —
     # without it the scatter runs batch-replicated and the combine gather
     # lowers to a full-batch fp32 all-reduce per layer (measured 16x worse
-    # collective volume; see EXPERIMENTS.md §Perf deepseek iteration 1).
+    # collective volume).
     pos_clip = jnp.where(keep, pos_tok, C - 1)                  # drops collide
     src = jnp.where(keep[..., None], x[:, :, None, :], 0.0).astype(x.dtype)
 

@@ -14,6 +14,7 @@ from repro.models.layers import rms_norm, embed_init, dense_init
 from repro.models.blocks import (
     init_dense_block, dense_block, init_moe_block, moe_block,
     init_attn, attn_forward, init_attn_cache, decoding)
+from repro.models.moe import EXPERT_WEIGHTS
 from repro.models.ssm import (
     init_mamba2, mamba2_forward, init_mamba2_state, mamba2_decode_step)
 from repro.models import encdec
@@ -150,8 +151,8 @@ def _forward(params, cfg, batch, *, constrain=_no_constrain,
 
         def body(carry, lp):
             x, aux = carry
-            y, _, a = moe_block(lp, x, cfg, pos_info=pos_info,
-                                constrain=constrain)
+            y, _, a, _ = moe_block(lp, x, cfg, pos_info=pos_info,
+                                   constrain=constrain)
             return (y, aux + a), None
         (x, aux), _ = jax.lax.scan(_maybe_remat(body, remat), (x, aux),
                                    params["layers"])
@@ -262,6 +263,11 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
             lambda x: jnp.broadcast_to(x, (n, *x.shape)).copy(), kv())
         if cfg.family == "moe" and cfg.first_layer_dense:
             cache["dense0"] = kv()
+        if cfg.family == "moe":
+            # the held experts' work of the steps since the counts were last
+            # zeroed (the serving engine's decode block zeroes them)
+            cache["moe"] = dict(assignments=jnp.zeros((), jnp.int32),
+                                experts_hit=jnp.zeros((), jnp.int32))
     elif fam == "ssm":
         st = init_mamba2_state(batch, cfg.d_model, cfg)
         cache["layers"] = jax.tree.map(
@@ -326,19 +332,35 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
                                 page_table=page_table)
             cache = dict(cache, dense0=c0)
 
+        layers = params["layers"]
+        xs = (layers, cache["layers"])
+        if fam == "moe":
+            # the held experts' weights stay stacked over the layers, out of
+            # the scan: the expert kernel reads each layer's blocks in place,
+            # where a scanned slice would copy them every step
+            experts = {k: layers["moe"][k] for k in EXPERT_WEIGHTS}
+            moe_rest = {k: v for k, v in layers["moe"].items()
+                        if k not in experts}
+            xs = (dict(layers, moe=moe_rest), cache["layers"],
+                  jnp.arange(cfg.n_layers - int(cfg.first_layer_dense)))
+
         def body(x, inp):
-            lp, cl = inp
             if fam == "moe":
-                y, nc, _ = moe_block(lp, x, cfg, pos_info=pos_info, cache=cl,
-                                     cache_pos=pos, constrain=constrain,
-                                     page_table=page_table)
-            else:
-                y, nc = dense_block(lp, x, cfg, pos_info=pos_info, cache=cl,
-                                    cache_pos=pos, constrain=constrain,
-                                    page_table=page_table)
-            return y, nc
-        x, new_caches = jax.lax.scan(body, x, (params["layers"],
-                                               cache["layers"]))
+                lp, cl, i = inp
+                lp = dict(lp, moe=dict(lp["moe"], **experts))
+                y, nc, _, counts = moe_block(
+                    lp, x, cfg, pos_info=pos_info, cache=cl, cache_pos=pos,
+                    constrain=constrain, page_table=page_table, layer=i)
+                return y, (nc, counts)
+            lp, cl = inp
+            return dense_block(lp, x, cfg, pos_info=pos_info, cache=cl,
+                               cache_pos=pos, constrain=constrain,
+                               page_table=page_table)
+        x, new_caches = jax.lax.scan(body, x, xs)
+        if fam == "moe":
+            new_caches, counts = new_caches
+            cache = dict(cache, moe=jax.tree.map(
+                lambda c, n: c + n.sum(), cache["moe"], counts))
         cache = dict(cache, layers=new_caches)
 
     elif fam == "ssm":
@@ -404,8 +426,10 @@ def decode_step(params, cfg, cache, tokens, *, positions=None,
     """One decode step (see ``_decode_step`` for shapes/positions semantics).
 
     Kernels dispatch through ``repro.kernels.registry``; the step's ops are
-    named ``decode.attn`` / ``decode.kv_write`` / ``decode.mlp`` /
-    ``decode.head`` (``jax.named_scope``)."""
+    named ``decode.attn`` / ``decode.kv_write`` / ``decode.mlp`` (MoE:
+    ``decode.router`` and ``decode.experts`` inside it) / ``decode.head``
+    (``jax.named_scope``). MoE steps add their expert counts to
+    ``cache["moe"]``."""
     with decoding():
         return _decode_step(params, cfg, cache, tokens, positions=positions,
                             constrain=constrain, page_table=page_table)

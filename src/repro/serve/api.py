@@ -86,6 +86,10 @@ class EngineStats:
     kv_fetched_tokens: int = 0               # K/V positions the paged
                                              # kernel fetched for them: each
                                              # p + 1 rounded up to its block
+    moe_assignments: int = 0                 # (token, held expert) pairs
+                                             # the decode steps computed
+    moe_experts_hit: int = 0                 # (step, layer, held expert)
+                                             # triples with a token
     admitted: int = 0
     retired: int = 0
     shed: int = 0
@@ -147,6 +151,9 @@ class EngineStats:
              f"kv_token_reads={self.kv_token_reads}")
         if self.kv_fetched_tokens:
             s += f" kv_fetched_tokens={self.kv_fetched_tokens}"
+        if self.moe_assignments:
+            s += (f" moe_assignments={self.moe_assignments} "
+                  f"moe_experts_hit={self.moe_experts_hit}")
         if self.prefix_hits or self.cow_copies or self.page_defrags:
             s += (f" prefix_hit_rate={self.prefix_hit_rate:.2f} "
                   f"prefix_tokens={self.prefix_tokens} "

@@ -92,6 +92,10 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
     leaves are a paged pool (``repro.serve.paging``). The engine reserves
     pages covering the block's k steps before dispatch, so the table is a
     constant input to the scan, not part of the carry.
+
+    MoE caches carry the held experts' counts (``cache["moe"]``): the
+    block zeroes them first, so ``state'.cache["moe"]`` holds its own k
+    steps' sums.
     """
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
@@ -113,6 +117,10 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
                                 sds((B,), jnp.int32), page_table)[2]
         state = state._replace(cache=jax.tree.map(
             lambda x, t: x.astype(t.dtype), state.cache, target))
+        if "moe" in state.cache:
+            # the block's own expert counts, fetched with its tokens
+            state = state._replace(cache=dict(state.cache, moe=jax.tree.map(
+                jnp.zeros_like, state.cache["moe"])))
 
         # a slot whose prompt overflows the prompt buffer or the cache can
         # never satisfy ``lengths >= prompt_len - 1`` and would spin through

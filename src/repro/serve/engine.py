@@ -81,6 +81,11 @@ _M_KV_READS = obs.counter("repro_serve_kv_token_reads_total",
 _M_KV_FETCHED = obs.counter("repro_serve_kv_fetched_tokens_total",
                             "K/V positions the paged decode kernel fetched "
                             "for them (whole blocks)")
+_M_MOE_ASSIGN = obs.counter("repro_serve_moe_assignments_total",
+                            "(token, held expert) pairs the decode steps "
+                            "computed")
+_M_MOE_HIT = obs.counter("repro_serve_moe_experts_hit_total",
+                         "(step, layer, held expert) triples with a token")
 _M_REQS = obs.counter("repro_serve_requests_total",
                       "completed requests by finish reason")
 _M_PREFIX_HITS = obs.counter("repro_serve_prefix_hits_total",
@@ -127,16 +132,17 @@ class _InFlight:
     lands, because its device writes still target them.
     """
 
-    __slots__ = ("toks", "emitted", "done", "eos_hit", "lengths", "slots",
-                 "active", "live", "ticket", "deferred")
+    __slots__ = ("toks", "emitted", "done", "eos_hit", "lengths", "moe",
+                 "slots", "active", "live", "ticket", "deferred")
 
-    def __init__(self, toks, emitted, done, eos_hit, lengths, slots, active,
-                 live, ticket):
+    def __init__(self, toks, emitted, done, eos_hit, lengths, moe, slots,
+                 active, live, ticket):
         self.toks = toks                # (k, B) device tokens
         self.emitted = emitted          # (k, B) device emit mask
         self.done = done                # (B,) device done mask (post-block)
         self.eos_hit = eos_hit          # (B,) device eos branch
         self.lengths = lengths          # (B,) device lengths (post-block)
+        self.moe = moe                  # the block's expert counts (MoE)
         self.slots = slots              # slot ids owned at dispatch
         self.active = active            # (B,) host bool snapshot at dispatch
         self.live = live                # active slot count at dispatch
@@ -529,6 +535,7 @@ class Engine:
                 jnp.asarray(self._active), samp, page_table)
             return _InFlight(toks, emitted, self.state.done,
                              self.state.eos_hit, self.state.lengths,
+                             self.state.cache.get("moe"),
                              list(self._slot_req), self._active.copy(), live,
                              ticket)
 
@@ -542,14 +549,16 @@ class Engine:
         t0 = time.perf_counter()
         with obs.span("serve.fetch"):
             # one coalesced device->host transfer: k tokens + per-slot masks
+            # (+ the block's expert counts)
             fetched = jax.device_get(
-                (inf.toks, inf.emitted, inf.done, inf.eos_hit, inf.lengths))
+                (inf.toks, inf.emitted, inf.done, inf.eos_hit, inf.lengths,
+                 inf.moe))
         blocked = time.perf_counter() - t0
         with obs.span("serve.complete"):
             return self._finish_block(inf, *fetched, blocked)
 
     def _finish_block(self, inf: _InFlight, toks, emitted, done, eos_hit,
-                      len_after, blocked: float
+                      len_after, moe, blocked: float
                       ) -> Tuple[List[StreamDelta], List[Response]]:
         # fence release: slots retired while ``inf`` was in flight return to
         # the pool only now — nothing could reallocate them while the
@@ -587,6 +596,9 @@ class Engine:
             self.stats.kv_fetched_tokens += kv_fetched
         self._len_host = np.where(inf.active, len_after, self._len_host)
         self.stats.prefill_tokens += new_prefill
+        if moe is not None:
+            self.stats.moe_assignments += int(moe["assignments"])
+            self.stats.moe_experts_hit += int(moe["experts_hit"])
         if obs.enabled():
             _M_SYNCS.inc()
             _M_STEPS.inc(self.k)
@@ -594,6 +606,9 @@ class Engine:
             _M_PROCESSED.inc(processed)
             _M_KV_READS.inc(kv_reads)
             _M_KV_FETCHED.inc(kv_fetched)
+            if moe is not None:
+                _M_MOE_ASSIGN.inc(int(moe["assignments"]))
+                _M_MOE_HIT.inc(int(moe["experts_hit"]))
             _M_BLOCKED.observe(blocked)
             if overlapped:
                 _M_HIDDEN.inc()

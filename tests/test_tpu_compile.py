@@ -31,6 +31,9 @@ CELL_SLOTS, CELL_PAGES = 32, 36
 ZAMBA_H, ZAMBA_D = 32, 80
 #: mamba2-780m SSD widths: 48 heads of 64 channels, state 128
 SSD_H, SSD_P, SSD_N = 48, 64, 128
+#: the dsmoe16b.decode_sat cell's expert layers: 16 rows, top-6, 8 held
+#: experts of d 2048 and d_ff 1408 in each of 27 layers
+MOE_T, MOE_K, MOE_E, MOE_D, MOE_F, MOE_L = 16, 6, 8, 2048, 1408, 27
 
 
 @pytest.fixture(scope="module")
@@ -153,6 +156,71 @@ def test_engine_decode_block_names_its_parts_on_the_chip(chip,
         assert re.match(r"paged_flash_decode(\.|$)", name), name
         assert "/decode.attn/" in re.search(r'op_name="([^"]*)"',
                                             ln).group(1)
+
+
+def test_held_experts_compiles_at_cell_widths(chip):
+    """The held-experts kernel at the MoE cell's widths: one custom call,
+    named ``held_experts_ffn`` for the benchmark's reader, that reads one
+    layer of the stacked expert weights in place (no slice or copy of them
+    precedes it)."""
+    import re
+    from repro.kernels.moe import ops as moe_ops
+    bf = jnp.bfloat16
+    w_in, w_out = (MOE_L, MOE_E, MOE_D, MOE_F), (MOE_L, MOE_E, MOE_F, MOE_D)
+    text = compile_kernel(
+        lambda x, s, g, wg, wu, wd, i: moe_ops.held_experts_ffn(
+            x, s, g, wg, wu, wd, i, first_expert=0, interpret=False),
+        chip((MOE_T, MOE_D), bf), chip((MOE_T, MOE_K), jnp.int32),
+        chip((MOE_T, MOE_K)), chip(w_in, bf), chip(w_in, bf),
+        chip(w_out, bf), chip((), jnp.int32))
+    entry = text[text.index("ENTRY"):]
+    calls = [ln.split(" = ", 1)[0].split()[-1].lstrip("%")
+             for ln in entry.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and re.match(r"held_experts_ffn(\.|$)",
+                                        calls[0]), calls
+    layer_elems = MOE_E * MOE_D * MOE_F
+    for ln in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?bf16\[([\d,]*)\]\S* "
+                     r"(copy|copy-start|dynamic-slice|slice|fusion|reshape"
+                     r"|transpose)\(", ln)
+        if m:
+            n = int(np.prod([int(x) for x in m.group(1).split(",") if x]))
+            assert n < layer_elems, ln
+
+
+def test_moe_decode_block_names_its_experts_on_the_chip(chip, monkeypatch):
+    """The engine's k-step block for deepseek's layout at a share of the
+    experts, compiled for the chip: the expert kernel is named
+    ``held_experts_ffn.*`` under ``decode.mlp/decode.experts``, the
+    attention kernel ``paged_flash_decode.*`` under ``decode.attn``."""
+    import re
+    from repro.configs import get_arch, smoke_config
+    from repro.models import init_params
+    from repro.serve import Engine
+    from repro.serve.sampling import SlotSampling
+    cfg = smoke_config(get_arch("deepseek-moe-16b")).scaled(
+        first_expert=2, n_experts_held=2)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = Engine(params, cfg, num_slots=SLOTS, max_len=PAGE * PAGES_PER_SLOT,
+                 k=4, max_prompt=PAGE, page_size=PAGE)
+    samp = SlotSampling(temperature=eng._temp, top_p=eng._top_p,
+                        top_k=eng._top_k, key=eng.pool.slot_keys)
+    args = (eng.params, eng.state, eng._prompt_buf, eng._prompt_len,
+            eng._max_new, eng._active, samp, eng.pool.tables)
+    shapes = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    text = eng._block.lower(*shapes).compile().as_text()
+    scopes = {}
+    for ln in text.splitlines():
+        if "tpu_custom_call" in ln and " custom-call(" in ln:
+            name = ln.split(" = ", 1)[0].split()[-1].lstrip("%")
+            op = re.search(r'op_name="([^"]*)"', ln).group(1)
+            scopes.setdefault(re.sub(r"\.\d+$", "", name), []).append(op)
+    assert set(scopes) == {"held_experts_ffn", "paged_flash_decode"}
+    assert all("/decode.mlp/decode.experts/" in op
+               for op in scopes["held_experts_ffn"])
+    assert all("/decode.attn/" in op for op in scopes["paged_flash_decode"])
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
