@@ -15,8 +15,9 @@ h // group) — no KV replication in HBM.
 
 Paged decode (``paged_flash_decode``): one program per batch row (and kv
 head block) walks the row's live blocks of several whole pages, copying
-each block's pages from the pool's native (pages, P, Hkv, D) layout into a
-double-buffered VMEM scratch while the previous block is computed.
+each block's pages of one layer from the pool stacked over layers
+(L, pages, P, Hkv, D), where it lies, into a double-buffered VMEM scratch
+while the previous block is computed.
 """
 from __future__ import annotations
 
@@ -131,11 +132,12 @@ def decode_blocks(page_size: int, hkv: int, d: int, itemsize: int,
     return max(1, min(npages, BLOCK_BYTES // page(hb))), hb
 
 
-def _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, ks_pages,
-                vs_pages, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, slot_ref,
-                acc_ref, m_ref, l_ref, *, scale: float, page_size: int,
-                pages: int, ppb: int):
+def _paged_body(table_ref, valid_ref, layer_ref, q_ref, k_pages, v_pages,
+                ks_pages, vs_pages, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
+                slot_ref, acc_ref, m_ref, l_ref, *, scale: float,
+                page_size: int, pages: int, ppb: int):
     hblk, b = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     n_hb, rows = pl.num_programs(0), pl.num_programs(1)
     hb = kbuf.shape[3]                       # kv heads of this head block
     T = ppb * page_size                      # tokens of one block
@@ -147,15 +149,15 @@ def _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, ks_pages,
 
     def copies(row, hblk, blk, slot):
         """The block's page copies: whole ``(P, hb, D)`` slabs of the
-        native pool, addressed through the scalar-prefetched table."""
+        layer's pages, addressed through the scalar-prefetched table."""
         out = []
         for j in range(ppb):
             idx = blk * ppb + j
             page = jnp.where(idx < pages,
                              table_ref[row, jnp.minimum(idx, pages - 1)], 0)
             for src, dst in ((k_pages, kbuf), (v_pages, vbuf)):
-                src = src.at[page] if hb == src.shape[2] else \
-                    src.at[page, :, pl.ds(hblk * hb, hb)]
+                src = src.at[layer, page] if hb == src.shape[3] else \
+                    src.at[layer, page, :, pl.ds(hblk * hb, hb)]
                 out.append(pltpu.make_async_copy(src, dst.at[slot, j],
                                                  sem.at[slot]))
             if ks_pages is not None:
@@ -163,7 +165,7 @@ def _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, ks_pages,
                 # copy
                 for src, dst in ((ks_pages, ksbuf), (vs_pages, vsbuf)):
                     out.append(pltpu.make_async_copy(
-                        src.at[page], dst.at[slot, j], sem.at[slot]))
+                        src.at[layer, page], dst.at[slot, j], sem.at[slot]))
         return out
 
     @pl.when((hblk == 0) & (b == 0))
@@ -236,29 +238,31 @@ def _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, ks_pages,
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kernel(table_ref, valid_ref, q_ref, k_pages, v_pages, o_ref, kbuf,
-                  vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, **kw):
-    _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, None, None,
-                o_ref, kbuf, vbuf, None, None, sem, slot_ref, acc_ref, m_ref,
-                l_ref, **kw)
-
-
-def _paged_kernel_quant(table_ref, valid_ref, q_ref, k_pages, v_pages,
-                        ks_pages, vs_pages, o_ref, kbuf, vbuf, ksbuf, vsbuf,
-                        sem, slot_ref, acc_ref, m_ref, l_ref, **kw):
-    _paged_body(table_ref, valid_ref, q_ref, k_pages, v_pages, ks_pages,
-                vs_pages, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, slot_ref,
+def _paged_kernel(table_ref, valid_ref, layer_ref, q_ref, k_pages, v_pages,
+                  o_ref, kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref,
+                  **kw):
+    _paged_body(table_ref, valid_ref, layer_ref, q_ref, k_pages, v_pages,
+                None, None, o_ref, kbuf, vbuf, None, None, sem, slot_ref,
                 acc_ref, m_ref, l_ref, **kw)
 
 
+def _paged_kernel_quant(table_ref, valid_ref, layer_ref, q_ref, k_pages,
+                        v_pages, ks_pages, vs_pages, o_ref, kbuf, vbuf, ksbuf,
+                        vsbuf, sem, slot_ref, acc_ref, m_ref, l_ref, **kw):
+    _paged_body(table_ref, valid_ref, layer_ref, q_ref, k_pages, v_pages,
+                ks_pages, vs_pages, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
+                slot_ref, acc_ref, m_ref, l_ref, **kw)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
+def paged_flash_decode(q, k_pool, v_pool, table, valid, layer, k_scale=None,
                        v_scale=None, *, scale: float, interpret: bool = True):
     """Decode attention through a scalar-prefetched page table.
 
-    q (B,Hq,D); pools (num_pages, page_size, Hkv, D) in their native
-    layout, with D a lane multiple and Hkv filling whole DMA tiles
-    (:func:`heads_dma_whole`); table (B, npages) int32; valid (B,) int32.
+    q (B,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over
+    layers in their native layout, with D a lane multiple and Hkv filling
+    whole DMA tiles (:func:`heads_dma_whole`); table (B, npages) int32;
+    valid (B,) int32; layer (1,) int32: the layer of the stack read.
 
     Grid (Hkv / heads_per_block, B), sequential: one program per row (and
     head block) walks only the row's live blocks, ``ceil(valid / T)`` of
@@ -267,18 +271,18 @@ def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
     softmax update. The pools are passed whole, wherever XLA keeps them,
     and never reshaped: each block's pages are copied whole, every kv head
     at once, into a double-buffered VMEM scratch at addresses read from
-    the scalar-prefetched table, and the next block (or the next row's
-    first block) is in flight while the current one is computed. Each kv
+    the scalar-prefetched table and layer, and the next block (or the next
+    row's first block) is in flight while the current one is computed. Each kv
     head's ``Hq/Hkv`` query rows meet its keys in f32: scores, online
     softmax and accumulator as in :func:`flash_attention`.
 
     Quantized pools additionally pass ``k_scale``/``v_scale``
-    (num_pages, page_size, lanes) f32, the kv heads on the first lanes; a
+    (L, num_pages, page_size, lanes) f32, the kv heads on the first lanes; a
     page's whole scale tile rides the same copy and dequantization happens
     in-register before the MXU.
     """
     B, Hq, D = q.shape
-    num_pages, P, Hkv = k_pool.shape[:3]
+    P, Hkv = k_pool.shape[2:4]
     group = Hq // Hkv
     npages = table.shape[1]
     quant = k_scale is not None
@@ -289,14 +293,14 @@ def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
                                pages=npages, ppb=ppb)
     # index maps receive (*grid_indices, *scalar_prefetch_refs)
     q_spec = pl.BlockSpec((1, hb, group, D),
-                          lambda hblk, b, t, n: (b, hblk, 0, 0))
+                          lambda hblk, b, t, n, l: (b, hblk, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q.reshape(B, Hkv, group, D), k_pool, v_pool]
     scratch = [pltpu.VMEM((2, ppb, P, hb, D), k_pool.dtype),   # K blocks
                pltpu.VMEM((2, ppb, P, hb, D), v_pool.dtype)]   # V blocks
     if quant:
         operands += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((2, ppb, P, k_scale.shape[2]),
+        scratch += [pltpu.VMEM((2, ppb, P, k_scale.shape[3]),
                                jnp.float32)] * 2
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),          # one per buffer
@@ -306,7 +310,7 @@ def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
         pltpu.VMEM((hb, group, 1), jnp.float32),    # running denom l
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(Hkv // hb, B),
         in_specs=[q_spec] + [hbm] * (len(operands) - 1),
         out_specs=q_spec,
@@ -320,7 +324,7 @@ def paged_flash_decode(q, k_pool, v_pool, table, valid, k_scale=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(table, valid, *operands)
+    )(table, valid, layer, *operands)
     return out.reshape(B, Hq, D)
 
 

@@ -115,26 +115,29 @@ def flash_attention_bwd(res, do, *, causal: bool = True,
     return dq, dk, dv
 
 
-def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, *,
-                       k_scale=None, v_scale=None,
+def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, layer,
+                       *, k_scale=None, v_scale=None,
                        scale: float | None = None,
                        interpret: bool | None = None):
-    """Decode attention over a paged KV pool, model layout.
+    """Decode attention over one layer of a paged KV pool, model layout.
 
-    q (B,1,Hq,D); pools (num_pages, page_size, Hkv, D); page_table
-    (B, npages) int32; kv_valid_len scalar or (B,) int32 -> (B,1,Hq,D).
-    The kernel reads the pools in this layout. Only where the shapes ask
-    for it are they padded: the head dim to the 128-lane boundary, and the
-    kv heads (with zero K/V and zero queries, sliced off the output) up to
-    a count whose page rows fill the pool dtype's DMA tiles
-    (``kernel.heads_dma_whole``). ``k_scale``/``v_scale``
-    (num_pages, page_size, Hkv) f32 switch the kernel to the
-    int8-dequantizing body.
+    q (B,1,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over
+    layers (an unstacked pool passes ``pool[None]`` and layer 0, a
+    bitcast); page_table (B, npages) int32; kv_valid_len scalar or (B,)
+    int32; layer scalar int32 -> (B,1,Hq,D). Where the shapes need no
+    padding (:func:`reads_pool_in_place`) the kernel reads the stack where
+    it lies, at the scalar-prefetched layer. Otherwise the layer's pools
+    are sliced and padded for the call: the head dim to the 128-lane
+    boundary, the kv heads (with zero K/V and zero queries, sliced off the
+    output) up to a count whose page rows fill the pool dtype's DMA tiles
+    (``kernel.heads_dma_whole``), and the int8 scales' kv heads to full
+    lanes. ``k_scale``/``v_scale`` (L, num_pages, page_size, Hkv) f32
+    switch the kernel to the int8-dequantizing body.
     """
     B, S, Hq, D = q.shape
     if S != 1:
         raise ValueError(f"paged decode expects a single query, got S={S}")
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[3]
     if Hq % Hkv:
         raise ValueError(f"GQA requires Hq % Hkv == 0, got {Hq=} {Hkv=}")
     if (k_scale is None) != (v_scale is None):
@@ -145,20 +148,37 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, *,
     group = Hq // Hkv
     Dp = pad.round_up(D, LANE)
     Hp = kv_heads_padded(Hkv, k_pool.dtype)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    pools = [k_pool, v_pool] + ([] if k_scale is None else [k_scale, v_scale])
+    if not reads_pool_in_place(Hkv, D, k_pool.dtype, k_scale is not None):
+        # this layer alone, padded: a pad of the stack would copy every
+        # layer on every call
+        pools = [jax.lax.dynamic_index_in_dim(x, layer[0], 0)
+                 for x in pools]
+        layer = jnp.zeros((1,), jnp.int32)
+        pools[:2] = [pad.pad_dims(x, {3: Hp, 4: Dp}) for x in pools[:2]]
+        # a scale tile is copied whole only with its kv heads on full lanes
+        Lp = pad.round_up(Hp, LANE)
+        pools[2:] = [pad.pad_dims(x, {3: Lp}) for x in pools[2:]]
+    kp, vp, *scales = pools
+    ksp, vsp = scales if scales else (None, None)
     qp = pad.pad_dims(q.reshape(B, Hkv, group, D), {1: Hp, 3: Dp})
-    kp = pad.pad_dims(k_pool, {2: Hp, 3: Dp})
-    vp = pad.pad_dims(v_pool, {2: Hp, 3: Dp})
-    # a scale tile is copied whole only with its kv heads on full lanes
-    Lp = pad.round_up(Hp, LANE)
-    ksp = None if k_scale is None else pad.pad_dims(k_scale, {2: Lp})
-    vsp = None if v_scale is None else pad.pad_dims(v_scale, {2: Lp})
     table = jnp.asarray(page_table, jnp.int32)
     valid = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32), (B,))
     out = _k.paged_flash_decode(qp.reshape(B, Hp * group, Dp), kp, vp, table,
-                                valid, ksp, vsp, scale=scale,
+                                valid, layer, ksp, vsp, scale=scale,
                                 interpret=interpret)
     out = out.reshape(B, Hp, group, Dp)[:, :Hkv, :, :D]
     return out.reshape(B, 1, Hq, D)
+
+
+def reads_pool_in_place(hkv: int, head_dim: int, dtype,
+                        quantized: bool) -> bool:
+    """Whether :func:`paged_flash_decode` reads a stacked pool of ``hkv``
+    kv heads of ``head_dim`` where it lies: the head dim fills whole lanes,
+    the kv heads whole DMA tiles, and there are no int8 scales to pad."""
+    return (head_dim % LANE == 0 and kv_heads_padded(hkv, dtype) == hkv
+            and not quantized)
 
 
 def kv_heads_padded(hkv: int, dtype) -> int:

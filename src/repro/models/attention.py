@@ -232,47 +232,61 @@ def quantize_kv(x):
     return q, scale
 
 
-def paged_attention(q, k_pool, v_pool, page_table, kv_valid_len, *,
+def paged_attention(q, k_pool, v_pool, page_table, kv_valid_len, layer, *,
                     k_scale=None, v_scale=None, scale=None,
                     chunk: Optional[int] = None, interpret=None):
-    """Backend-dispatched decode attention over a paged KV pool.
+    """Backend-dispatched decode attention over one layer of a paged KV
+    pool.
 
-    q (B,1,Hq,D); pools (num_pages, page_size, Hkv, D); page_table
+    q (B,1,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over
+    layers (a single layer's pool passes ``pool[None]``); page_table
     (B, pages_per_slot) int32 mapping each batch row's logical pages to pool
-    pages; kv_valid_len (B,) int32 valid KV length per row. Rows past
-    ``kv_valid_len`` — including everything reached through table entry 0,
-    the serve layer's scratch page — are masked out exactly (finite values,
-    zero weight), so pool garbage never perturbs the output.
+    pages; kv_valid_len (B,) int32 valid KV length per row; layer scalar
+    int32, the layer of the stack read. Rows past ``kv_valid_len`` —
+    including everything reached through table entry 0, the serve layer's
+    scratch page — are masked out exactly (finite values, zero weight), so
+    pool garbage never perturbs the output.
 
     Quantized pools pass int8 K/V plus ``k_scale``/``v_scale``
-    (num_pages, page_size, Hkv) f32; both impls dequantize on read
+    (L, num_pages, page_size, Hkv) f32; both impls dequantize on read
     (``x = int8 * scale``), so the score/output math runs in the same
     precision as the f32 path and the only error is the per-row rounding
     bounded by ``scale/2 = absmax/254`` per element.
 
-    The xla impl gathers the table into dense rows and reuses
+    The xla impl gathers the layer's table rows into dense rows and reuses
     :func:`chunked_attention` — bitwise the slot-engine decode path. The
     pallas impl (decode-only, S == 1) copies each row's live blocks of
-    whole pages straight from the pool through a scalar-prefetched table,
-    never materialising the gather.
+    whole pages straight from the stack through a scalar-prefetched table
+    and layer, never materialising the gather.
     """
     return registry.dispatch(
         "paged_attention", q, k_pool, v_pool, page_table, kv_valid_len,
-        k_scale=k_scale, v_scale=v_scale, scale=scale, chunk=chunk,
+        layer, k_scale=k_scale, v_scale=v_scale, scale=scale, chunk=chunk,
         interpret=interpret)
 
 
-def _paged_attention_xla(q, k_pool, v_pool, page_table, kv_valid_len, *,
-                         k_scale=None, v_scale=None, scale=None,
+def paged_pool_in_place(kv_leaf, quantized: bool) -> bool:
+    """Whether ``paged_attention``, under the active backend policy, reads
+    a stacked pool of ``kv_leaf``'s ``(..., Hkv, D)`` pages where it lies:
+    the xla impl gathers only the table's rows; the pallas kernel does so
+    where its shapes need no padding (``ops.reads_pool_in_place``)."""
+    if registry.resolved_backend() != "pallas":
+        return True
+    return _fa_ops.reads_pool_in_place(kv_leaf.shape[-2], kv_leaf.shape[-1],
+                                       kv_leaf.dtype, quantized)
+
+
+def _paged_attention_xla(q, k_pool, v_pool, page_table, kv_valid_len, layer,
+                         *, k_scale=None, v_scale=None, scale=None,
                          chunk: Optional[int] = None, interpret=None):
     del interpret                                  # pallas-only knob
     B = q.shape[0]
-    Hkv, D = k_pool.shape[2], k_pool.shape[3]
-    k = k_pool[page_table].reshape(B, -1, Hkv, D)
-    v = v_pool[page_table].reshape(B, -1, Hkv, D)
+    Hkv, D = k_pool.shape[3], k_pool.shape[4]
+    k = k_pool[layer, page_table].reshape(B, -1, Hkv, D)
+    v = v_pool[layer, page_table].reshape(B, -1, Hkv, D)
     if k_scale is not None:
-        ks = k_scale[page_table].reshape(B, -1, Hkv)
-        vs = v_scale[page_table].reshape(B, -1, Hkv)
+        ks = k_scale[layer, page_table].reshape(B, -1, Hkv)
+        vs = v_scale[layer, page_table].reshape(B, -1, Hkv)
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
     # decode reads are right-aligned single queries: causal=False + the
@@ -282,12 +296,12 @@ def _paged_attention_xla(q, k_pool, v_pool, page_table, kv_valid_len, *,
                              kv_valid_len=kv_valid_len)
 
 
-def _paged_attention_pallas(q, k_pool, v_pool, page_table, kv_valid_len, *,
-                            k_scale=None, v_scale=None, scale=None,
+def _paged_attention_pallas(q, k_pool, v_pool, page_table, kv_valid_len,
+                            layer, *, k_scale=None, v_scale=None, scale=None,
                             chunk: Optional[int] = None, interpret=None):
     del chunk                                      # xla-only knob
     return _fa_ops.paged_flash_decode(q, k_pool, v_pool, page_table,
-                                      kv_valid_len, k_scale=k_scale,
+                                      kv_valid_len, layer, k_scale=k_scale,
                                       v_scale=v_scale, scale=scale,
                                       interpret=interpret)
 
@@ -296,17 +310,18 @@ def _paged_make_inputs(shape, dtype=jnp.float32):
     B, Hq, D, Hkv, npg, P = shape
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), dtype)
-    k = jax.random.normal(ks[1], (1 + B * npg, P, Hkv, D), dtype)
-    v = jax.random.normal(ks[2], (1 + B * npg, P, Hkv, D), dtype)
+    k = jax.random.normal(ks[1], (1, 1 + B * npg, P, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (1, 1 + B * npg, P, Hkv, D), dtype)
     table = jnp.arange(1, 1 + B * npg, dtype=jnp.int32).reshape(B, npg)
     valid = jnp.full((B,), npg * P, jnp.int32)
-    return (q, k, v, table, valid), {}
+    return (q, k, v, table, valid, jnp.zeros((), jnp.int32)), {}
 
 
 registry.describe(
     "paged_attention",
-    shape_of=lambda q, k, v, t, n, **kw: (q.shape[0], q.shape[2], q.shape[3],
-                                          k.shape[2], t.shape[1], k.shape[1]),
+    shape_of=lambda q, k, v, t, n, l, **kw: (q.shape[0], q.shape[2],
+                                             q.shape[3], k.shape[3],
+                                             t.shape[1], k.shape[2]),
     make_inputs=_paged_make_inputs)
 registry.register("paged_attention", "xla",
                   tunables=("chunk",))(_paged_attention_xla)

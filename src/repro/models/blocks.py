@@ -91,7 +91,8 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
                  positions=None, mrope_pos=None, rope_theta: float = 1e4,
                  causal: bool = True, cache: Optional[dict] = None,
                  cache_pos=None, kv_override=None, constrain=lambda x, s: x,
-                 attn_chunk: Optional[int] = None, page_table=None):
+                 attn_chunk: Optional[int] = None, page_table=None,
+                 layer=None):
     """GQA attention. x (B,S,d).
 
     cache: dict(k=(B,Smax,Hkv,Dh), v=...) updated at cache_pos (decode).
@@ -103,6 +104,10 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
     pool (num_pages, page_size, Hkv, Dh) and position p of batch row b lives
     at pool page ``page_table[b, p // page_size]``, row ``p % page_size``
     (decode-only: requires S == 1 and per-row ``cache_pos``).
+    layer: with ``page_table``, the cache leaves are the pool stacked over
+    layers, ``(L, num_pages, page_size, Hkv, Dh)``, and this call writes
+    its rows into and reads layer ``layer`` of the stack where it lies
+    (None: the leaves are one layer's pool).
     Returns (out, new_cache).
     """
     B, S, d = x.shape
@@ -144,33 +149,32 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
         if S != 1 or not getattr(cache_pos, "ndim", 0):
             raise ValueError("paged KV cache is decode-only: S == 1 with "
                              "per-row cache_pos")
-        P_pg = cache["k"].shape[1]
+        # one layer's pool is the stack of one layer (a bitcast)
+        pool = cache if layer is not None else \
+            {n: a[None] for n, a in cache.items()}
+        li = 0 if layer is None else layer
+        P_pg = pool["k"].shape[2]
         pidx = jnp.take_along_axis(page_table, cache_pos[:, None] // P_pg,
                                    axis=1)[:, 0]
         off = cache_pos % P_pg
-        if "k_scale" in cache:
-            # int8 pool: quantize on scatter — codes and their
-            # per-(row, head) scales land in the same page/row, so a page is
-            # self-describing and CoW/defrag/trie sharing move both together
-            with decode_scope("kv_write"):
-                kq, ks = quantize_kv(k[:, 0])
-                vq, vs = quantize_kv(v[:, 0])
-                kc = cache["k"].at[pidx, off].set(kq)
-                vc = cache["v"].at[pidx, off].set(vq)
-                kcs = cache["k_scale"].at[pidx, off].set(ks)
-                vcs = cache["v_scale"].at[pidx, off].set(vs)
-            new_cache = dict(k=kc, v=vc, k_scale=kcs, v_scale=vcs)
-            o = paged_attention(q, kc, vc, page_table, cache_pos + 1,
-                                k_scale=kcs, v_scale=vcs, chunk=attn_chunk)
-        else:
-            with decode_scope("kv_write"):
-                kc = cache["k"].at[pidx, off].set(
-                    k[:, 0].astype(cache["k"].dtype))
-                vc = cache["v"].at[pidx, off].set(
-                    v[:, 0].astype(cache["v"].dtype))
-            new_cache = dict(k=kc, v=vc)
-            o = paged_attention(q, kc, vc, page_table, cache_pos + 1,
-                                chunk=attn_chunk)
+        # B rows a step, scattered into the pool where it lies
+        with decode_scope("kv_write"):
+            if "k_scale" in pool:
+                # int8 pool: quantize on scatter — codes and their
+                # per-(row, head) scales land in the same page/row, so a
+                # page is self-describing and CoW/defrag/trie sharing move
+                # both together
+                (kq, ks), (vq, vs) = quantize_kv(k[:, 0]), quantize_kv(v[:, 0])
+                rows = dict(k=kq, v=vq, k_scale=ks, v_scale=vs)
+            else:
+                rows = dict(k=k[:, 0], v=v[:, 0])
+            pool = {n: pool[n].at[li, pidx, off].set(
+                r.astype(pool[n].dtype)) for n, r in rows.items()}
+        o = paged_attention(q, pool["k"], pool["v"], page_table,
+                            cache_pos + 1, li, k_scale=pool.get("k_scale"),
+                            v_scale=pool.get("v_scale"), chunk=attn_chunk)
+        new_cache = pool if layer is not None else \
+            {n: a[0] for n, a in pool.items()}
         o = o.reshape(B, S, n_heads * head_dim)
         out = jnp.einsum("bsh,hd->bsd", o, params["wo"].astype(x.dtype))
         return constrain(out, ("batch", None, None)), new_cache
@@ -221,7 +225,8 @@ def init_dense_block(key, cfg, dtype=jnp.float32):
 
 
 def dense_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
-                constrain=lambda x, s: x, page_table=None):
+                constrain=lambda x, s: x, page_table=None, layer=None):
+    """``layer``: see ``attn_forward``."""
     with decode_scope("attn"):
         h, new_cache = attn_forward(
             params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
@@ -229,7 +234,7 @@ def dense_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
             positions=pos_info.get("positions"),
             mrope_pos=pos_info.get("mrope"), rope_theta=cfg.rope_theta,
             cache=cache, cache_pos=cache_pos, constrain=constrain,
-            page_table=page_table)
+            page_table=page_table, layer=layer)
         x = x + h
     with decode_scope("mlp"):
         x = x + swiglu(params["mlp"],
@@ -258,7 +263,7 @@ def moe_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
     load-balance ``aux`` and no counts; decoding, or holding a share of the
     experts, the dropless held-experts layer gives ``counts``
     (``moe.expert_counts``) and a zero ``aux``. ``layer``: see
-    ``moe.held_experts``."""
+    ``moe.held_experts`` and ``attn_forward``."""
     with decode_scope("attn"):
         h, new_cache = attn_forward(
             params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
@@ -266,7 +271,7 @@ def moe_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
             positions=pos_info.get("positions"),
             mrope_pos=pos_info.get("mrope"), rope_theta=cfg.rope_theta,
             cache=cache, cache_pos=cache_pos, constrain=constrain,
-            page_table=page_table)
+            page_table=page_table, layer=layer)
         x = x + h
     with decode_scope("mlp"):
         mp = params["moe"]

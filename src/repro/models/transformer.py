@@ -305,7 +305,10 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
 
     page_table: optional (B, pages_per_slot) int32 — the attention K/V
     leaves are a paged pool (see ``repro.serve.paging``) and every attention
-    read/write goes through the table. Requires per-row ``positions``.
+    read/write goes through the table. Requires per-row ``positions``. The
+    dense/vlm/moe layer scan carries the pool stacked over its layers, so
+    a step writes its rows into the pool where it lies (nothing reads the
+    carried pool after the scatter, so XLA updates it in place).
     Recurrent leaves (mamba conv/ssm, whisper cross-K/V) are pageless and
     ignore it."""
     fam = cfg.family
@@ -333,32 +336,44 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
             cache = dict(cache, dense0=c0)
 
         layers = params["layers"]
-        xs = (layers, cache["layers"])
+        n = cfg.n_layers - int(fam == "moe" and cfg.first_layer_dense)
         if fam == "moe":
             # the held experts' weights stay stacked over the layers, out of
             # the scan: the expert kernel reads each layer's blocks in place,
             # where a scanned slice would copy them every step
             experts = {k: layers["moe"][k] for k in EXPERT_WEIGHTS}
-            moe_rest = {k: v for k, v in layers["moe"].items()
-                        if k not in experts}
-            xs = (dict(layers, moe=moe_rest), cache["layers"],
-                  jnp.arange(cfg.n_layers - int(cfg.first_layer_dense)))
+            layers = dict(layers, moe={k: v for k, v in layers["moe"].items()
+                                       if k not in experts})
+        # a paged pool rides the carry, stacked over the layers: each layer
+        # scatters its rows into it and the attention reads it where it
+        # lies, where a scanned slice and a stacked result would copy it
+        # every step. Slot-layout caches are scanned per layer.
+        paged = page_table is not None
+        xs = (layers, jnp.arange(n)) + (() if paged else (cache["layers"],))
 
-        def body(x, inp):
+        def body(carry, inp):
+            lp, i = inp[:2]
+            x, cl = carry if paged else (carry, inp[2])
             if fam == "moe":
-                lp, cl, i = inp
                 lp = dict(lp, moe=dict(lp["moe"], **experts))
                 y, nc, _, counts = moe_block(
                     lp, x, cfg, pos_info=pos_info, cache=cl, cache_pos=pos,
                     constrain=constrain, page_table=page_table, layer=i)
-                return y, (nc, counts)
-            lp, cl = inp
-            return dense_block(lp, x, cfg, pos_info=pos_info, cache=cl,
-                               cache_pos=pos, constrain=constrain,
-                               page_table=page_table)
-        x, new_caches = jax.lax.scan(body, x, xs)
+            else:
+                (y, nc), counts = dense_block(
+                    lp, x, cfg, pos_info=pos_info, cache=cl, cache_pos=pos,
+                    constrain=constrain, page_table=page_table,
+                    layer=i), None
+            if paged:
+                return (y, nc), counts
+            return y, (nc, counts)
+        carry, ys = jax.lax.scan(body, (x, cache["layers"]) if paged else x,
+                                 xs)
+        if paged:
+            (x, new_caches), counts = carry, ys
+        else:
+            x, (new_caches, counts) = carry, ys
         if fam == "moe":
-            new_caches, counts = new_caches
             cache = dict(cache, moe=jax.tree.map(
                 lambda c, n: c + n.sum(), cache["moe"], counts))
         cache = dict(cache, layers=new_caches)

@@ -86,6 +86,9 @@ class EngineStats:
     kv_fetched_tokens: int = 0               # K/V positions the paged
                                              # kernel fetched for them: each
                                              # p + 1 rounded up to its block
+    kv_in_place_blocks: int = 0              # blocks whose stacked paged
+                                             # K/V pool went through without
+                                             # a copy (= syncs when it can)
     moe_assignments: int = 0                 # (token, held expert) pairs
                                              # the decode steps computed
     moe_experts_hit: int = 0                 # (step, layer, held expert)
@@ -151,6 +154,8 @@ class EngineStats:
              f"kv_token_reads={self.kv_token_reads}")
         if self.kv_fetched_tokens:
             s += f" kv_fetched_tokens={self.kv_fetched_tokens}"
+        if self.kv_in_place_blocks:
+            s += f" kv_in_place_blocks={self.kv_in_place_blocks}"
         if self.moe_assignments:
             s += (f" moe_assignments={self.moe_assignments} "
                   f"moe_experts_hit={self.moe_experts_hit}")

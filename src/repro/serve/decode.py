@@ -28,6 +28,13 @@ all k next-token draws (temperature / top-p / top-k, per-slot PRNG keys)
 happen inside the scan body — see ``repro.serve.sampling`` — so stochastic
 decode costs exactly as many host syncs as greedy: one per k tokens.
 
+The decode state is donated to the block: its buffers become the block's
+carry and its result, so the paged K/V pool lives in one device buffer
+from block to block and each step writes its rows into it in place. A
+caller never touches a state after passing it to a block; what the host
+fetches afterwards (``BlockOut``) has buffers of its own, which the next
+block's donation leaves intact.
+
 Overlap contract (the engine's double-buffered loop, ``overlap=True``): a
 block is a pure function of its dispatch-time inputs — every host-side
 argument (prompt buffer, sampling policy, page table) is device-copied at
@@ -63,11 +70,30 @@ class DecodeState(NamedTuple):
                              # no length cause fired the same step)
 
 
+class BlockOut(NamedTuple):
+    """What the host fetches from one block, in buffers of its own."""
+    toks: jnp.ndarray        # (k, B) int32: the k steps' tokens (-1: none)
+    emitted: jnp.ndarray     # (k, B) bool: tokens[t, b] is valid
+    done: jnp.ndarray        # (B,) bool, after the block
+    eos_hit: jnp.ndarray     # (B,) bool, after the block
+    lengths: jnp.ndarray     # (B,) int32, after the block
+    moe: Any                 # the block's expert counts (MoE), else None
+
+
 def init_decode_state(cache, num_slots: int) -> DecodeState:
-    z = jnp.zeros((num_slots,), jnp.int32)
-    f = jnp.zeros((num_slots,), bool)
-    return DecodeState(cache=cache, lengths=z, last_tok=z, n_out=z,
-                       done=f, eos_hit=f)
+    """A fresh state whose leaves are distinct buffers (the block donates
+    each one; a buffer shared by two leaves cannot be donated twice)."""
+    seen = set()
+
+    def own(x):
+        if id(x) in seen:
+            return jnp.array(x, copy=True)
+        seen.add(id(x))
+        return x
+    z = lambda dt: jnp.zeros((num_slots,), dt)
+    return DecodeState(cache=jax.tree.map(own, cache), lengths=z(jnp.int32),
+                       last_tok=z(jnp.int32), n_out=z(jnp.int32),
+                       done=z(bool), eos_hit=z(bool))
 
 
 def make_decode_block(cfg, rules, *, k: int, max_len: int,
@@ -75,13 +101,15 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
     """Build the jitted k-step block.
 
     block(params, state, prompts, prompt_len, max_new, active, samp=None,
-          page_table=None) ->
-      (state', tokens (k, B) int32, emitted (k, B) bool)
+          page_table=None) -> (state', BlockOut)
+
+    ``state`` is donated: its buffers are the block's carry and become
+    ``state'``'s, so the caller must not use ``state`` again.
 
     prompts (B, P) holds each slot's prompt; a slot is *prefilling* while
-    ``lengths < prompt_len`` and *decoding* after. ``tokens[t, b]`` is valid
-    iff ``emitted[t, b]`` (non-emitting steps carry -1). One host sync
-    retrieves k tokens: the k-fold latency saving.
+    ``lengths < prompt_len`` and *decoding* after. ``out.toks[t, b]`` is
+    valid iff ``out.emitted[t, b]`` (non-emitting steps carry -1). One host
+    sync retrieves k tokens: the k-fold latency saving.
 
     samp: optional ``SlotSampling`` — per-slot temperature/top-p/top-k and
     PRNG keys; every draw happens inside the scan (``sample_tokens``), so
@@ -94,8 +122,7 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
     constant input to the scan, not part of the carry.
 
     MoE caches carry the held experts' counts (``cache["moe"]``): the
-    block zeroes them first, so ``state'.cache["moe"]`` holds its own k
-    steps' sums.
+    block zeroes them first, so ``out.moe`` holds its own k steps' sums.
     """
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
@@ -176,6 +203,7 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
             return new, (out, emit)
 
         state, (toks, emitted) = jax.lax.scan(body, state, xs=None, length=k)
-        return state, toks, emitted
+        return state, BlockOut(toks, emitted, state.done, state.eos_hit,
+                               state.lengths, state.cache.get("moe"))
 
-    return jax.jit(block)
+    return jax.jit(block, donate_argnums=(1,))

@@ -51,13 +51,15 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels.flash_attention.ops import block_tokens
 from repro.models import init_cache
+from repro.models.attention import paged_pool_in_place
 from repro.models.transformer import prefill_audio_cache
 from repro.serve.api import (Request, Response, EngineStats, StreamDelta,
                              FINISH_EOS, FINISH_ERROR, FINISH_LENGTH,
                              FINISH_SHED)
 from repro.serve.cache import CachePool
 from repro.serve.paging import PagedCachePool
-from repro.serve.decode import init_decode_state, make_decode_block
+from repro.serve.decode import (BlockOut, init_decode_state,
+                                make_decode_block)
 from repro.serve.sampling import GREEDY, SlotSampling, host_fold_in
 from repro.serve.scheduler import Scheduler
 
@@ -81,6 +83,9 @@ _M_KV_READS = obs.counter("repro_serve_kv_token_reads_total",
 _M_KV_FETCHED = obs.counter("repro_serve_kv_fetched_tokens_total",
                             "K/V positions the paged decode kernel fetched "
                             "for them (whole blocks)")
+_M_KV_IN_PLACE = obs.counter("repro_serve_kv_in_place_blocks_total",
+                             "decode blocks whose stacked paged K/V pool went "
+                             "through without a copy")
 _M_MOE_ASSIGN = obs.counter("repro_serve_moe_assignments_total",
                             "(token, held expert) pairs the decode steps "
                             "computed")
@@ -123,26 +128,20 @@ def _fetched_upto(n: np.ndarray, block: int) -> np.ndarray:
 class _InFlight:
     """One dispatched-but-not-fetched k-block (the pipeline entry).
 
-    Captures the block's raw output arrays at dispatch — before any later
-    admission functionally updates ``Engine.state`` — so completion reads
-    exactly what this block computed. ``slots``/``active`` snapshot the slot
+    Holds the block's ``BlockOut``, buffers of their own that neither later
+    admissions nor the next block's donation of ``Engine.state`` touch, so
+    completion reads exactly what this block computed. ``slots``/``active``
+    snapshot the slot
     ownership at dispatch: completion only touches rows this block owned,
     and ``deferred`` collects slots retired while the block was in flight —
     their pool rows stay fenced (allocated, unreusable) until the block
     lands, because its device writes still target them.
     """
 
-    __slots__ = ("toks", "emitted", "done", "eos_hit", "lengths", "moe",
-                 "slots", "active", "live", "ticket", "deferred")
+    __slots__ = ("out", "slots", "active", "live", "ticket", "deferred")
 
-    def __init__(self, toks, emitted, done, eos_hit, lengths, moe, slots,
-                 active, live, ticket):
-        self.toks = toks                # (k, B) device tokens
-        self.emitted = emitted          # (k, B) device emit mask
-        self.done = done                # (B,) device done mask (post-block)
-        self.eos_hit = eos_hit          # (B,) device eos branch
-        self.lengths = lengths          # (B,) device lengths (post-block)
-        self.moe = moe                  # the block's expert counts (MoE)
+    def __init__(self, out: BlockOut, slots, active, live, ticket):
+        self.out = out                  # the block's device results
         self.slots = slots              # slot ids owned at dispatch
         self.active = active            # (B,) host bool snapshot at dispatch
         self.live = live                # active slot count at dispatch
@@ -232,6 +231,12 @@ class Engine:
             self._kv_block = block_tokens(pool.page_size, kv.shape[-2],
                                           kv.shape[-1], kv.dtype,
                                           pool.pages_per_slot)
+        # the dense/vlm/moe layer scan carries a paged pool stacked over its
+        # layers, the block's donated state keeps it in one buffer, and the
+        # attention op reads it where it lies unless the kernel must pad it
+        self._kv_in_place = (
+            self.paged and cfg.family in ("dense", "vlm", "moe")
+            and paged_pool_in_place(pool.kv_leaf(), pool.quantized))
         self.prefix_on = (bool(prefix_cache) and self.paged
                           and cfg.family in ("dense", "vlm", "moe"))
         self.scheduler = scheduler if scheduler is not None else Scheduler()
@@ -529,15 +534,13 @@ class Engine:
                 self.stats.peak_live_pages = max(
                     self.stats.peak_live_pages, self.pool.live_page_count())
             ticket = obs.mark_dispatch("serve.dispatch")
-            self.state, toks, emitted = self._block(
+            # the state is donated: the block's result replaces it
+            self.state, out = self._block(
                 self.params, self.state, jnp.asarray(self._prompt_buf),
                 jnp.asarray(self._prompt_len), jnp.asarray(self._max_new),
                 jnp.asarray(self._active), samp, page_table)
-            return _InFlight(toks, emitted, self.state.done,
-                             self.state.eos_hit, self.state.lengths,
-                             self.state.cache.get("moe"),
-                             list(self._slot_req), self._active.copy(), live,
-                             ticket)
+            return _InFlight(out, list(self._slot_req), self._active.copy(),
+                             live, ticket)
 
     def _complete_block(self, inf: _InFlight
                         ) -> Tuple[List[StreamDelta], List[Response]]:
@@ -550,9 +553,7 @@ class Engine:
         with obs.span("serve.fetch"):
             # one coalesced device->host transfer: k tokens + per-slot masks
             # (+ the block's expert counts)
-            fetched = jax.device_get(
-                (inf.toks, inf.emitted, inf.done, inf.eos_hit, inf.lengths,
-                 inf.moe))
+            fetched = jax.device_get(inf.out)
         blocked = time.perf_counter() - t0
         with obs.span("serve.complete"):
             return self._finish_block(inf, *fetched, blocked)
@@ -570,6 +571,7 @@ class Engine:
         deltas: List[StreamDelta] = []
         self.stats.syncs += 1
         self.stats.steps += self.k
+        self.stats.kv_in_place_blocks += int(self._kv_in_place)
         self.stats.occupancy_sum += inf.live / self.pool.num_slots
         self.stats.host_blocked_s += blocked
         if overlapped:
@@ -606,6 +608,7 @@ class Engine:
             _M_PROCESSED.inc(processed)
             _M_KV_READS.inc(kv_reads)
             _M_KV_FETCHED.inc(kv_fetched)
+            _M_KV_IN_PLACE.inc(int(self._kv_in_place))
             if moe is not None:
                 _M_MOE_ASSIGN.inc(int(moe["assignments"]))
                 _M_MOE_HIT.inc(int(moe["experts_hit"]))

@@ -24,7 +24,7 @@ from repro.dist.sharding import make_rules
 from repro.kernels import registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
-from repro.models.attention import paged_attention
+from repro.models.attention import paged_attention, quantize_kv
 from repro.serve import (CachePool, Engine, PagedCachePool, PageError,
                          PrefixCache, Request, SamplingParams, Scheduler,
                          FINISH_EOS, FINISH_LENGTH)
@@ -134,6 +134,45 @@ def test_prefix_cache_streams_bit_identical(family_setup):
         assert s_on.prefix_hits == 0 and s_on.prefix_tokens == 0
 
 
+@pytest.mark.parametrize("overlap", [False, True], ids=["blocking",
+                                                      "overlap"])
+def test_donated_pool_survives_the_engines_moves(overlap):
+    """The decode block takes the state donated, so the paged pool lives in
+    one buffer from block to block. Between blocks the engine admits, takes
+    a prefix hit with a copy-on-write page, compacts its slots and its
+    pages, and (overlapped) fences retired slots and fetches a block after
+    the next one took the state: no buffer is used after its donation,
+    every block carries the pool without a copy, and the streams equal the
+    slot engine's."""
+    params = init_params(CFG_TINY, jax.random.PRNGKey(0))
+    shared = [11, 12, 13, 14, 15, 16, 17, 18]
+    # slots 0 and 2 retire first, leaving holes; the last two requests
+    # arrive once the first wave has published the two shared pages: one
+    # matches both, one diverges inside the second (copy on write)
+    prompts = [shared + [i + 1] for i in range(5)] + [shared[:6] + [99, 98]]
+    reqs = lambda: [Request(id=f"m{i}", prompt=p,
+                            max_new_tokens=2 if i % 2 == 0 else 10)
+                    for i, p in enumerate(prompts)]
+    with registry.use("xla"):
+        want = Engine(params, CFG_TINY, num_slots=4, max_len=MAX_LEN, k=2,
+                      max_prompt=12).run(reqs())
+        eng = Engine(params, CFG_TINY, num_slots=4, max_len=MAX_LEN, k=2,
+                     max_prompt=12, page_size=4, prefix_cache=True,
+                     defrag_threshold=0.25, overlap=overlap)
+        for r in reqs():
+            eng.submit(r)
+        first_pool = eng.state.cache["layers"]["k"]
+        out = eng.step()
+        assert first_pool.is_deleted()           # donated to the block
+        while not eng._drained():
+            out += eng.step()
+    assert {r.id: r.tokens for r in out} == {r.id: r.tokens for r in want}
+    st = eng.stats
+    assert st.admitted == 6 and st.prefix_hits >= 1 and st.cow_copies >= 1
+    assert st.defrags >= 1 and st.page_defrags >= 1
+    assert st.kv_in_place_blocks == st.syncs > 0
+
+
 #: op-level cases of the paged decode kernel against the XLA gather+mask
 #: impl: (B, Hq, Hkv, D, page_size, pages a row, valid lengths), the valid
 #: lengths given from the page size ``P``, the case's kernel block ``bt``
@@ -153,18 +192,40 @@ PAGED_CASES = {
 }
 
 
-def paged_case(name, dtype=jnp.float32):
-    """Inputs of ``PAGED_CASES[name]``: random pools, each row's live pages
-    distinct, and the table's tail past ``valid`` pointing at the scratch
-    page 0 (odd rows) or at stale pages of other rows (even rows)."""
+#: pools stacked over layers: (shape as in ``PAGED_CASES``, pool dtype,
+#: layers, the layer read). bf16 and int8 at the cell's 8 kv heads of 128,
+#: and 32 kv heads on 64-token pages, where one page of K+V outgrows a
+#: kernel block and the kernel splits the heads over its grid: of 128
+#: channels, read in place, and of zamba2's 80, padded
+STACKED_CASES = {
+    "stacked_bf16_layer2": (PAGED_CASES["ragged_valid"], jnp.bfloat16, 3, 2),
+    "stacked_int8_layer1": (PAGED_CASES["ragged_valid"], jnp.int8, 3, 1),
+    "stacked_head_blocks_layer1": (
+        (2, 32, 32, 128, 64, 3, lambda P, bt, full: [full, P + 3]),
+        jnp.bfloat16, 2, 1),
+    "stacked_zamba2_layer1": (
+        (2, 32, 32, 80, 64, 3, lambda P, bt, full: [P - 1, full]),
+        jnp.bfloat16, 2, 1),
+}
+
+
+def paged_case(name, dtype=jnp.float32, layers=None):
+    """Inputs of ``PAGED_CASES[name]`` (or a tuple of its form): random
+    pools, each row's live pages distinct, and the table's tail past
+    ``valid`` pointing at the scratch page 0 (odd rows) or at stale pages
+    of other rows (even rows). ``layers``: pools stacked over that many
+    layers, each layer's values its own."""
     from repro.kernels.flash_attention.ops import block_tokens
-    B, Hq, Hkv, D, P, npg, valid = PAGED_CASES[name]
+    B, Hq, Hkv, D, P, npg, valid = PAGED_CASES.get(name, name)
     valid = valid(P, block_tokens(P, Hkv, D, dtype, npg), npg * P)
     assert max(valid) <= npg * P
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    lead = () if layers is None else (layers,)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (1 + B * npg, P, Hkv, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (1 + B * npg, P, Hkv, D), jnp.float32)
+    k_pool = jax.random.normal(ks[1], lead + (1 + B * npg, P, Hkv, D),
+                               jnp.float32)
+    v_pool = jax.random.normal(ks[2], lead + (1 + B * npg, P, Hkv, D),
+                               jnp.float32)
     pages = 1 + np.random.default_rng(0).permutation(B * npg)
     table = pages.reshape(B, npg)
     live = np.arange(npg)[None] * P < np.asarray(valid)[:, None]
@@ -174,20 +235,45 @@ def paged_case(name, dtype=jnp.float32):
                                                               jnp.int32)
 
 
-@pytest.mark.parametrize("case", list(PAGED_CASES))
+@pytest.mark.parametrize("case", list(PAGED_CASES) + list(STACKED_CASES))
 def test_paged_attention_pallas_matches_xla(case):
     """Op level: the paged kernel, which walks each row's live blocks of
     whole pages, agrees with the XLA gather+mask reference at ragged valid
     lengths on page and block boundaries, a horizon its block does not
     divide, GQA groups 1 to 6, one row, and table entries past ``valid``
-    pointing at the scratch page or at other rows' pages."""
-    q, k_pool, v_pool, table, valid = paged_case(case)
+    pointing at the scratch page or at other rows' pages. Stacked pools:
+    both impls read layer ``layer`` of the stack exactly as they read that
+    layer's pool alone, in bf16, int8 (with scales) and head blocks."""
+    if case in PAGED_CASES:
+        q, k_pool, v_pool, table, valid = paged_case(case)
+        k_pool, v_pool, layer, scales = k_pool[None], v_pool[None], 0, {}
+    else:
+        shape, dtype, layers, layer = STACKED_CASES[case]
+        q, k_pool, v_pool, table, valid = paged_case(shape, dtype, layers)
+        scales = {}
+        if dtype == jnp.int8:
+            k_pool, ks = quantize_kv(k_pool)
+            v_pool, vs = quantize_kv(v_pool)
+            scales = dict(k_scale=ks, v_scale=vs)
+        else:
+            k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
+        with registry.use("xla"):
+            alone = paged_attention(
+                q, k_pool[layer][None], v_pool[layer][None], table, valid,
+                0, **{n: s[layer][None] for n, s in scales.items()})
     with registry.use("xla"):
-        ref = paged_attention(q, k_pool, v_pool, table, valid)
+        ref = paged_attention(q, k_pool, v_pool, table, valid, layer,
+                              **scales)
     with registry.use("pallas"):
-        got = paged_attention(q, k_pool, v_pool, table, valid)
+        got = paged_attention(q, k_pool, v_pool, table, valid, layer,
+                              **scales)
+    # over bf16 pools the xla impl rounds the softmax weights to bf16 for
+    # p.v (2**-9 relative), the kernel keeps them f32
+    tol = 5e-3 if k_pool.dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+                               rtol=tol, atol=tol)
+    if case in STACKED_CASES:
+        np.testing.assert_array_equal(np.asarray(ref), np.asarray(alone))
 
 
 # ------------------------------------------------------- pool bookkeeping --

@@ -75,12 +75,13 @@ def test_paged_attention_quantized_parity(case):
     q, k_pool, v_pool, table, valid = paged_case(case, jnp.int8)
     kq, kscale = quantize_kv(k_pool)
     vq, vscale = quantize_kv(v_pool)
+    kq, vq, kscale, vscale = kq[None], vq[None], kscale[None], vscale[None]
     with registry.use("xla"):
-        ref = paged_attention(q, k_pool, v_pool, table, valid)
-        got_x = paged_attention(q, kq, vq, table, valid,
+        ref = paged_attention(q, k_pool[None], v_pool[None], table, valid, 0)
+        got_x = paged_attention(q, kq, vq, table, valid, 0,
                                 k_scale=kscale, v_scale=vscale)
     with registry.use("pallas"):
-        got_p = paged_attention(q, kq, vq, table, valid,
+        got_p = paged_attention(q, kq, vq, table, valid, 0,
                                 k_scale=kscale, v_scale=vscale)
     np.testing.assert_allclose(np.asarray(got_p), np.asarray(got_x),
                                rtol=2e-5, atol=2e-5)

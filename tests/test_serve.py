@@ -297,7 +297,6 @@ def test_decode_block_retires_unservable_prompt():
     prompt_len = jnp.asarray([30, 2], jnp.int32)   # slot 0 can never emit
     max_new = jnp.asarray([4, 4], jnp.int32)
     active = jnp.asarray([True, True])
-    state, toks, emitted = block(params, state, prompts, prompt_len,
-                                 max_new, active)
-    assert bool(state.done[0]) and not np.asarray(emitted)[:, 0].any()
+    state, out = block(params, state, prompts, prompt_len, max_new, active)
+    assert bool(state.done[0]) and not np.asarray(out.emitted)[:, 0].any()
     assert not bool(state.done[1])                 # healthy slot unaffected

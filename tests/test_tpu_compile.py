@@ -72,19 +72,27 @@ def _pallas(op):
     return registry.get_op(op).impls["pallas"].fn
 
 
+#: layers of the stacked pools the paged decode kernel is compiled over
+POOL_LAYERS = 3
+
+
+def _paged_decode(q, k, v, t, n, layer, *s):
+    return fa_ops.paged_flash_decode(
+        q, k, v, t, n, layer, k_scale=s[0] if s else None,
+        v_scale=s[1] if s else None, interpret=False)
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_paged_decode_compiles(chip, kv_dtype):
     pages = 1 + SLOTS * PAGES_PER_SLOT
-    pool = chip((pages, PAGE, HKV, D),
+    pool = chip((POOL_LAYERS, pages, PAGE, HKV, D),
                 jnp.int8 if kv_dtype == "int8" else jnp.bfloat16)
     shapes = [chip((SLOTS, 1, HQ, D), jnp.bfloat16), pool, pool,
               chip((SLOTS, PAGES_PER_SLOT), jnp.int32),
-              chip((SLOTS,), jnp.int32)]
+              chip((SLOTS,), jnp.int32), chip((), jnp.int32)]
     if kv_dtype == "int8":
-        shapes += [chip((pages, PAGE, HKV)), chip((pages, PAGE, HKV))]
-    compile_kernel(lambda q, k, v, t, n, *s: fa_ops.paged_flash_decode(
-        q, k, v, t, n, k_scale=s[0] if s else None,
-        v_scale=s[1] if s else None, interpret=False), *shapes)
+        shapes += [chip((POOL_LAYERS, pages, PAGE, HKV))] * 2
+    compile_kernel(_paged_decode, *shapes)
 
 
 @pytest.mark.parametrize("case", ["cell-bf16", "cell-int8",
@@ -93,9 +101,11 @@ def test_paged_decode_compiles_at_cell_widths(chip, case):
     """The paged decode kernel at the benchmark cell's widths (32 slots x
     36 pages of 16, Hq 16, Hkv 8, D 128), bf16 and int8, and at zamba2's
     32 kv heads on 64-token pages, where one page of K+V outgrows a block
-    and the kernel splits the heads over its grid. The kernel reads the
-    pools as they are: no copy, reshape or transpose of a pool-sized
-    array precedes the call."""
+    and the kernel splits the heads over its grid, each over pools stacked
+    over layers. The kernel reads one layer of the pools: no copy, reshape
+    or transpose of a layer's pool precedes the call, and where no padding
+    is needed (the bf16 cell) no slice or fusion either — the kernel reads
+    the stack where it lies."""
     import re
     from repro.kernels.flash_attention import kernel as fa_k
     if case == "zamba2-head-blocks":
@@ -107,23 +117,26 @@ def test_paged_decode_compiles_at_cell_widths(chip, case):
         B, npg, P, hq, hkv, d = CELL_SLOTS, CELL_PAGES, PAGE, HQ, HKV, D
         dt = jnp.int8 if case == "cell-int8" else jnp.bfloat16
     pages = 1 + B * npg
-    pool = chip((pages, P, hkv, d), dt)
+    pool = chip((POOL_LAYERS, pages, P, hkv, d), dt)
     shapes = [chip((B, 1, hq, d), jnp.bfloat16), pool, pool,
-              chip((B, npg), jnp.int32), chip((B,), jnp.int32)]
+              chip((B, npg), jnp.int32), chip((B,), jnp.int32),
+              chip((), jnp.int32)]
     if dt == jnp.int8:
-        shapes += [chip((pages, P, hkv)), chip((pages, P, hkv))]
-    text = compile_kernel(lambda q, k, v, t, n, *s: fa_ops.paged_flash_decode(
-        q, k, v, t, n, k_scale=s[0] if s else None,
-        v_scale=s[1] if s else None, interpret=False), *shapes)
+        shapes += [chip((POOL_LAYERS, pages, P, hkv))] * 2
+    text = compile_kernel(_paged_decode, *shapes)
     entry = text[text.index("ENTRY"):]
     assert re.search(r"%paged_flash_decode(\.\d+)? = ", entry)
-    pool_elems = pages * P * hkv * d
+    in_place = fa_ops.reads_pool_in_place(hkv, d, dt, dt == jnp.int8)
+    assert in_place == (case == "cell-bf16")
+    moves = "copy|copy-start|reshape|transpose" + \
+        ("|dynamic-slice|fusion" if in_place else "")
+    layer_elems = pages * P * hkv * d
     for ln in entry.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* "
-                     r"(copy|copy-start|reshape|transpose)\(", ln)
+                     rf"({moves})\(", ln)
         if m:
             n = int(np.prod([int(x) for x in m.group(1).split(",") if x]))
-            assert n < pool_elems, ln
+            assert n < layer_elems, ln
 
 
 def test_engine_decode_block_names_its_parts_on_the_chip(chip,
@@ -156,6 +169,85 @@ def test_engine_decode_block_names_its_parts_on_the_chip(chip,
         assert re.match(r"paged_flash_decode(\.|$)", name), name
         assert "/decode.attn/" in re.search(r'op_name="([^"]*)"',
                                             ln).group(1)
+
+
+def _cell_block_text(chip, cfg, slots):
+    """The engine's k-step block, compiled for the chip, over a cell's pool
+    of ``slots`` slots of ``CELL_PAGES`` pages of ``PAGE`` (weights as
+    shapes only: nothing is allocated at their size)."""
+    from repro.models import init_params
+    from repro.serve import Engine
+    from repro.serve.sampling import SlotSampling
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    eng = Engine(params, cfg, num_slots=slots, max_len=CELL_PAGES * PAGE,
+                 k=4, max_prompt=64, page_size=PAGE)
+    assert eng._kv_in_place
+    samp = SlotSampling(temperature=eng._temp, top_p=eng._top_p,
+                        top_k=eng._top_k, key=eng.pool.slot_keys)
+    args = (eng.params, eng.state, eng._prompt_buf, eng._prompt_len,
+            eng._max_new, eng._active, samp, eng.pool.tables)
+    shapes = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    return eng, eng._block.lower(*shapes).compile().as_text()
+
+
+def _assert_pool_in_place(text, kv_leaf):
+    """The paged pool stays in one buffer through the block: no copy,
+    slice, slab update or fusion makes an array as large as one layer's K
+    pool, apart from the in-place scatters of each step's rows
+    (``decode.kv_write``); every pool the block takes is aliased to its
+    output (donated); and the attention kernel keeps its name
+    ``paged_flash_decode.*`` under ``decode.attn``."""
+    import re
+    layer_elems = int(np.prod(kv_leaf.shape[-4:]))
+    elems = lambda dims: np.prod([int(x) for x in dims.split(",") if x])
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* "
+                     r"(copy|copy-start|dynamic-slice|dynamic-update-slice"
+                     r"|fusion)\(", ln)
+        if m and elems(m.group(1)) >= layer_elems:
+            assert m.group(2) == "fusion" and \
+                "/decode.kv_write/" in ln, ln
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{", alias)}
+    entry = text[text.index("\nENTRY"):]
+    pools = [int(num) for dims, num in re.findall(
+        r"%state_cache\S* = \w+\[([\d,]*)\]\S* parameter\((\d+)\)", entry)
+        if elems(dims) >= layer_elems]
+    assert pools and set(pools) <= aliased, (pools, alias)
+    calls = [ln for ln in text.splitlines()
+             if re.search(r"%paged_flash_decode(\.\d+)? = ", ln)]
+    assert calls
+    for ln in calls:
+        assert "/decode.attn/" in re.search(r'op_name="([^"]*)"',
+                                            ln).group(1)
+
+
+def test_engine_decode_block_keeps_the_pool_in_place_at_cell_widths(
+        chip, monkeypatch):
+    """internlm2's attention widths over the internlm2.decode_sat cell's
+    pool (32 slots of 36 pages of 16, 8 kv heads of 128), at a smoke depth
+    of 3 layers: the pool goes through the block without a copy."""
+    from repro.configs import get_arch, smoke_config
+    cfg = smoke_config(get_arch("internlm2-1.8b")).scaled(
+        n_layers=3, n_heads=HQ, n_kv_heads=HKV, head_dim=D)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng, text = _cell_block_text(chip, cfg, CELL_SLOTS)
+    _assert_pool_in_place(text, eng.pool.kv_leaf())
+
+
+def test_moe_decode_block_keeps_the_pool_in_place_at_cell_widths(
+        chip, monkeypatch):
+    """deepseek's layout (a dense first layer, then held experts) over the
+    dsmoe16b.decode_sat cell's pool (16 slots of 36 pages of 16, 16 kv
+    heads of 128), at a smoke depth of 3 layers: the stacked pool and the
+    dense layer's own go through the block without a copy."""
+    from repro.configs import get_arch, smoke_config
+    cfg = smoke_config(get_arch("deepseek-moe-16b")).scaled(
+        n_layers=3, n_heads=16, n_kv_heads=16, head_dim=D, first_expert=2,
+        n_experts_held=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng, text = _cell_block_text(chip, cfg, 16)
+    _assert_pool_in_place(text, eng.pool.kv_leaf())
 
 
 def test_held_experts_compiles_at_cell_widths(chip):
