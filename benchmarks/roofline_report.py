@@ -1,4 +1,5 @@
-"""Render EXPERIMENTS.md §Dry-run and §Roofline tables from the dry-run JSON.
+"""Render the dry-run status, per-mesh roofline and collective-schedule
+markdown tables from the dry-run JSON.
 
   PYTHONPATH=src python -m benchmarks.roofline_report results/dryrun_final.json
 """

@@ -25,10 +25,8 @@ engine's, and the compile drain's auditor additionally checks
 ``audit.overlap_epochs == stats.hidden_syncs`` bitwise — the engine's
 overlap bookkeeping verified at the intercepted jax boundary.
 
-Paged rows (``layout=paged``) rerun the greedy sweep through the
-``PagedCachePool`` engine and assert token-identical output at the identical
-sync count — pricing the page-table gather against the dense slot layout.
-The ``serve-prefix`` rows drain a shared-system-prompt workload (a common
+Every row runs the engine over its paged ``CachePool`` (16-token pages
+unless a row says otherwise). The ``serve-prefix`` rows drain a shared-system-prompt workload (a common
 32-token prefix, one unique tail token per request, three waves through the
 slots) with the radix prefix cache off and on: the on-run must emit
 bit-identical tokens while consuming at most half the prefill tokens, with
@@ -66,8 +64,7 @@ from benchmarks.common import emit
 from repro import obs
 from repro.configs import get_arch, smoke_config
 from repro.models import init_params
-from repro.serve import (Engine, PagedCachePool, PageError, Request,
-                         SamplingParams)
+from repro.serve import CachePool, Engine, PageError, Request, SamplingParams
 from repro.serve.sampling import fold_in_seed
 
 ARCH = "internlm2-1.8b"
@@ -84,10 +81,9 @@ def _requests(cfg, n, seed=0, sampling=None):
             for i in range(n)]
 
 
-def _timed_drain(cfg, params, slots, k, sampling, page_size=None,
-                 overlap=False):
+def _timed_drain(cfg, params, slots, k, sampling, overlap=False):
     eng = Engine(params, cfg, num_slots=slots, max_len=NEW_TOKENS + 8,
-                 k=k, max_prompt=4, page_size=page_size, overlap=overlap)
+                 k=k, max_prompt=4, overlap=overlap)
     # untimed compile drain, under the jax-boundary sync auditor: the
     # engine's own sync counter must agree bitwise with the audited number
     # of host round-trip epochs — EngineStats.syncs is bookkeeping, the
@@ -183,15 +179,15 @@ def _capacity_sweep(cfg):
     enough that page granularity strands the f32 remainder while the
     ~half-cost int8 pages convert it into whole spans); the >= 2x gate is
     the PR's capacity claim asserted in-process."""
-    span = PagedCachePool(cfg, 1, CAP_MAX_LEN, page_size=CAP_PAGE)
-    span_q = PagedCachePool(cfg, 1, CAP_MAX_LEN, page_size=CAP_PAGE,
-                            kv_dtype="int8")
+    span = CachePool(cfg, 1, CAP_MAX_LEN, page_size=CAP_PAGE)
+    span_q = CachePool(cfg, 1, CAP_MAX_LEN, page_size=CAP_PAGE,
+                       kv_dtype="int8")
     budget = int(2.5 * span.pages_per_slot) * span.page_bytes()
 
     def residents(kv_dtype, page_bytes):
-        pool = PagedCachePool(cfg, 64, CAP_MAX_LEN, page_size=CAP_PAGE,
-                              kv_dtype=kv_dtype,
-                              num_pages=1 + budget // page_bytes)
+        pool = CachePool(cfg, 64, CAP_MAX_LEN, page_size=CAP_PAGE,
+                         kv_dtype=kv_dtype,
+                         num_pages=1 + budget // page_bytes)
         count = 0
         try:
             while True:
@@ -331,16 +327,6 @@ def run():
                  sdt / ssteps * 1e6,
                  f"tok_per_s={stoks / sdt:.0f};"
                  f"ms_per_step={sdt / ssteps * 1e3:.3f};syncs={ssyncs}")
-            pdt, psteps, psyncs, ptoks, pseqs, _, _ = _timed_drain(
-                cfg, params, slots, k, None, page_size=8)
-            # paged layout must be invisible to the schedule and the tokens
-            assert pseqs == seqs, f"k={k}: paged tokens diverged from slot"
-            assert psyncs == syncs, \
-                f"k={k}: paging changed the sync count ({psyncs} vs {syncs})"
-            emit(f"serve/{cfg.name}/k={k},slots={slots},layout=paged",
-                 pdt / psteps * 1e6,
-                 f"tok_per_s={ptoks / pdt:.0f};"
-                 f"ms_per_step={pdt / psteps * 1e3:.3f};syncs={psyncs}")
             # double-buffered loop: identical tokens, hidden vs blocking
             # syncs split out, per-block host-blocked time priced against
             # the blocking engine's

@@ -121,18 +121,17 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, kv_valid_len, layer,
                        interpret: bool | None = None):
     """Decode attention over one layer of a paged KV pool, model layout.
 
-    q (B,1,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over
-    layers (an unstacked pool passes ``pool[None]`` and layer 0, a
-    bitcast); page_table (B, npages) int32; kv_valid_len scalar or (B,)
-    int32; layer scalar int32 -> (B,1,Hq,D). Where the shapes need no
-    padding (:func:`reads_pool_in_place`) the kernel reads the stack where
-    it lies, at the scalar-prefetched layer. Otherwise the layer's pools
-    are sliced and padded for the call: the head dim to the 128-lane
+    q (B,1,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over the
+    layers that read them; page_table (B, npages) int32; kv_valid_len scalar
+    or (B,) int32; layer scalar int32 -> (B,1,Hq,D). Where the shapes need
+    no padding (:func:`reads_pool_in_place`) the kernel reads the stack
+    where it lies, at the scalar-prefetched layer. Otherwise the layer's
+    pools are sliced and padded for the call: the head dim to the 128-lane
     boundary, the kv heads (with zero K/V and zero queries, sliced off the
     output) up to a count whose page rows fill the pool dtype's DMA tiles
     (``kernel.heads_dma_whole``), and the int8 scales' kv heads to full
-    lanes. ``k_scale``/``v_scale`` (L, num_pages, page_size, Hkv) f32
-    switch the kernel to the int8-dequantizing body.
+    lanes. ``k_scale``/``v_scale`` (L, num_pages, page_size, Hkv) f32 switch
+    the kernel to the int8-dequantizing body.
     """
     B, S, Hq, D = q.shape
     if S != 1:

@@ -17,10 +17,11 @@ Sampling: ``--temperature/--top-p/--top-k/--sample-seed`` attach a
 request's token deltas as k-blocks retire — tokens surface with one block
 of latency, at the same one-sync-per-k-tokens schedule.
 
-Paged extras: ``--kv-dtype int8`` stores pageable K/V as int8 codes with
-f32 row/head scales (about double the resident capacity at the same pool
-bytes); ``--n`` fans every synthetic request into n sampled streams that
-share its prompt pages, each stream seeded with ``fold_in_seed(seed, i)``.
+The engine's attention K/V lives in pages of ``--page-size`` tokens (16 by
+default). ``--kv-dtype int8`` stores them as int8 codes with f32 row/head
+scales (about double the resident capacity at the same pool bytes);
+``--n`` fans every synthetic request into n sampled streams that share its
+prompt pages, each stream seeded with ``fold_in_seed(seed, i)``.
 :func:`check_paged_logits` compares cached decode through a page pool with
 a full forward over the same tokens.
 """
@@ -44,7 +45,7 @@ from repro.launch.steps import make_serve_step
 from repro.dist.sharding import make_rules
 from repro.models import init_params, init_cache
 from repro.models.transformer import decode_step, forward, prefill_audio_cache
-from repro.serve import Engine, PagedCachePool, Request, SamplingParams
+from repro.serve import CachePool, Engine, Request, SamplingParams
 
 #: bound on the relative L2 error, per position, of cached paged-decode
 #: logits against a full forward over the same tokens. Both paths carry bf16
@@ -113,7 +114,7 @@ def serve_engine(cfg, rules, args):
                     max_len=args.max_len, k=args.k,
                     max_prompt=min(16, args.max_len // 2),
                     enc_len=args.max_len if cfg.family == "audio" else None,
-                    page_size=args.page_size or None,
+                    page_size=args.page_size,
                     kv_dtype=args.kv_dtype,
                     prefix_cache=args.prefix_cache,
                     overlap=args.overlap)
@@ -149,7 +150,7 @@ def serve_engine(cfg, rules, args):
           f"prefill_tokens={s.prefill_tokens} retired={s.retired} "
           f"shed={s.shed} defrags={s.defrags} occupancy={s.occupancy:.2f}")
     print(s.summary())
-    if engine.paged:
+    if engine.pool.has_paged:
         print(f"paged: page_size={engine.pool.page_size} "
               f"pages={engine.pool.num_pages} "
               f"kv_dtype={'int8' if engine.pool.quantized else 'f32'} "
@@ -166,8 +167,8 @@ def check_paged_logits(params, cfg, *, page_size: int, kv_dtype: str = "f32",
     """Worst per-position relative L2 error of the logits of ``n_tokens``
     seeded tokens decoded one at a time through a fresh page pool, against
     one full forward over the same tokens at the highest matmul precision."""
-    pool = PagedCachePool(cfg, 1, n_tokens, page_size=page_size,
-                          kv_dtype=kv_dtype)
+    pool = CachePool(cfg, 1, n_tokens, page_size=page_size,
+                     kv_dtype=kv_dtype)
     slot = pool.allocate("logit-check")
     pool.reserve(slot, n_tokens)
     table = jnp.asarray(pool.tables)
@@ -253,21 +254,20 @@ def main(argv=None):
                     help="top-k truncation (0 disables)")
     ap.add_argument("--sample-seed", type=int, default=0,
                     help="base seed for per-request sampling streams")
-    ap.add_argument("--page-size", type=int, default=0,
-                    help="engine mode: tokens per KV page (0 = whole-row "
-                         "slot cache; token streams identical either way)")
+    ap.add_argument("--page-size", type=int, default=16,
+                    help="engine mode: tokens per KV page")
     ap.add_argument("--kv-dtype", choices=["f32", "int8"], default="f32",
-                    help="engine mode, with --page-size: int8 stores "
-                         "pageable K/V as int8 codes + f32 row/head scales "
-                         "(~2x resident capacity at matched pool bytes)")
+                    help="engine mode: int8 stores paged K/V as int8 "
+                         "codes + f32 row/head scales (~2x resident "
+                         "capacity at matched pool bytes)")
     ap.add_argument("--n", type=int, default=1,
                     help="engine mode: fan each synthetic request into n "
                          "sampled streams sharing its prompt pages (stream "
                          "i seeds with fold_in_seed(seed, i))")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="engine mode, with --page-size: reuse radix-trie "
-                         "shared prompt-prefix pages across requests and "
-                         "skip their prefill steps")
+                    help="engine mode: reuse radix-trie shared "
+                         "prompt-prefix pages across requests and skip "
+                         "their prefill steps")
     ap.add_argument("--overlap", action="store_true",
                     help="engine mode: double-buffer the host loop — "
                          "dispatch each k-block before blocking on the "
@@ -284,7 +284,7 @@ def main(argv=None):
         # decode geometry: q length 1 against the full KV horizon
         run_autotune(plan_shapes(cfg, batch=args.batch, seq_q=1,
                                  seq_kv=args.max_len,
-                                 page_size=args.page_size or None,
+                                 page_size=args.page_size,
                                  max_len=args.max_len))
     rules = make_rules(make_host_mesh())
     observing = obs_begin(args)

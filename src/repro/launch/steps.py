@@ -134,8 +134,8 @@ def make_serve_step(cfg, rules: Optional[Rules], *, greedy: bool = True):
     positions: optional (B,) per-slot decode depths — see
     ``repro.models.decode_step``; the continuous-batching engine
     (``repro.serve``) drives this, the classic whole-batch path omits it.
-    page_table: optional (B, pages_per_slot) int32 when the cache K/V leaves
-    are a paged pool (``repro.serve.paging``).
+    page_table: (B, pages_per_slot) int32, with per-row positions, when
+    the cache K/V leaves are a paged pool (``repro.serve.paging``).
 
     Kernels dispatch through ``repro.kernels.registry`` (backend pinned at
     build time)."""

@@ -14,8 +14,11 @@ the KV prefix it can see — matching FlashAttention's block-skipping FLOPs.
 This module registers the ``flash_attention`` registry op in the model's
 (B, S, H, D) layout: ``xla`` = :func:`chunked_attention`, ``pallas`` = the
 kernel in ``repro.kernels.flash_attention`` (static masks only — its
-per-call predicate rejects dynamic ``kv_valid_len``, so cached decode always
-takes the XLA path). The pallas impl registers the FA-2-style custom VJP
+per-call predicate rejects dynamic ``kv_valid_len``, so the model's plain
+whole-batch decode over slot rows takes the XLA path). The serving engine's
+cached decode reads a paged pool through :func:`paged_attention`, whose
+pallas impl is the paged decode kernel, on TPU the default. The pallas
+impl of ``flash_attention`` registers the FA-2-style custom VJP
 (``kernels.flash_attention.backward``), so ``loss_fn`` gradients trace the
 pallas backward kernels rather than detouring to XLA. Call sites use
 :func:`attention`, which defers to the process backend policy (see
@@ -61,7 +64,7 @@ def _attn_inner(q, k, v, *, causal: bool, chunk: int, scale: float,
     # Operands stay bf16 (MXU-native); accumulation is fp32 via
     # preferred_element_type. Upcasting q itself costs a full fp32
     # activation tensor per layer AND turns every backward cotangent fp32
-    # (llama3 train_4k: -30% memory term; EXPERIMENTS.md §Perf llama it.3).
+    # (llama3 train_4k: -30% memory term).
     qg = q.reshape(B, S, Hkv, group, D)
     qpos = jnp.arange(S, dtype=jnp.int32) + qpos_offset
 
@@ -239,7 +242,7 @@ def paged_attention(q, k_pool, v_pool, page_table, kv_valid_len, layer, *,
     pool.
 
     q (B,1,Hq,D); pools (L, num_pages, page_size, Hkv, D), stacked over
-    layers (a single layer's pool passes ``pool[None]``); page_table
+    the layers that read them; page_table
     (B, pages_per_slot) int32 mapping each batch row's logical pages to pool
     pages; kv_valid_len (B,) int32 valid KV length per row; layer scalar
     int32, the layer of the stack read. Rows past ``kv_valid_len`` —
@@ -254,7 +257,8 @@ def paged_attention(q, k_pool, v_pool, page_table, kv_valid_len, layer, *,
     bounded by ``scale/2 = absmax/254`` per element.
 
     The xla impl gathers the layer's table rows into dense rows and reuses
-    :func:`chunked_attention` — bitwise the slot-engine decode path. The
+    :func:`chunked_attention` — bitwise the model's plain decode over slot
+    rows. The
     pallas impl (decode-only, S == 1) copies each row's live blocks of
     whole pages straight from the stack through a scalar-prefetched table
     and layer, never materialising the gather.
@@ -290,7 +294,7 @@ def _paged_attention_xla(q, k_pool, v_pool, page_table, kv_valid_len, layer,
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
     # decode reads are right-aligned single queries: causal=False + the
-    # per-row kv_valid mask is the exact slot-engine semantics
+    # per-row kv_valid mask is the exact plain-decode semantics
     return chunked_attention(q, k, v, causal=False,
                              chunk=chunk or KV_CHUNK_DEFAULT, scale=scale,
                              kv_valid_len=kv_valid_len)

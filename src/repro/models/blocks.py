@@ -95,19 +95,19 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
                  layer=None):
     """GQA attention. x (B,S,d).
 
-    cache: dict(k=(B,Smax,Hkv,Dh), v=...) updated at cache_pos (decode).
-    cache_pos: scalar (whole batch at one depth, classic decode) or (B,)
-    int32 (per-slot depths — the continuous-batching serve path; each batch
-    row writes and masks at its own position).
+    cache: the K/V of every layer that uses it, stacked over those layers;
+    this call writes its rows into layer ``layer`` of the stack and reads
+    that layer where it lies. Returns the stack updated.
+    - scalar ``cache_pos`` (the model's plain whole-batch decode): slot rows
+      dict(k=(L,B,Smax,Hkv,Dh), v=...), the whole batch written at
+      ``cache_pos``.
+    - (B,) int32 ``cache_pos`` (the serving engine: each row at its own
+      depth) needs ``page_table``, (B, pages_per_slot) int32: the leaves
+      are a paged pool (L, num_pages, page_size, Hkv, Dh), plus int8 scale
+      siblings (L, num_pages, page_size, Hkv), and position p of batch row
+      b lives at pool page ``page_table[b, p // page_size]``, row
+      ``p % page_size`` (decode-only: S == 1).
     kv_override: (k, v) tuple for cross-attention (whisper decoder).
-    page_table: (B, pages_per_slot) int32 — the cache leaves are a paged
-    pool (num_pages, page_size, Hkv, Dh) and position p of batch row b lives
-    at pool page ``page_table[b, p // page_size]``, row ``p % page_size``
-    (decode-only: requires S == 1 and per-row ``cache_pos``).
-    layer: with ``page_table``, the cache leaves are the pool stacked over
-    layers, ``(L, num_pages, page_size, Hkv, Dh)``, and this call writes
-    its rows into and reads layer ``layer`` of the stack where it lies
-    (None: the leaves are one layer's pool).
     Returns (out, new_cache).
     """
     B, S, d = x.shape
@@ -145,21 +145,20 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
 
     new_cache = cache
     kv_valid = None
-    if cache is not None and page_table is not None:
-        if S != 1 or not getattr(cache_pos, "ndim", 0):
-            raise ValueError("paged KV cache is decode-only: S == 1 with "
-                             "per-row cache_pos")
-        # one layer's pool is the stack of one layer (a bitcast)
-        pool = cache if layer is not None else \
-            {n: a[None] for n, a in cache.items()}
-        li = 0 if layer is None else layer
-        P_pg = pool["k"].shape[2]
+    per_row = cache is not None and getattr(cache_pos, "ndim", 0) > 0
+    if per_row != (page_table is not None):
+        raise ValueError("per-row cache_pos and a page_table go together: "
+                         "the engine's decode reads a paged pool")
+    if page_table is not None:
+        if S != 1:
+            raise ValueError("paged KV cache is decode-only: S == 1")
+        P_pg = cache["k"].shape[2]
         pidx = jnp.take_along_axis(page_table, cache_pos[:, None] // P_pg,
                                    axis=1)[:, 0]
         off = cache_pos % P_pg
         # B rows a step, scattered into the pool where it lies
         with decode_scope("kv_write"):
-            if "k_scale" in pool:
+            if "k_scale" in cache:
                 # int8 pool: quantize on scatter — codes and their
                 # per-(row, head) scales land in the same page/row, so a
                 # page is self-describing and CoW/defrag/trie sharing move
@@ -168,31 +167,23 @@ def attn_forward(params, x, *, n_heads: int, n_kv: int, head_dim: int,
                 rows = dict(k=kq, v=vq, k_scale=ks, v_scale=vs)
             else:
                 rows = dict(k=k[:, 0], v=v[:, 0])
-            pool = {n: pool[n].at[li, pidx, off].set(
-                r.astype(pool[n].dtype)) for n, r in rows.items()}
-        o = paged_attention(q, pool["k"], pool["v"], page_table,
-                            cache_pos + 1, li, k_scale=pool.get("k_scale"),
-                            v_scale=pool.get("v_scale"), chunk=attn_chunk)
-        new_cache = pool if layer is not None else \
-            {n: a[0] for n, a in pool.items()}
+            new_cache = {n: cache[n].at[layer, pidx, off].set(
+                r.astype(cache[n].dtype)) for n, r in rows.items()}
+        o = paged_attention(q, new_cache["k"], new_cache["v"], page_table,
+                            cache_pos + 1, layer,
+                            k_scale=new_cache.get("k_scale"),
+                            v_scale=new_cache.get("v_scale"),
+                            chunk=attn_chunk)
         o = o.reshape(B, S, n_heads * head_dim)
         out = jnp.einsum("bsh,hd->bsd", o, params["wo"].astype(x.dtype))
         return constrain(out, ("batch", None, None)), new_cache
     if cache is not None:
         with decode_scope("kv_write"):
-            if getattr(cache_pos, "ndim", 0):  # (B,) per-slot write positions
-                upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(
-                    c, u, (p, 0, 0)))
-                k = upd(cache["k"], k.astype(cache["k"].dtype), cache_pos)
-                v = upd(cache["v"], v.astype(cache["v"].dtype), cache_pos)
-            else:
-                k = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype),
-                    (0, cache_pos, 0, 0))
-                v = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype),
-                    (0, cache_pos, 0, 0))
-        new_cache = dict(k=k, v=v)
+            new_cache = {n: jax.lax.dynamic_update_slice(
+                cache[n], u[None].astype(cache[n].dtype),
+                (layer, 0, cache_pos, 0, 0)) for n, u in (("k", k), ("v", v))}
+        k, v = (jax.lax.dynamic_index_in_dim(new_cache[n], layer,
+                                             keepdims=False) for n in "kv")
         kv_valid = cache_pos + S
         causal = False if S == 1 else causal    # single query: mask via kv_valid
 
@@ -226,7 +217,7 @@ def init_dense_block(key, cfg, dtype=jnp.float32):
 
 def dense_block(params, x, cfg, *, pos_info, cache=None, cache_pos=None,
                 constrain=lambda x, s: x, page_table=None, layer=None):
-    """``layer``: see ``attn_forward``."""
+    """``cache``/``layer``: see ``attn_forward``."""
     with decode_scope("attn"):
         h, new_cache = attn_forward(
             params["attn"], rms_norm(x, params["ln1"], cfg.norm_eps),

@@ -61,14 +61,17 @@ def cross_kv(params, enc_out, cfg, constrain):
 
 
 def dec_block(params, x, cfg, *, kv_cross, positions, cache=None,
-              cache_pos=None, constrain=lambda x, s: x, page_table=None):
-    # page_table pages the decoder self-attn cache only; the cross K/V is
-    # enc_len-shaped request state and stays in slot layout
+              cache_pos=None, constrain=lambda x, s: x, page_table=None,
+              layer=None):
+    # cache/page_table/layer address the decoder self-attn K/V only (see
+    # ``attn_forward``); the cross K/V is enc_len-shaped request state and
+    # stays in slot layout
     h, new_cache = attn_forward(
         params["self_attn"], rms_norm(x, params["ln1"], cfg.norm_eps),
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
         positions=positions, rope_theta=cfg.rope_theta, cache=cache,
-        cache_pos=cache_pos, constrain=constrain, page_table=page_table)
+        cache_pos=cache_pos, constrain=constrain, page_table=page_table,
+        layer=layer)
     x = x + h
     h, _ = attn_forward(
         params["cross_attn"], rms_norm(x, params["ln2"], cfg.norm_eps),

@@ -12,7 +12,7 @@ def rms_norm(x, gamma, eps: float = 1e-5):
     is applied in the stream dtype. This keeps the residual stream and its
     cotangents bf16 end-to-end — materializing the fp32 upcast costs two
     full activation tensors of HBM traffic per layer (llama3 train_4k:
-    -344 GB/step/chip, EXPERIMENTS.md §Perf llama iteration 2)."""
+    -344 GB/step/chip)."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     inv = jax.lax.rsqrt(var + eps).astype(x.dtype)
     return x * inv * gamma.astype(x.dtype)

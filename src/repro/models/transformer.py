@@ -262,7 +262,7 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
         cache["layers"] = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (n, *x.shape)).copy(), kv())
         if cfg.family == "moe" and cfg.first_layer_dense:
-            cache["dense0"] = kv()
+            cache["dense0"] = jax.tree.map(lambda x: x[None], kv())
         if cfg.family == "moe":
             # the held experts' work of the steps since the counts were last
             # zeroed (the serving engine's decode block zeroes them)
@@ -292,6 +292,22 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
     return cache
 
 
+def _attn_scan(body, x, xs, kv):
+    """Scan ``body(x, xs_i, kv, i) -> (x, kv, y)`` over the layers. ``kv``,
+    the attention K/V stacked over those layers, rides the carry whole:
+    each layer writes its rows into it and reads its own layer where it
+    lies, where a scanned slice and a stacked result would copy it every
+    step (nothing reads the carried stack after the write, so XLA updates
+    it in place). Returns (x, kv, ys)."""
+    def step(carry, inp):
+        x, kv = carry
+        x, kv, y = body(x, inp[0], kv, inp[1])
+        return (x, kv), y
+    n = jax.tree.leaves(kv)[0].shape[0]
+    (x, kv), ys = jax.lax.scan(step, (x, kv), (xs, jnp.arange(n)))
+    return x, kv, ys
+
+
 def _decode_step(params, cfg, cache, tokens, *, positions=None,
                  constrain=_no_constrain, page_table=None):
     """One decode step: tokens (B, 1) -> (logits (B, 1, V), new cache).
@@ -303,14 +319,13 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
     pytree keeps its classic-path meaning. Default: the scalar ``cache["pos"]``
     shared by the whole batch.
 
-    page_table: optional (B, pages_per_slot) int32 — the attention K/V
-    leaves are a paged pool (see ``repro.serve.paging``) and every attention
-    read/write goes through the table. Requires per-row ``positions``. The
-    dense/vlm/moe layer scan carries the pool stacked over its layers, so
-    a step writes its rows into the pool where it lies (nothing reads the
-    carried pool after the scatter, so XLA updates it in place).
+    page_table: (B, pages_per_slot) int32, required with per-row
+    ``positions`` wherever the model has attention: the attention K/V
+    leaves are then a paged pool (see ``repro.serve.paging``) and every
+    attention read/write goes through the table. Either layout is stacked
+    over the layers that use it and rides their scan (``_attn_scan``).
     Recurrent leaves (mamba conv/ssm, whisper cross-K/V) are pageless and
-    ignore it."""
+    are scanned per layer."""
     fam = cfg.family
     B = tokens.shape[0]
     if positions is None:
@@ -325,18 +340,17 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
     if cfg.family == "vlm":
         # after the vision prefix all three M-RoPE streams advance together
         pos_info = dict(mrope=jnp.broadcast_to(positions, (3, B, 1)))
+    attn = dict(cache_pos=pos, constrain=constrain, page_table=page_table)
 
     if fam in ("dense", "vlm", "moe"):
         if fam == "moe" and cfg.first_layer_dense:
             dense_cfg = cfg.scaled(d_ff=cfg.dense_d_ff)
             x, c0 = dense_block(params["dense0"], x, dense_cfg,
                                 pos_info=pos_info, cache=cache["dense0"],
-                                cache_pos=pos, constrain=constrain,
-                                page_table=page_table)
+                                layer=0, **attn)
             cache = dict(cache, dense0=c0)
 
         layers = params["layers"]
-        n = cfg.n_layers - int(fam == "moe" and cfg.first_layer_dense)
         if fam == "moe":
             # the held experts' weights stay stacked over the layers, out of
             # the scan: the expert kernel reads each layer's blocks in place,
@@ -344,35 +358,17 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
             experts = {k: layers["moe"][k] for k in EXPERT_WEIGHTS}
             layers = dict(layers, moe={k: v for k, v in layers["moe"].items()
                                        if k not in experts})
-        # a paged pool rides the carry, stacked over the layers: each layer
-        # scatters its rows into it and the attention reads it where it
-        # lies, where a scanned slice and a stacked result would copy it
-        # every step. Slot-layout caches are scanned per layer.
-        paged = page_table is not None
-        xs = (layers, jnp.arange(n)) + (() if paged else (cache["layers"],))
 
-        def body(carry, inp):
-            lp, i = inp[:2]
-            x, cl = carry if paged else (carry, inp[2])
+        def body(x, lp, kv, i):
             if fam == "moe":
                 lp = dict(lp, moe=dict(lp["moe"], **experts))
-                y, nc, _, counts = moe_block(
-                    lp, x, cfg, pos_info=pos_info, cache=cl, cache_pos=pos,
-                    constrain=constrain, page_table=page_table, layer=i)
-            else:
-                (y, nc), counts = dense_block(
-                    lp, x, cfg, pos_info=pos_info, cache=cl, cache_pos=pos,
-                    constrain=constrain, page_table=page_table,
-                    layer=i), None
-            if paged:
-                return (y, nc), counts
-            return y, (nc, counts)
-        carry, ys = jax.lax.scan(body, (x, cache["layers"]) if paged else x,
-                                 xs)
-        if paged:
-            (x, new_caches), counts = carry, ys
-        else:
-            x, (new_caches, counts) = carry, ys
+                y, kv, _, counts = moe_block(lp, x, cfg, pos_info=pos_info,
+                                             cache=kv, layer=i, **attn)
+                return y, kv, counts
+            y, kv = dense_block(lp, x, cfg, pos_info=pos_info, cache=kv,
+                                layer=i, **attn)
+            return y, kv, None
+        x, new_caches, counts = _attn_scan(body, x, layers, cache["layers"])
         if fam == "moe":
             cache = dict(cache, moe=jax.tree.map(
                 lambda c, n: c + n.sum(), cache["moe"], counts))
@@ -392,39 +388,36 @@ def _decode_step(params, cfg, cache, tokens, *, positions=None,
     elif fam == "hybrid":
         shared = params["shared"]
 
-        def super_body(x, inp):
-            sb, st, skv = inp
+        def super_body(x, inp, skv, i):
             def inner(x, inp2):
                 lp, s = inp2
                 h, ns = mamba2_decode_step(
                     lp["mamba"], rms_norm(x, lp["ln"], cfg.norm_eps), s, cfg,
                     constrain)
                 return x + h, ns
-            x, new_st = jax.lax.scan(inner, x, (sb, st))
-            h, new_skv = attn_forward(
+            x, new_st = jax.lax.scan(inner, x, inp)
+            h, skv = attn_forward(
                 shared["attn"], rms_norm(x, shared["ln"], cfg.norm_eps),
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                 head_dim=cfg.head_dim, positions=positions,
-                rope_theta=cfg.rope_theta, cache=skv, cache_pos=pos,
-                constrain=constrain, page_table=page_table)
-            return x + h, (new_st, new_skv)
-        x, (new_st, new_skv) = jax.lax.scan(
-            super_body, x, (params["layers"], cache["layers"],
-                            cache["shared"]))
+                rope_theta=cfg.rope_theta, cache=skv, layer=i, **attn)
+            return x + h, skv, new_st
+        x, new_skv, new_st = _attn_scan(
+            super_body, x, (params["layers"], cache["layers"]),
+            cache["shared"])
         cache = dict(cache, layers=new_st, shared=new_skv)
 
     elif fam == "audio":
-        def body(x, inp):
-            lp, cl, cross = inp
-            y, nc = encdec.dec_block(lp, x, cfg, kv_cross=(cross["k"],
+        def body(x, inp, kv, i):
+            lp, cross = inp
+            y, kv = encdec.dec_block(lp, x, cfg, kv_cross=(cross["k"],
                                                            cross["v"]),
-                                     positions=positions, cache=cl,
-                                     cache_pos=pos, constrain=constrain,
-                                     page_table=page_table)
-            return y, nc
-        x, new_caches = jax.lax.scan(body, x, (params["layers"],
-                                               cache["layers"],
-                                               cache["cross"]))
+                                     positions=positions, cache=kv,
+                                     layer=i, **attn)
+            return y, kv, None
+        x, new_caches, _ = _attn_scan(body, x,
+                                      (params["layers"], cache["cross"]),
+                                      cache["layers"])
         cache = dict(cache, layers=new_caches)
 
     else:

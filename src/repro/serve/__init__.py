@@ -8,17 +8,15 @@ Six modules, one contract:
                   and the batched in-scan draw (``sample_tokens``): every
                   stochastic token is drawn inside the fused block, so
                   sampling costs zero extra host syncs.
-- ``cache``     — ``CachePool``: slot-based KV/SSM cache over the
-                  ``init_cache`` layouts (allocate / free / defrag) plus
-                  per-slot request PRNG keys, sharded via
+- ``paging``    — ``CachePool``: the serving cache. Request state in
+                  slots (allocate / free / defrag, per-slot request PRNG
+                  keys); attention K/V in fixed-size pages stacked over the
+                  layers, behind per-slot page tables, with refcounted
+                  radix-trie shared-prefix reuse (``PrefixCache``),
+                  page-level defrag, and optional int8 page storage
+                  (``kv_dtype="int8"``: codes + f32 row/head scales, ~2x
+                  resident capacity at matched pool bytes); sharded via
                   ``repro.dist.cache_specs`` when rules are bound.
-- ``paging``    — ``PagedCachePool``: sub-slot fixed-size pages behind
-                  per-slot page tables, with refcounted radix-trie
-                  shared-prefix reuse (``PrefixCache``), page-level defrag,
-                  and optional int8 page storage (``kv_dtype="int8"``:
-                  codes + f32 row/head scales, ~2x resident capacity at
-                  matched pool bytes); token streams identical to the slot
-                  pool.
 - ``scheduler`` — FIFO admission + ``repro.dist.DeadlineGate`` overload
                   shedding.
 - ``decode``    — the ``lax.scan``-fused k-step decode block: k tokens per
@@ -35,8 +33,7 @@ from repro.serve.api import (Request, Response, StreamDelta, EngineStats,
                              FINISH_SHED)
 from repro.serve.sampling import (SamplingParams, SlotSampling,
                                   fold_in_seed, host_fold_in, sample_tokens)
-from repro.serve.cache import CachePool, SlotError
-from repro.serve.paging import PagedCachePool, PrefixCache, PageError
+from repro.serve.paging import CachePool, PrefixCache, PageError, SlotError
 from repro.serve.scheduler import Scheduler
 from repro.serve.decode import (DecodeState, init_decode_state,
                                 make_decode_block)
@@ -47,8 +44,7 @@ __all__ = [
     "FINISH_EOS", "FINISH_ERROR", "FINISH_LENGTH", "FINISH_SHED",
     "SamplingParams", "SlotSampling", "sample_tokens",
     "fold_in_seed", "host_fold_in",
-    "CachePool", "SlotError", "Scheduler",
-    "PagedCachePool", "PrefixCache", "PageError",
+    "CachePool", "SlotError", "Scheduler", "PrefixCache", "PageError",
     "DecodeState", "init_decode_state", "make_decode_block",
     "Engine",
 ]

@@ -99,7 +99,7 @@ class EngineStats:
     rejected: int = 0                        # invalid at admission (error)
     defrags: int = 0
     occupancy_sum: float = 0.0               # live-slot fraction, per sync
-    # paged-pool counters (zero on the slot-layout engine)
+    # page-pool counters (zero for a model without attention K/V)
     prefix_hits: int = 0                     # admissions that matched the trie
     prefix_tokens: int = 0                   # prefill tokens skipped via reuse
     cow_copies: int = 0                      # copy-on-write divergence pages

@@ -61,7 +61,7 @@ from repro.serve.sampling import SlotSampling, sample_tokens
 
 class DecodeState(NamedTuple):
     """Device-side per-slot decode state (the fused block's carry)."""
-    cache: Any               # pool cache pytree (per-slot rows)
+    cache: Any               # pool cache pytree (slot rows and pages)
     lengths: jnp.ndarray     # (B,) int32: tokens written == next write pos
     last_tok: jnp.ndarray    # (B,) int32: last sampled token per slot
     n_out: jnp.ndarray       # (B,) int32: tokens emitted per slot
@@ -116,10 +116,11 @@ def make_decode_block(cfg, rules, *, k: int, max_len: int,
     the sync count is unchanged. None (or all temperatures 0) is the greedy
     path, bit-identical to the pre-sampling block.
 
-    page_table: optional (B, pages_per_slot) int32 when the cache K/V
-    leaves are a paged pool (``repro.serve.paging``). The engine reserves
-    pages covering the block's k steps before dispatch, so the table is a
-    constant input to the scan, not part of the carry.
+    page_table: (B, pages_per_slot) int32, the page table of the pool's
+    attention K/V (``repro.serve.paging``; None for a model without
+    attention). The engine reserves pages covering the block's k steps
+    before dispatch, so the table is a constant input to the scan, not part
+    of the carry.
 
     MoE caches carry the held experts' counts (``cache["moe"]``): the
     block zeroes them first, so ``out.moe`` holds its own k steps' sums.

@@ -20,24 +20,31 @@ the round (``StreamDelta``), and ``stream`` is the generator form — tokens
 surface every k-block instead of at retirement. ``step``/``run`` keep the
 whole-response contract.
 
+The cache is one ``CachePool`` (``repro.serve.paging``): request state in
+slot rows, attention K/V in pages stacked over the layers, which the decode
+block reads through a page table (``paged_attention``; the Pallas paged
+kernel on TPU).
+
 Double-buffering (``overlap=True``): the CA-k schedule already cut the sync
 *count* to one per k steps; the overlapped loop hides the one that remains.
-``jax.jit`` dispatch is asynchronous, so each round dispatches block i+1
-*before* blocking on block i's device->host transfer — all host work of a
-round (admission, prompt staging, detokenize, stream deltas, scheduler and
-defrag bookkeeping) overlaps device compute of the newer block, on a
-one-deep pipeline of :class:`_InFlight` records. Correctness rests on
-stale-slot fencing (mirroring the paged pool's page-table discipline): a
-slot retired while a newer block is still in flight is *fenced* — its pool
-row, pages, and PRNG key are released only when that block completes, so
-admission can never hand the row to a new request the in-flight block still
-writes. Structural moves (slot/page defrag) flush the pipeline first.
-Admission updates are safe mid-flight because they are functional updates on
-the in-flight block's *output* arrays — jax orders them by data flow — and
-every block input (prompt buffers, sampling policy, page tables) is
-snapshotted to the device at dispatch. Token streams are bit-identical to
-the non-overlapped engine: per-slot decode depends only on the request
-(prompt, key, max_new), never on placement or fetch timing.
+Rounds run a pipeline of :class:`_InFlight` records, at depth 0 (blocking:
+each block is fetched in the round that dispatched it) or, overlapped, one
+deep: ``jax.jit`` dispatch is asynchronous, so each round dispatches block
+i+1 *before* blocking on block i's device->host transfer — all host work of
+a round (admission, prompt staging, detokenize, stream deltas, scheduler
+and defrag bookkeeping) overlaps device compute of the newer block.
+Correctness rests on stale-slot fencing (mirroring the pool's page-table
+discipline): a slot retired while a newer block is still in flight is
+*fenced* — its pool row, pages, and PRNG key are released only when that
+block completes, so admission can never hand the row to a new request the
+in-flight block still writes. Structural moves (slot/page defrag) flush the
+pipeline first. Admission updates are safe mid-flight because they are
+functional updates on the in-flight block's *output* arrays — jax orders
+them by data flow — and every block input (prompt buffers, sampling
+policy, page tables) is snapshotted to the device at dispatch. Token
+streams are bit-identical to the blocking engine: per-slot decode depends
+only on the request (prompt, key, max_new), never on placement or fetch
+timing.
 """
 from __future__ import annotations
 
@@ -56,8 +63,7 @@ from repro.models.transformer import prefill_audio_cache
 from repro.serve.api import (Request, Response, EngineStats, StreamDelta,
                              FINISH_EOS, FINISH_ERROR, FINISH_LENGTH,
                              FINISH_SHED)
-from repro.serve.cache import CachePool
-from repro.serve.paging import PagedCachePool
+from repro.serve.paging import CachePool
 from repro.serve.decode import (BlockOut, init_decode_state,
                                 make_decode_block)
 from repro.serve.sampling import GREEDY, SlotSampling, host_fold_in
@@ -150,7 +156,7 @@ class _InFlight:
 
 
 class Engine:
-    """Continuous-batching serving engine over a slot cache pool.
+    """Continuous-batching serving engine over a paged cache pool.
 
     params/cfg: model weights + arch config (any of the 10 assigned archs).
     num_slots: concurrent sequences (the fused block's batch dimension).
@@ -158,19 +164,18 @@ class Engine:
     eos_id: greedy decode stops a slot on this token (None: length-only).
     scheduler: admission policy; default plain FIFO (pass
     ``Scheduler(gate=DeadlineGate(...))`` for overload shedding).
-    page_size: switch the attention K/V leaves to a paged pool
-    (``repro.serve.paging``) with this many tokens per page; None keeps the
-    whole-row slot layout. Token streams are identical either way. A
-    pure-SSM arch has no pageable leaves and silently keeps the slot pool.
-    prefix_cache: with paging on, reuse radix-trie shared prompt-prefix
+    page_size: tokens per page of the attention K/V pool
+    (``repro.serve.paging``); must be >= 1. A pure-SSM arch has no
+    attention K/V: its pool has no pages and its block no page table.
+    prefix_cache: reuse radix-trie shared prompt-prefix
     pages across requests (their prefill steps are skipped). Enabled only
     for families whose prompt K/V depends on the tokens alone — recurrent
     state must consume every prompt token, and whisper's decoder K/V mixes
     in per-request encoder output — so ssm/hybrid/audio decline it.
     num_pages: page-pool depth override (default: full slot backing + 1
     scratch page; doubled when quantized).
-    kv_dtype: ``"f32"`` keeps the init_cache dtypes; ``"int8"`` (paged
-    pools only) stores pageable K/V as int8 codes with per-(page row, head)
+    kv_dtype: ``"f32"`` keeps the init_cache dtypes; ``"int8"`` stores
+    paged K/V as int8 codes with per-(page row, head)
     f32 scales — quantized on scatter, dequantized inside both
     ``paged_attention`` impls — roughly doubling resident-request capacity
     at fixed pool bytes. Greedy token parity vs the f32 pool is statistical
@@ -195,7 +200,7 @@ class Engine:
                  scheduler: Optional[Scheduler] = None,
                  enc_len: Optional[int] = None,
                  defrag_threshold: float = 0.5,
-                 page_size: Optional[int] = None,
+                 page_size: int = 16,
                  prefix_cache: bool = False,
                  num_pages: Optional[int] = None,
                  kv_dtype: str = "f32",
@@ -209,40 +214,27 @@ class Engine:
         self.eos_id = eos_id
         enc_len = (enc_len if enc_len is not None else max_len) \
             if cfg.family == "audio" else None
-        if kv_dtype != "f32" and page_size is None:
-            raise ValueError("kv_dtype requires a paged pool: pass page_size")
-        pool: Optional[CachePool] = None
-        if page_size is not None:
-            pool = PagedCachePool(cfg, num_slots, max_len,
-                                  page_size=page_size, rules=rules,
-                                  enc_len=enc_len, num_pages=num_pages,
-                                  kv_dtype=kv_dtype)
-            if not pool.has_paged:
-                pool = None                 # pure-SSM: nothing to page
-        if pool is None:
-            pool = CachePool(cfg, num_slots, max_len, rules=rules,
-                             enc_len=enc_len)
-        self.pool = pool
-        self.paged = isinstance(pool, PagedCachePool)
-        # tokens of one block of the paged decode kernel (0: no pages)
-        self._kv_block = 0
-        if self.paged:
+        self.pool = pool = CachePool(cfg, num_slots, max_len,
+                                     page_size=page_size, rules=rules,
+                                     enc_len=enc_len, num_pages=num_pages,
+                                     kv_dtype=kv_dtype)
+        # tokens of one block of the paged decode kernel (0: no pages). The
+        # layer scan carries the pool stacked over its layers, the block's
+        # donated state keeps it in one buffer, and the attention op reads
+        # it where it lies unless the kernel must pad it
+        self._kv_block, self._kv_in_place = 0, False
+        if pool.has_paged:
             kv = pool.kv_leaf()
             self._kv_block = block_tokens(pool.page_size, kv.shape[-2],
                                           kv.shape[-1], kv.dtype,
                                           pool.pages_per_slot)
-        # the dense/vlm/moe layer scan carries a paged pool stacked over its
-        # layers, the block's donated state keeps it in one buffer, and the
-        # attention op reads it where it lies unless the kernel must pad it
-        self._kv_in_place = (
-            self.paged and cfg.family in ("dense", "vlm", "moe")
-            and paged_pool_in_place(pool.kv_leaf(), pool.quantized))
-        self.prefix_on = (bool(prefix_cache) and self.paged
+            self._kv_in_place = paged_pool_in_place(kv, pool.quantized)
+        self.prefix_on = (bool(prefix_cache)
                           and cfg.family in ("dense", "vlm", "moe"))
         self.scheduler = scheduler if scheduler is not None else Scheduler()
         self.defrag_threshold = float(defrag_threshold)
         self.overlap = bool(overlap)
-        self._pipe: List[_InFlight] = []    # one-deep dispatch pipeline
+        self._pipe: List[_InFlight] = []    # dispatched, not yet fetched
         self._block = make_decode_block(cfg, rules, k=self.k,
                                         max_len=self.max_len, eos_id=eos_id)
         self.state = init_decode_state(self.pool.make_cache(), num_slots)
@@ -267,9 +259,9 @@ class Engine:
         self._groups: dict = {}         # request id -> unfinished streams
         self.stats = EngineStats()
         if cfg.family == "audio":
-            row = lambda p, enc: prefill_audio_cache(
+            row = lambda p, enc: dict(cross=prefill_audio_cache(
                 p, cfg, init_cache(cfg, 1, self.max_len, enc_len=enc_len),
-                enc[None].astype(jnp.bfloat16))
+                enc[None].astype(jnp.bfloat16))["cross"])
             self._audio_row = jax.jit(row)
 
     # -------------------------------------------------------------- ingest
@@ -339,7 +331,7 @@ class Engine:
                 base_key = np.array([seed >> 32, seed & 0xFFFFFFFF],
                                     np.uint32)
             prompt = [int(t) for t in r.prompt]
-            P = self.pool.page_size if self.paged else 0
+            P = self.pool.page_size
             group_slots: List[int] = []
             m0, cow, pinned = 0, None, False
             for i in range(n_streams):
@@ -371,7 +363,7 @@ class Engine:
                             self.stats.prefix_tokens += m0 * n_streams
                             _M_PREFIX_HITS.inc()
                             _M_PREFIX_TOKENS.inc(m0 * n_streams)
-                    if n_streams > 1 and self.paged:
+                    if n_streams > 1:
                         # reserve the whole-prompt page span up front so
                         # the siblings below adopt (refcount-share) it
                         # instead of allocating duplicate pages
@@ -382,18 +374,16 @@ class Engine:
                             self.pool.pin_page(cow[0])
                             pinned = True
                 else:
-                    if self.paged:
-                        self.stats.shared_prompt_pages += \
-                            self.pool.adopt_prompt_pages(group_slots[0],
-                                                         slot, n)
-                        if cow is not None and (m0 // P) >= (n // P):
-                            # the trie match runs into the private boundary
-                            # page: this sibling needs its own CoW copy
-                            dst = self.pool.map_cow_page(slot, n // P)
-                            st = st._replace(cache=self.pool.copy_page(
-                                st.cache, cow[0], dst))
-                            self.stats.cow_copies += 1
-                            _M_COW.inc()
+                    self.stats.shared_prompt_pages += \
+                        self.pool.adopt_prompt_pages(group_slots[0], slot, n)
+                    if cow is not None and (m0 // P) >= (n // P):
+                        # the trie match runs into the private boundary
+                        # page: this sibling needs its own CoW copy
+                        dst = self.pool.map_cow_page(slot, n // P)
+                        st = st._replace(cache=self.pool.copy_page(
+                            st.cache, cow[0], dst))
+                        self.stats.cow_copies += 1
+                        _M_COW.inc()
                 self._prompt_buf[slot, :] = 0
                 self._prompt_buf[slot, :n] = np.asarray(r.prompt, np.int32)
                 self._prompt_len[slot] = n
@@ -443,23 +433,20 @@ class Engine:
 
     # -------------------------------------------------------------- defrag
     def _needs_defrag(self) -> bool:
-        """Threshold check only — used by the overlapped loop to decide
-        whether a pipeline flush (and its one-round bubble) is worth it.
-        Fenced slots awaiting release still count as live here; their frees
-        land next completion and the check runs every round, so a triggered
-        defrag is at most one round late."""
+        """Threshold check only — decides whether a pipeline flush (and,
+        overlapped, its one-round bubble) is worth it. Fenced slots awaiting
+        release still count as live here; their frees land next completion
+        and the check runs every round, so a triggered defrag is at most one
+        round late."""
         if self.pool.live_count and \
                 self.pool.fragmentation() >= self.defrag_threshold:
             return True
-        return self.paged and \
-            self.pool.page_fragmentation() >= self.defrag_threshold
+        return self.pool.page_fragmentation() >= self.defrag_threshold
 
-    def _maybe_defrag(self) -> None:
-        # defrag permutes slot rows / page tables in place: the overlapped
-        # loop must flush its pipeline first (no block may own moved rows)
+    def _defrag(self) -> None:
+        # defrag permutes slot rows / page tables in place: the loop must
+        # flush its pipeline first (no block may own moved rows)
         assert not self._pipe, "defrag with a block in flight"
-        if not self._needs_defrag():
-            return
         with obs.span("serve.defrag"):
             if self.pool.live_count and \
                     self.pool.fragmentation() >= self.defrag_threshold:
@@ -494,8 +481,7 @@ class Engine:
                                      for s, i in self._slot_stream.items()}
                 self.stats.defrags += 1
                 _M_DEFRAGS.inc(kind="slot")
-            if self.paged and \
-                    self.pool.page_fragmentation() >= self.defrag_threshold:
+            if self.pool.page_fragmentation() >= self.defrag_threshold:
                 # pure page permutation: slot contents (and the
                 # emission-count PRNG stream) are unchanged, so defrag stays
                 # invisible to tokens
@@ -519,20 +505,17 @@ class Engine:
                                 top_p=jnp.asarray(self._top_p),
                                 top_k=jnp.asarray(self._top_k),
                                 key=jnp.asarray(self.pool.slot_keys))
-            page_table = None
-            if self.paged:
-                # pre-reserve pages for every position this block can write,
-                # so the table is constant across the k in-scan steps; under
-                # overlap ``_len_host`` is one un-fetched block stale, so the
-                # horizon covers the in-flight block's k steps plus this
-                # one's
-                horizon = self.k * (2 if self.overlap else 1)
-                for slot in self._slot_req:
-                    self.pool.reserve(slot,
-                                      int(self._len_host[slot]) + horizon)
-                page_table = jnp.asarray(self.pool.tables)
-                self.stats.peak_live_pages = max(
-                    self.stats.peak_live_pages, self.pool.live_page_count())
+            # pre-reserve pages for every position this block can write, so
+            # the table is constant across the k in-scan steps; under overlap
+            # ``_len_host`` is one un-fetched block stale, so the horizon
+            # covers the in-flight block's k steps plus this one's
+            horizon = self.k * (2 if self.overlap else 1)
+            for slot in self._slot_req:
+                self.pool.reserve(slot, int(self._len_host[slot]) + horizon)
+            page_table = jnp.asarray(self.pool.tables) \
+                if self.pool.has_paged else None
+            self.stats.peak_live_pages = max(self.stats.peak_live_pages,
+                                             self.pool.live_page_count())
             ticket = obs.mark_dispatch("serve.dispatch")
             # the state is donated: the block's result replaces it
             self.state, out = self._block(
@@ -719,20 +702,15 @@ class Engine:
         # shed / rejected requests never held a slot: terminal delta only
         deltas = [StreamDelta(id=r.id, tokens=[], done=True, response=r)
                   for r in out]
-        if not self.overlap:
-            # classic blocking schedule: dispatch, then fetch immediately
-            if self._active.any():
-                d, o = self._complete_block(self._dispatch_block())
-                deltas += d
-                out += o
-                self._maybe_defrag()
-            return deltas, out
         if self._active.any():
             self._pipe.append(self._dispatch_block())
-        # keep the pipeline one deep: fetch the oldest block once a newer
-        # one is in flight (its host work hides behind device compute), and
-        # drain fully when nothing new was dispatched (tail of the stream)
-        while self._pipe and (len(self._pipe) > 1
+        # fetch the oldest block while the pipeline is deeper than its depth
+        # — blocking (0) fetches the block just dispatched; overlapped (1)
+        # fetches a block once a newer one is in flight, so its host work
+        # hides behind device compute — and drain fully when nothing new
+        # was dispatched (tail of the stream)
+        depth = 1 if self.overlap else 0
+        while self._pipe and (len(self._pipe) > depth
                               or not self._active.any()):
             d, o = self._complete_block(self._pipe.pop(0))
             deltas += d
@@ -744,7 +722,7 @@ class Engine:
                 d, o = self._complete_block(self._pipe.pop(0))
                 deltas += d
                 out += o
-            self._maybe_defrag()
+            self._defrag()
         return deltas, out
 
     def step(self, now: Optional[float] = None) -> List[Response]:

@@ -1,23 +1,31 @@
-"""Paged KV cache pool + radix-style shared-prefix reuse.
+"""The serving cache pool: request state in slots, attention K/V in pages,
+and radix-style shared-prefix reuse.
 
-``PagedCachePool`` scales :class:`repro.serve.cache.CachePool` from
-whole-row slots to sub-slot *pages*: every attention K/V leaf trades its
-``(..., num_slots, max_len, ...)`` row layout for a flat page pool
-``(..., num_pages, page_size, ...)`` plus a host-side per-slot page table
-``(num_slots, pages_per_slot) int32``. A slot's logical sequence position
-``p`` lives at pool page ``table[slot, p // page_size]``, row
-``p % page_size`` — the jitted decode block scatters new K/V through the
-table and the ``paged_attention`` op gathers through it, so cache capacity
-is no longer ``num_slots * max_len`` rows but however many pages are
-actually written.
+A *slot* is one sequence of the engine's fused decode block. The pool owns
+slot bookkeeping (allocate / free / row defrag, per-slot PRNG keys), page
+bookkeeping (page tables, refcounts, the prefix trie, copy-on-write, page
+defrag) and the pure-array operations on both; the engine owns the live
+cache pytree itself (it is threaded through the jitted k-step decode block
+as a carry).
 
-Which leaves get paged is *inferred*, exactly like the batch axes: the pool
-eval_shapes ``init_cache`` at two ``max_len`` values and diffs the shapes.
-A leaf whose sequence axis sits immediately after its batch axis is a KV
-page leaf; everything else — mamba2 conv/ssm state, whisper's
-``enc_len``-sized cross K/V, scalar ``pos`` — keeps the slot layout and the
-inherited slot ops (the paged leaves are masked out of ``batch_axes`` so
-``zero_slot`` / ``set_slot`` / row ``defrag`` never touch them).
+Every leaf of the ``repro.models.init_cache`` pytree keeps one of two
+layouts, *inferred* from shapes rather than hard-coded per family: the pool
+eval_shapes ``init_cache`` at two batch sizes and at two ``max_len`` values
+and diffs the shapes.
+
+- A leaf whose sequence axis sits right after its batch axis is attention
+  K/V. It trades its ``(L, num_slots, max_len, Hkv, Dh)`` rows for a page
+  pool ``(L, num_pages, page_size, Hkv, Dh)``, stacked over the layers that
+  read it, plus a host-side per-slot page table ``(num_slots,
+  pages_per_slot) int32``. A slot's logical position ``p`` lives at pool
+  page ``table[slot, p // page_size]``, row ``p % page_size``: the decode
+  block scatters new K/V through the table and the ``paged_attention`` op
+  gathers through it, so capacity is however many pages are written, not
+  ``num_slots * max_len`` rows.
+- Every other leaf with a batch axis — mamba2 conv/ssm state, whisper's
+  ``enc_len``-sized cross K/V — keeps one row per slot (``zero_slot``,
+  ``set_slot``, row ``defrag``). Batchless leaves (``pos``, MoE counts) are
+  neither. A family without attention (mamba2) has no pages and no table.
 
 Page 0 is a reserved scratch page: freeing a slot zeroes its table row on
 the host, so the stale frozen-slot writes that the fused k-block keeps
@@ -36,10 +44,10 @@ slot tables and trie nodes; a page returns to the free heap only when the
 count hits zero, and the trie evicts least-recently-matched leaves when the
 pool runs dry. ``defrag_pages`` compacts live pages to the front of the
 pool with a pure permutation — refcounts, tables and trie pointers are
-remapped through the same LUT, and the PR-5 emission-count PRNG keys are
+remapped through the same LUT, and the emission-count PRNG keys are
 untouched, so sampled streams stay bit-identical across page defrags.
 
-Quantized pages (``kv_dtype="int8"``): the pageable K/V leaves store int8
+Quantized pages (``kv_dtype="int8"``): the paged K/V leaves store int8
 with a sibling f32 scale leaf per page (``k_scale``/``v_scale``, one scale
 per page row per KV head — symmetric absmax over head_dim). Quantization
 happens on scatter in the decode write path (``models.blocks``) and both
@@ -49,6 +57,17 @@ they scale. At the bf16 leaves the pool replaces, an int8 page plus its
 scales costs ~(Dh+4)/(2*Dh) ≈ half the bytes — the default ``num_pages``
 doubles accordingly, multiplying resident-request capacity ~2x at ~constant
 pool bytes (``page_bytes`` exposes the exact accounting).
+
+Sharding: with ``rules`` bound, the pool cache is laid out by
+``repro.dist.cache_specs`` (pages@data and page rows@model, exactly where
+the rows they replace put batch and sequence), so the serving engine runs
+on the same production meshes as the trainer.
+
+RNG state: each slot also carries a per-request PRNG key (``seed_slot`` /
+``slot_keys``) consumed by the sampled decode path (``repro.serve.sampling``).
+The key is request state, not slot state — it is seeded at admission, zeroed
+on free, and follows the request through defrag, which is what makes sampled
+token streams independent of slot placement.
 """
 from __future__ import annotations
 
@@ -61,37 +80,53 @@ import numpy as np
 
 from repro.models import init_cache
 from repro.dist import cache_shardings
-from repro.serve.cache import CachePool, SlotError, _NO_BATCH
+
+#: axis entry of a leaf that has no such axis (a batchless leaf's slot axis,
+#: a slot-row leaf's page axis)
+_NO_AXIS = -1
+
+
+class SlotError(RuntimeError):
+    """Invalid slot transition (double allocate/free)."""
 
 
 class PageError(RuntimeError):
     """Page pool exhausted (or invalid page transition)."""
 
 
-def _page_axes(cfg, max_len: int, enc_len: Optional[int], batch_axes):
-    """Pytree of sequence-axis indices for pageable leaves.
+def _layout_axes(cfg, max_len: int, enc_len: Optional[int]):
+    """-> ``(slot_axes, seq_axes)``, pytrees over ``init_cache``'s leaves.
 
-    A leaf is pageable iff varying ``max_len`` (with ``enc_len`` pinned)
-    moves exactly one axis *and* that axis sits immediately after the leaf's
-    batch axis — the ``(..., B, seq, heads, head_dim)`` KV layout shared by
-    every attention family. Returns the sequence-axis index per leaf, or
-    ``_NO_BATCH`` for leaves that stay in slot layout.
-    """
-    a = jax.eval_shape(lambda: init_cache(cfg, 2, max_len, enc_len=enc_len))
-    b = jax.eval_shape(lambda: init_cache(cfg, 2, max_len + 1, enc_len=enc_len))
+    A leaf's batch axis is the one axis that moves with the batch size. A
+    leaf pages iff varying ``max_len`` (with ``enc_len`` pinned) moves
+    exactly the axis right after its batch axis — the ``(..., B, seq,
+    heads, head_dim)`` K/V layout of every attention family: its entry in
+    ``seq_axes`` is that sequence axis, and in ``slot_axes`` ``_NO_AXIS``.
+    Every other leaf keeps its batch axis in ``slot_axes``."""
+    shapes = lambda b, n: jax.eval_shape(
+        lambda: init_cache(cfg, b, n, enc_len=enc_len))
+    base, wide, deep = shapes(2, max_len), shapes(3, max_len), \
+        shapes(2, max_len + 1)
 
-    def diff(x, y, bax):
-        axes = [i for i, (p, q) in enumerate(zip(x.shape, y.shape)) if p != q]
-        if len(axes) != 1 or bax == _NO_BATCH:
-            return _NO_BATCH
-        return axes[0] if axes[0] == bax + 1 else _NO_BATCH
+    def moved(x, y):
+        return [i for i, (p, q) in enumerate(zip(x.shape, y.shape)) if p != q]
 
-    return jax.tree.map(diff, a, b, batch_axes)
+    def seq(x, y, z):
+        b = moved(x, y)
+        assert len(b) <= 1, f"ambiguous batch axis for shape {x.shape}"
+        return b[0] + 1 if b and moved(x, z) == [b[0] + 1] else _NO_AXIS
+
+    def slot(x, y, z):
+        b = moved(x, y)
+        return b[0] if b and seq(x, y, z) == _NO_AXIS else _NO_AXIS
+
+    return (jax.tree.map(slot, base, wide, deep),
+            jax.tree.map(seq, base, wide, deep))
 
 
 def _with_scale_siblings(tree, axes, fn):
-    """Rebuild ``tree`` (dict/list/tuple pytree), giving paged K/V dict
-    leaves a ``<name>_scale`` sibling.
+    """Rebuild the dict pytree ``tree``, giving paged K/V leaves a
+    ``<name>_scale`` sibling.
 
     ``fn(name, leaf, ax) -> (new_leaf, scale_or_None)`` decides both the
     leaf transform and whether a sibling is added (None: no sibling);
@@ -101,27 +136,15 @@ def _with_scale_siblings(tree, axes, fn):
     parents inside the cache pytree so they ride the jitted decode block's
     carry like any other cache leaf.
     """
-    if isinstance(tree, dict):
-        out = {}
-        for name in tree:
-            sub, ax = tree[name], axes[name]
-            if isinstance(sub, (dict, list, tuple)):
-                out[name] = _with_scale_siblings(sub, ax, fn)
-            else:
-                leaf, scale = fn(name, sub, ax)
-                out[name] = leaf
-                if scale is not None:
-                    out[name + "_scale"] = scale
-        return out
-    if isinstance(tree, (list, tuple)):
-        vals = []
-        for sub, ax in zip(tree, axes):
-            if isinstance(sub, (dict, list, tuple)):
-                vals.append(_with_scale_siblings(sub, ax, fn))
-            else:
-                vals.append(fn(None, sub, ax)[0])
-        return type(tree)(vals)
-    return fn(None, tree, axes)[0]
+    out = {}
+    for name, sub in tree.items():
+        if isinstance(sub, dict):
+            out[name] = _with_scale_siblings(sub, axes[name], fn)
+            continue
+        out[name], scale = fn(name, sub, axes[name])
+        if scale is not None:
+            out[name + "_scale"] = scale
+    return out
 
 
 class _TrieNode:
@@ -250,33 +273,35 @@ class PrefixCache:
             node.page = int(lut[node.page])
 
 
-class PagedCachePool(CachePool):
-    """CachePool whose attention K/V leaves live in a shared page pool.
-
-    Slot bookkeeping (allocate/free/owner/keys/row-defrag) is inherited; the
-    paged leaves are carved out of ``batch_axes`` so every inherited slot op
-    skips them, and this class adds the page-table layer on top.
-    """
+class CachePool:
+    """Slots and pages of the serving cache for ``num_slots`` sequences of
+    up to ``max_len`` tokens, ``page_size`` tokens a page (module
+    docstring)."""
 
     def __init__(self, cfg, num_slots: int, max_len: int, *,
                  page_size: int, rules=None, enc_len: Optional[int] = None,
                  num_pages: Optional[int] = None, kv_dtype: str = "f32"):
-        if page_size < 1:
+        if num_slots < 1:
+            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        if page_size is None or page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if kv_dtype not in ("f32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'f32' or 'int8', got {kv_dtype!r}")
         if getattr(cfg, "family", None) == "audio" and enc_len is None:
             enc_len = max_len      # pin enc_len so the max_len diff is clean
-        super().__init__(cfg, num_slots, max_len, rules=rules,
-                         enc_len=enc_len)
+        self.cfg = cfg
+        self.num_slots = int(num_slots)
+        self.max_len = int(max_len)
+        self.enc_len = enc_len
+        self.rules = rules
         self.page_size = int(page_size)
-        self.pages_per_slot = -(-self.max_len // self.page_size)   # ceil
-        base_pax = _page_axes(cfg, self.max_len, self.enc_len,
-                              self.batch_axes)
-        self.has_paged = any(ax != _NO_BATCH
-                             for ax in jax.tree.leaves(base_pax))
-        self.kv_dtype = kv_dtype
+        slot_axes, seq_axes = _layout_axes(cfg, self.max_len, enc_len)
+        self.has_paged = any(ax != _NO_AXIS
+                             for ax in jax.tree.leaves(seq_axes))
+        # a pool without attention K/V has no pages: empty tables
+        self.pages_per_slot = -(-self.max_len // self.page_size) \
+            if self.has_paged else 0
         self.quantized = kv_dtype == "int8" and self.has_paged
         # +1 for the reserved scratch page 0; default backing is full
         # capacity, so reserve() can always succeed after trie eviction. An
@@ -287,30 +312,29 @@ class PagedCachePool(CachePool):
             num_pages = 1 + self.num_slots * self.pages_per_slot * \
                 (2 if self.quantized else 1)
         self.num_pages = int(num_pages)
-        if self.num_pages < 2:
+        if self.has_paged and self.num_pages < 2:
             raise ValueError("num_pages must cover scratch + one real page")
-        # _base_page_axes matches the init_cache structure (no scale leaves);
-        # page_axes/batch_axes below match the *actual* pool cache, which in
+        # ``_seq_axes`` matches the init_cache structure (no scale leaves);
+        # ``page_axes``/``batch_axes`` match the pool cache, which in
         # quantized mode carries k_scale/v_scale siblings. A scale leaf is
         # its parent minus the trailing head_dim axis, so its page axis sits
         # at the same index (pax - 1) — every page op (copy_page, defrag
         # take, LUT permute) applies to it unchanged under the parent's pax.
-        self._base_page_axes = base_pax
-        self.page_axes = base_pax
-        # paged leaves leave the slot world: inherited ops must skip them
-        self.batch_axes = jax.tree.map(
-            lambda bax, pax: _NO_BATCH if pax != _NO_BATCH else bax,
-            self.batch_axes, base_pax)
+        self._seq_axes = seq_axes
+        self.page_axes, self.batch_axes = seq_axes, slot_axes
         if self.quantized:
-            self.page_axes = _with_scale_siblings(
-                base_pax, base_pax,
-                lambda name, pax, _: (pax, pax if self._quant_leaf(name, pax)
-                                      else None))
-            self.batch_axes = _with_scale_siblings(
-                self.batch_axes, base_pax,
-                lambda name, bax, pax: (bax, _NO_BATCH
-                                        if self._quant_leaf(name, pax)
-                                        else None))
+            sibling = lambda tree, ax_of: _with_scale_siblings(
+                tree, seq_axes, lambda name, ax, pax: (
+                    ax, ax_of(pax) if self._quant_leaf(name, pax) else None))
+            self.page_axes = sibling(seq_axes, lambda pax: pax)
+            self.batch_axes = sibling(slot_axes, lambda pax: _NO_AXIS)
+        # slots: a min-heap keeps live slots packed at the front (lowest
+        # index first) with O(log n) frees
+        self._free: List[int] = list(range(self.num_slots))
+        self._owner: Dict[int, str] = {}
+        # per-slot PRNG key data (jax.random.PRNGKey rows) for sampled decode
+        self._keys = np.zeros((self.num_slots, 2), np.uint32)
+        # pages
         self._tables = np.zeros((self.num_slots, self.pages_per_slot),
                                 np.int32)
         self._n_pages = np.zeros((self.num_slots,), np.int32)
@@ -324,8 +348,8 @@ class PagedCachePool(CachePool):
     def _quant_leaf(name, pax) -> bool:
         """Paged attention K/V value leaves are the ones that quantize (and
         grow a scale sibling); whisper's cross K/V keep slot layout (``pax ==
-        _NO_BATCH``) and are excluded along with conv/ssm state."""
-        return pax != _NO_BATCH and name in ("k", "v")
+        _NO_AXIS``) and are excluded along with conv/ssm state."""
+        return pax != _NO_AXIS and name in ("k", "v")
 
     def _pool_arrays(self):
         """The pool cache pytree (pre-sharding) — paged leaves in page-pool
@@ -339,12 +363,12 @@ class PagedCachePool(CachePool):
 
         if not self.quantized:
             return jax.tree.map(
-                lambda leaf, pax: leaf if pax == _NO_BATCH
+                lambda leaf, pax: leaf if pax == _NO_AXIS
                 else jnp.zeros(paged_shape(leaf, pax), leaf.dtype),
-                cache, self._base_page_axes)
+                cache, self._seq_axes)
 
         def f(name, leaf, pax):
-            if pax == _NO_BATCH:
+            if pax == _NO_AXIS:
                 return leaf, None
             shp = paged_shape(leaf, pax)
             if not self._quant_leaf(name, pax):
@@ -354,9 +378,10 @@ class PagedCachePool(CachePool):
             return (jnp.zeros(shp, jnp.int8),
                     jnp.ones(shp[:-1], jnp.float32))
 
-        return _with_scale_siblings(cache, self._base_page_axes, f)
+        return _with_scale_siblings(cache, self._seq_axes, f)
 
     def make_cache(self):
+        """Fresh pool cache pytree; ownership passes to the caller."""
         cache = self._pool_arrays()
         if self.rules is not None and self.rules.n_devices > 1:
             cache = jax.device_put(cache, cache_shardings(cache, self.rules))
@@ -364,7 +389,7 @@ class PagedCachePool(CachePool):
 
     def kv_leaf(self) -> jax.ShapeDtypeStruct:
         """Shape and dtype of a paged K leaf,
-        ``(..., num_pages, page_size, Hkv, head_dim)``: what the paged
+        ``(L, num_pages, page_size, Hkv, head_dim)``: what the paged
         decode kernel reads a page of."""
         shapes = jax.tree_util.tree_leaves_with_path(
             jax.eval_shape(self._pool_arrays))
@@ -380,25 +405,144 @@ class PagedCachePool(CachePool):
         total = 0
         for leaf, pax in zip(jax.tree.leaves(shapes),
                              jax.tree.leaves(self.page_axes)):
-            if pax == _NO_BATCH:
+            if pax == _NO_AXIS:
                 continue
             n = int(np.prod(leaf.shape)) // leaf.shape[pax - 1]
             total += n * leaf.dtype.itemsize
         return total
 
-    def set_slot(self, cache, slot: int, row_cache):
-        # the batch=1 row cache comes from init_cache and has no scale
-        # leaves; pad its structure with dummies (their batch_axes entries
-        # are _NO_BATCH, so the inherited write skips them)
-        if self.quantized:
-            row_cache = _with_scale_siblings(
-                row_cache, self._base_page_axes,
-                lambda name, leaf, pax: (leaf, jnp.zeros(())
-                                         if self._quant_leaf(name, pax)
-                                         else None))
-        return super().set_slot(cache, slot, row_cache)
+    # ------------------------------------------------------- slot bookkeeping
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
 
-    # ------------------------------------------------------------ bookkeeping
+    @property
+    def live_count(self) -> int:
+        return len(self._owner)
+
+    def live_slots(self) -> List[int]:
+        return sorted(self._owner)
+
+    def owner(self, slot: int) -> Optional[str]:
+        return self._owner.get(slot)
+
+    def _check(self, slot: int) -> None:
+        if slot not in self._owner:
+            raise SlotError(f"slot {slot} is not allocated")
+
+    def allocate(self, request_id: str) -> int:
+        if not self._free:
+            raise SlotError("cache pool exhausted")
+        slot = heapq.heappop(self._free)
+        assert slot not in self._owner, "free list / owner map out of sync"
+        self._owner[slot] = request_id
+        return slot
+
+    def free(self, slot: int) -> None:
+        """Release ``slot`` and its references to its pages."""
+        self._check(slot)
+        for i in range(int(self._n_pages[slot])):
+            self._decref(int(self._tables[slot, i]))
+        # stale frozen-slot writes (and any read) now divert to scratch
+        self._tables[slot, :] = 0
+        self._n_pages[slot] = 0
+        del self._owner[slot]
+        self._keys[slot] = 0               # request key dies with the request
+        heapq.heappush(self._free, slot)
+
+    def seed_slot(self, slot: int, seed: int) -> None:
+        """Bind a slot's PRNG key to a request seed (sampled decode). The
+        key is per-request: it survives defrag along with the cache rows and
+        is zeroed when the slot is freed.
+
+        The key data is built on host — the threefry2x32 layout of
+        ``jax.random.PRNGKey``, [seed >> 32, seed & 0xffffffff] — rather
+        than materializing a device PRNGKey and fetching it back: seeding
+        happens at admission, and a device round trip there would be an
+        uncounted host sync per sampled request (the ``obs.sync_audit``
+        boundary check caught exactly that)."""
+        self._check(slot)
+        self._keys[slot] = np.array([seed >> 32, seed & 0xFFFFFFFF],
+                                    np.uint32)
+
+    def set_slot_key(self, slot: int, key) -> None:
+        """Bind a slot to pre-derived raw key data ((2,) uint32 threefry
+        words). The n>1 fan-out path derives stream i's key as
+        ``host_fold_in(base_key, i)`` — still host-only, same no-hidden-sync
+        contract as :meth:`seed_slot`."""
+        self._check(slot)
+        self._keys[slot] = np.asarray(key, np.uint32).reshape(2)
+
+    @property
+    def slot_keys(self) -> np.ndarray:
+        """(num_slots, 2) uint32 per-slot key data (zeros for greedy/free)."""
+        return self._keys
+
+    def fragmentation(self) -> float:
+        """Hole fraction of the occupied slot span [0, max live slot]."""
+        if not self._owner:
+            return 0.0
+        span = max(self._owner) + 1
+        return 1.0 - len(self._owner) / span
+
+    # ------------------------------------------------------- pure slot ops
+    def zero_slot(self, cache, slot: int):
+        """Zero one slot's rows (required for SSM state reuse; stale K/V
+        pages are invisible behind per-slot kv_valid)."""
+        def f(leaf, ax):
+            if ax == _NO_AXIS:
+                return leaf
+            idx = (slice(None),) * ax + (slot,)
+            return leaf.at[idx].set(jnp.zeros((), leaf.dtype))
+        return jax.tree.map(f, cache, self.batch_axes)
+
+    def set_slot(self, cache, slot: int, rows):
+        """Write batch=1 ``rows`` — a dict sub-tree of the cache's slot-row
+        leaves, such as whisper's prefilled cross K/V — into ``slot``."""
+        def put(leaf, row, ax):
+            if isinstance(row, dict):
+                return dict(leaf, **{n: put(leaf[n], row[n], ax[n])
+                                     for n in row})
+            idx = (slice(None),) * ax + (slot,)
+            return leaf.at[idx].set(
+                jnp.take(row, 0, axis=ax).astype(leaf.dtype))
+        return put(cache, rows, self.batch_axes)
+
+    def defrag(self, cache) -> Tuple[object, List[int], Dict[int, int]]:
+        """Compact live slots to the lowest indices, preserving contents.
+
+        Returns ``(new_cache, perm, mapping)``: ``perm`` is the old-slot
+        permutation applied along every slot-row axis and to the page
+        tables (new slot i holds old slot ``perm[i]``) — callers must apply
+        the same ``jnp.take(..., perm)`` to any per-slot side arrays
+        (lengths, tokens, masks); ``mapping`` is old->new for the live
+        slots only.
+        """
+        live = self.live_slots()
+        perm = live + [s for s in range(self.num_slots) if s not in self._owner]
+        mapping = {old: new for new, old in enumerate(live)}
+        perm_dev = jnp.asarray(perm, jnp.int32)
+
+        def f(leaf, ax):
+            if ax == _NO_AXIS:
+                return leaf
+            return jnp.take(leaf, perm_dev, axis=ax)
+
+        new_cache = jax.tree.map(f, cache, self.batch_axes)
+        self._owner = {mapping[s]: rid for s, rid in self._owner.items()}
+        # ascending range is already a valid min-heap
+        self._free = list(range(len(live), self.num_slots))
+        hp = np.asarray(perm)
+        self._keys = self._keys[hp]          # keys follow their request
+        self._tables = self._tables[hp]
+        self._n_pages = self._n_pages[hp]
+        return new_cache, perm, mapping
+
+    def take_rows(self, per_slot, perm):
+        """Apply a defrag permutation to a (num_slots, ...) device array."""
+        return jnp.take(per_slot, jnp.asarray(perm, jnp.int32), axis=0)
+
+    # ------------------------------------------------------- page bookkeeping
     @property
     def tables(self) -> np.ndarray:
         """(num_slots, pages_per_slot) int32 host page table. Entries past a
@@ -435,10 +579,9 @@ class PagedCachePool(CachePool):
     def reserve(self, slot: int, upto_len: int) -> None:
         """Grow ``slot``'s table to cover positions [0, min(upto_len,
         max_len)). Called before each fused k-block dispatch so the table is
-        constant within a block."""
-        if slot not in self._owner:
-            raise SlotError(f"slot {slot} is not allocated")
-        need = -(-min(int(upto_len), self.max_len) // self.page_size)
+        constant within a block. A pool without pages reserves none."""
+        self._check(slot)
+        need = min(-(-int(upto_len) // self.page_size), self.pages_per_slot)
         n = int(self._n_pages[slot])
         while n < need:
             pg = self._take_free_page()
@@ -446,16 +589,6 @@ class PagedCachePool(CachePool):
             self._tables[slot, n] = pg
             n += 1
         self._n_pages[slot] = n
-
-    def free(self, slot: int) -> None:
-        if slot not in self._owner:
-            raise SlotError(f"slot {slot} is not allocated")
-        for i in range(int(self._n_pages[slot])):
-            self._decref(int(self._tables[slot, i]))
-        # stale frozen-slot writes (and any read) now divert to scratch
-        self._tables[slot, :] = 0
-        self._n_pages[slot] = 0
-        super().free(slot)
 
     # -------------------------------------------------------- prefix sharing
     def map_prefix(self, slot: int, prompt: Sequence[int]
@@ -470,8 +603,7 @@ class PagedCachePool(CachePool):
         ``len(prompt) - 1`` so the final prompt token is always consumed
         in-loop (it primes the first emission).
         """
-        if slot not in self._owner:
-            raise SlotError(f"slot {slot} is not allocated")
+        self._check(slot)
         if int(self._n_pages[slot]):
             raise PageError(f"slot {slot} already holds pages")
         full, partial = self.prefix.match(prompt)
@@ -504,8 +636,7 @@ class PagedCachePool(CachePool):
         pages entirely inside the prompt *and* entirely written
         (``written_len`` tokens consumed) are published. Returns the number
         of pages newly inserted."""
-        if slot not in self._owner:
-            raise SlotError(f"slot {slot} is not allocated")
+        self._check(slot)
         P = self.page_size
         limit = min(min(int(written_len), len(prompt)) // P,
                     int(self._n_pages[slot]))
@@ -531,8 +662,7 @@ class PagedCachePool(CachePool):
         token) stays private. Returns the number of shared pages.
         """
         for s in (src_slot, dst_slot):
-            if s not in self._owner:
-                raise SlotError(f"slot {s} is not allocated")
+            self._check(s)
         if int(self._n_pages[dst_slot]):
             raise PageError(f"slot {dst_slot} already holds pages")
         n_shared = min(int(n_tok) // self.page_size,
@@ -548,8 +678,7 @@ class PagedCachePool(CachePool):
         """Allocate a fresh private page at ``table[slot, index]`` (the
         fan-out boundary-page CoW destination). Returns the new page; the
         caller owns the device ``copy_page`` into it."""
-        if slot not in self._owner:
-            raise SlotError(f"slot {slot} is not allocated")
+        self._check(slot)
         if int(self._n_pages[slot]) != index:
             raise PageError(
                 f"slot {slot}: cow index {index} != next page "
@@ -571,7 +700,7 @@ class PagedCachePool(CachePool):
     def copy_page(self, cache, src: int, dst: int):
         """Device-copy pool page ``src`` into ``dst`` (copy-on-write)."""
         def f(leaf, pax):
-            if pax == _NO_BATCH:
+            if pax == _NO_AXIS:
                 return leaf
             ax = pax - 1                      # page axis replaced batch axis
             row = jax.lax.index_in_dim(leaf, src, axis=ax, keepdims=False)
@@ -604,7 +733,7 @@ class PagedCachePool(CachePool):
         perm_dev = jnp.asarray(perm)
 
         def f(leaf, pax):
-            if pax == _NO_BATCH:
+            if pax == _NO_AXIS:
                 return leaf
             return jnp.take(leaf, perm_dev, axis=pax - 1)
 
@@ -614,11 +743,3 @@ class PagedCachePool(CachePool):
         self.prefix.remap(lut)
         self._free_pages = list(range(len(live), self.num_pages))
         return new_cache
-
-    def defrag(self, cache):
-        """Slot-row defrag (inherited) + page-table row permutation."""
-        new_cache, perm, mapping = super().defrag(cache)
-        hp = np.asarray(perm)
-        self._tables = self._tables[hp]
-        self._n_pages = self._n_pages[hp]
-        return new_cache, perm, mapping

@@ -73,9 +73,12 @@ def test_arch_decode_step(name):
 
 @pytest.mark.parametrize("family_arch", ["llama3-8b", "granite-moe-1b-a400m",
                                          "mamba2-780m", "zamba2-2.7b",
-                                         "qwen2-vl-2b"])
+                                         "qwen2-vl-2b", "whisper-medium"])
 def test_decode_matches_teacher_forcing(family_arch):
-    """Sequential cached decode == teacher-forced forward (same tokens).
+    """Sequential cached decode == teacher-forced forward (same tokens;
+    whisper decodes against the cross K/V prefilled from the same encoder
+    input). This is the check of the decode's layer indexing that the
+    engine's parity tests, whose reference shares it, cannot make.
 
     MoE uses an over-provisioned capacity factor so no token is dropped —
     capacity dropping is batch-composition-dependent and legitimately differs
@@ -96,10 +99,15 @@ def test_decode_matches_teacher_forcing(family_arch):
                     "decode smoke + dense path")
     toks = jax.random.randint(jax.random.PRNGKey(5), (B, seq), 0, cfg.vocab)
     batch = dict(tokens=toks, labels=toks)
+    if cfg.family == "audio":
+        batch["enc_embeds"] = make_batch(cfg)["enc_embeds"]
     with registry.use("xla"):
         tf_logits, _ = jax.jit(lambda p, b: forward(p, cfg, b))(params, batch)
 
-    cache = init_cache(cfg, B, seq)
+    cache = init_cache(cfg, B, seq, enc_len=S)
+    if cfg.family == "audio":
+        cache = jax.jit(lambda p, c, e: prefill_audio_cache(p, cfg, c, e))(
+            params, cache, batch["enc_embeds"])
     step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t))
     outs = []
     with registry.use("xla"):       # pin the decode trace as well (encdec

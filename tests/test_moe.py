@@ -30,8 +30,7 @@ from repro.launch.steps import make_serve_step  # noqa: E402
 from repro.models import init_cache, init_params  # noqa: E402
 from repro.models.moe import held_experts, route  # noqa: E402
 from repro.models.mlp import swiglu  # noqa: E402
-from repro.serve import Engine, Request  # noqa: E402
-from repro.serve.paging import PagedCachePool  # noqa: E402
+from repro.serve import CachePool, Engine, Request  # noqa: E402
 
 SEED = 3_150_000_123
 
@@ -71,7 +70,7 @@ def test_paged_decode_matches_the_reference_forward(norm):
     toks = rng.integers(0, cfg["vocab_size"], 48).astype(np.int32)
     want = np.asarray(moe_lm.logits(params, jnp.asarray(toks), shape))
 
-    pool = PagedCachePool(arch, 2, 64, page_size=8)
+    pool = CachePool(arch, 2, 64, page_size=8)
     slot = pool.allocate("r")
     pool.reserve(slot, len(toks))
     table = jnp.asarray(pool.tables)

@@ -59,7 +59,7 @@ def _requests(cfg, sampling=None):
     return reqs
 
 
-def _drain(cfg, params, *, k, sampling, overlap, page_size=None,
+def _drain(cfg, params, *, k, sampling, overlap, page_size=16,
            prefix_cache=False):
     with registry.use("xla"):
         eng = Engine(params, cfg, num_slots=3, max_len=MAX_LEN, k=k,
@@ -102,7 +102,7 @@ def test_overlap_paged_prefix_parity():
     want, _ = _drain(cfg, params, overlap=False, **kw)
     got, eng = _drain(cfg, params, overlap=True, **kw)
     assert got == want
-    assert eng.paged
+    assert eng.pool.has_paged
     assert eng.pool.live_page_count() == 0
 
 

@@ -1,13 +1,14 @@
 """Paged KV cache + shared-prefix reuse: parity, refcounts, regressions.
 
-The tentpole claim is layout invisibility: the paged engine — sub-slot page
+The tentpole claim is layout invisibility: the engine — sub-slot page
 tables, scratch-page retirement, copy-on-write prefix sharing, page defrag —
-must emit exactly the tokens of the slot-layout engine for every family, at
-every k, greedy and sampled. Slot tokens are k-invariant (PR 5's emission-
-count PRNG), so one slot reference per family/mode anchors the whole sweep.
+must emit exactly the tokens of the model's plain whole-batch decode
+(``init_cache`` slot rows, one request at a time) for every family, at
+every k, greedy and sampled. Engine tokens are k-invariant (the emission-
+count PRNG), so one plain reference per family/mode anchors the sweep.
 
-Engine-level tests pin ``registry.use("xla")``: the slot engine's decode
-attention falls back to XLA (kv_valid_len), while the paged engine would
+Engine-level tests pin ``registry.use("xla")``: the plain decode's
+attention falls back to XLA (kv_valid_len), while the engine would
 otherwise pick the Pallas paged kernel under ``REPRO_BACKEND=pallas`` — the
 backends agree only to float tolerance, and these tests assert exact token
 equality. The op-level test below covers the pallas/xla agreement explicitly.
@@ -25,10 +26,12 @@ from repro.kernels import registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.models.attention import paged_attention, quantize_kv
-from repro.serve import (CachePool, Engine, PagedCachePool, PageError,
-                         PrefixCache, Request, SamplingParams, Scheduler,
-                         FINISH_EOS, FINISH_LENGTH)
-from repro.serve.cache import _NO_BATCH
+from repro.serve import (CachePool, Engine, PageError, PrefixCache, Request,
+                         SamplingParams, Scheduler, FINISH_EOS,
+                         FINISH_LENGTH)
+from repro.serve.paging import _NO_AXIS
+from repro.serve.sampling import SlotSampling, sample_tokens
+from test_serve import _classic_tokens, _plain_cache, _plain_step
 
 MAX_LEN = 32
 PROMPTS = [[7], [3, 11, 5], [9, 2], [4, 4, 4, 8], [13]]
@@ -39,9 +42,9 @@ SAMPLED = SamplingParams(temperature=0.8, top_p=0.9, top_k=8)
 
 CFG_TINY = smoke_config(get_arch("internlm2-1.8b"))
 
-#: slot-engine reference streams, keyed (arch, mode) — slot tokens are
-#: k-invariant, so one drain per family/mode anchors the k sweep
-_SLOT_REFS: dict = {}
+#: plain-decode reference streams, keyed (arch, mode) — engine tokens are
+#: k-invariant, so one reference per family/mode anchors the k sweep
+_PLAIN_REFS: dict = {}
 
 
 @pytest.fixture(scope="module", params=FAMILY_ARCHS)
@@ -66,7 +69,7 @@ def _requests(cfg, sampling=None):
     return reqs
 
 
-def _drain(cfg, params, *, k, sampling, page_size=None, prefix_cache=False):
+def _drain(cfg, params, *, k, sampling, page_size=16, prefix_cache=False):
     with registry.use("xla"):
         eng = Engine(params, cfg, num_slots=3, max_len=MAX_LEN, k=k,
                      max_prompt=8, enc_len=16 if cfg.family == "audio"
@@ -76,27 +79,67 @@ def _drain(cfg, params, *, k, sampling, page_size=None, prefix_cache=False):
     return {r.id: list(r.tokens) for r in out}, eng
 
 
+@jax.jit
+def _draw(logits, tok, samp, n_out):
+    return sample_tokens(logits[:, -1], tok[:, 0], samp, n_out)[:, None]
+
+
+def _sampled_tokens(cfg, params, req):
+    """Reference for a sampled request: the model's plain decode of it
+    alone (``make_serve_step`` over a one-row ``init_cache``), each token
+    drawn by ``sample_tokens`` under the request's key and its emission
+    count."""
+    sp = req.sampling
+    samp = SlotSampling(
+        temperature=jnp.asarray([sp.temperature], jnp.float32),
+        top_p=jnp.asarray([sp.top_p], jnp.float32),
+        top_k=jnp.asarray([sp.top_k], jnp.int32),
+        key=jnp.asarray([[sp.seed >> 32, sp.seed & 0xFFFFFFFF]], jnp.uint32))
+    step = _plain_step(cfg, registry.resolved_backend())
+    cache = _plain_cache(cfg, params, req.enc_embeds)
+    for t in req.prompt:
+        tok, logits, cache = step(params, cache, jnp.array([[t]], jnp.int32))
+    out = []
+    for i in range(req.max_new_tokens):
+        if i:
+            tok, logits, cache = step(params, cache, tok)
+        tok = _draw(logits, tok, samp, jnp.asarray([i], jnp.int32))
+        out.append(int(tok[0, 0]))
+    return out
+
+
+def _plain_tokens(cfg, params, sampling):
+    with registry.use("xla"):
+        if sampling is None:
+            return {r.id: _classic_tokens(cfg, params, r.prompt,
+                                          r.max_new_tokens, r.enc_embeds)
+                    for r in _requests(cfg)}
+        return {r.id: _sampled_tokens(cfg, params, r)
+                for r in _requests(cfg, sampling)}
+
+
 # ------------------------------------------------------------------ parity --
 @pytest.mark.parametrize("mode", ["greedy", "sampled"])
 @pytest.mark.parametrize("k", [1, 4, 16])
-def test_paged_engine_matches_slot_engine(family_setup, k, mode):
-    """Every family, every k, greedy and sampled: the paged engine is
-    token-identical to the slot engine. Odd page size 5 vs MAX_LEN 32
-    forces a ragged final page per slot."""
+def test_engine_matches_plain_decode(family_setup, k, mode):
+    """Every family, every k, greedy and sampled: the engine is
+    token-identical to the model's plain decode of each request alone.
+    Odd page size 5 vs MAX_LEN 32 forces a ragged final page per slot."""
     cfg, params = family_setup
     sampling = None if mode == "greedy" else SAMPLED
     ref_key = (cfg.name, mode)
-    if ref_key not in _SLOT_REFS:
-        _SLOT_REFS[ref_key] = _drain(cfg, params, k=4, sampling=sampling)[0]
-    want = _SLOT_REFS[ref_key]
+    if ref_key not in _PLAIN_REFS:
+        _PLAIN_REFS[ref_key] = _plain_tokens(cfg, params, sampling)
+    want = _PLAIN_REFS[ref_key]
     got, eng = _drain(cfg, params, k=k, sampling=sampling, page_size=5)
     assert got == want
     if cfg.family == "ssm":
-        # pure-SSM has no pageable leaves: the engine must fall back to the
-        # slot pool instead of building a degenerate page world
-        assert not eng.paged
+        # pure-SSM has no attention K/V: a pool without page leaves or
+        # tables, not a degenerate page world
+        assert not eng.pool.has_paged and eng.pool.pages_per_slot == 0
+        assert not any(ax != _NO_AXIS
+                       for ax in jax.tree.leaves(eng.pool.page_axes))
     else:
-        assert eng.paged
         assert eng.pool.live_page_count() == 0      # all pages returned
         assert eng.pool.free_page_count == eng.pool.num_pages - 1
 
@@ -142,8 +185,8 @@ def test_donated_pool_survives_the_engines_moves(overlap):
     a prefix hit with a copy-on-write page, compacts its slots and its
     pages, and (overlapped) fences retired slots and fetches a block after
     the next one took the state: no buffer is used after its donation,
-    every block carries the pool without a copy, and the streams equal the
-    slot engine's."""
+    every block carries the pool without a copy, and the streams equal
+    those of an engine on 16-token pages without prefix reuse."""
     params = init_params(CFG_TINY, jax.random.PRNGKey(0))
     shared = [11, 12, 13, 14, 15, 16, 17, 18]
     # slots 0 and 2 retire first, leaving holes; the last two requests
@@ -155,7 +198,7 @@ def test_donated_pool_survives_the_engines_moves(overlap):
                     for i, p in enumerate(prompts)]
     with registry.use("xla"):
         want = Engine(params, CFG_TINY, num_slots=4, max_len=MAX_LEN, k=2,
-                      max_prompt=12).run(reqs())
+                      max_prompt=12, page_size=16).run(reqs())
         eng = Engine(params, CFG_TINY, num_slots=4, max_len=MAX_LEN, k=2,
                      max_prompt=12, page_size=4, prefix_cache=True,
                      defrag_threshold=0.25, overlap=overlap)
@@ -311,7 +354,7 @@ def test_exhaustion_with_slot_held_pages_fails_fast_keeping_trie():
     eviction can free nothing — reserve() must raise PageError *without*
     wiping the trie (the old loop destroyed every node on its way to the
     same error, forfeiting all future prefix reuse)."""
-    pool = PagedCachePool(CFG_TINY, 2, 8, page_size=4, num_pages=3)
+    pool = CachePool(CFG_TINY, 2, 8, page_size=4, num_pages=3)
     a = pool.allocate("a")
     pool.reserve(a, 8)
     pool.register_prefix(a, [1, 2, 3, 4, 5, 6, 7, 8], written_len=8)
@@ -330,7 +373,7 @@ def test_paged_pool_refcounts_across_retire_and_defrag():
     """Pages stay alive while any slot table or trie node references them;
     retire drops the slot's reference but keeps published pages resident;
     page defrag permutes pool rows without disturbing what tables see."""
-    pool = PagedCachePool(CFG_TINY, 3, 16, page_size=4)
+    pool = CachePool(CFG_TINY, 3, 16, page_size=4)
     cache = pool.make_cache()
     prompt = [1, 2, 3, 4, 5, 6, 7, 8]
 
@@ -370,7 +413,7 @@ def test_paged_pool_refcounts_across_retire_and_defrag():
     # stamp every page row with its own pool index, then defrag: c's table
     # must still gather exactly the rows it saw before the permutation
     def stamp(leaf, pax):
-        if pax == _NO_BATCH:
+        if pax == _NO_AXIS:
             return leaf
         n = leaf.shape[pax - 1]
         shp = [1] * leaf.ndim
@@ -383,7 +426,7 @@ def test_paged_pool_refcounts_across_retire_and_defrag():
     assert pool.page_fragmentation() == 0.0
     leaves = [(leaf, pax) for leaf, pax in zip(
         jax.tree.leaves(cache), jax.tree.leaves(pool.page_axes))
-        if pax != _NO_BATCH]
+        if pax != _NO_AXIS]
     assert leaves
     for leaf, pax in leaves:
         got = np.asarray(jnp.moveaxis(leaf, pax - 1, 0)).reshape(
@@ -397,7 +440,7 @@ def test_paged_pool_refcounts_across_retire_and_defrag():
 def test_page_pool_exhaustion_evicts_then_raises():
     """When the free heap runs dry, reserve() reclaims trie-only pages via
     LRU eviction; with nothing left to evict it raises PageError."""
-    pool = PagedCachePool(CFG_TINY, 2, 8, page_size=4, num_pages=3)
+    pool = CachePool(CFG_TINY, 2, 8, page_size=4, num_pages=3)
     a = pool.allocate("a")
     pool.reserve(a, 8)                       # both real pages
     pool.register_prefix(a, [1, 2, 3, 4, 5, 6, 7, 8], written_len=8)
@@ -416,7 +459,7 @@ def test_paged_cache_specs_shard_pages():
     pages@dp and page rows@tp exactly where the slot layout sharded
     batch@dp and seq@tp."""
     rules = make_rules(make_host_mesh())        # (data=2, model=4) spoofed
-    pool = PagedCachePool(CFG_TINY, 2, 16, page_size=4, num_pages=16)
+    pool = CachePool(CFG_TINY, 2, 16, page_size=4, num_pages=16)
     specs = cache_specs(pool.make_cache(), rules)
     kv = [(jtu.keystr(path), spec)
           for path, spec in jtu.tree_leaves_with_path(specs)
@@ -479,7 +522,7 @@ def test_scheduler_sheds_expired_under_light_load():
 def test_cachepool_free_heap_keeps_lowest_slot_first():
     """The free list is a heap: allocation after interleaved frees always
     takes the lowest slot index, in O(log n) per op."""
-    pool = CachePool(CFG_TINY, 8, 8)
+    pool = CachePool(CFG_TINY, 8, 8, page_size=4)
     slots = [pool.allocate(f"r{i}") for i in range(8)]
     assert slots == list(range(8))
     order = [6, 1, 4, 3]

@@ -31,7 +31,7 @@ from repro.kernels import registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.models.attention import paged_attention, quantize_kv
-from repro.serve import Engine, PagedCachePool, PageError, Request
+from repro.serve import CachePool, Engine, PageError, Request
 from test_paged import PAGED_CASES, paged_case
 
 MAX_LEN = 32
@@ -95,8 +95,8 @@ def test_paged_attention_quantized_parity(case):
 def test_quantized_pool_layout_and_defaults():
     """int8 codes + f32 scale siblings (parent shape minus head_dim), paged
     axes extended to the scales, doubled num_pages default, cheaper pages."""
-    f = PagedCachePool(CFG_TINY, 3, 16, page_size=4)
-    q = PagedCachePool(CFG_TINY, 3, 16, page_size=4, kv_dtype="int8")
+    f = CachePool(CFG_TINY, 3, 16, page_size=4)
+    q = CachePool(CFG_TINY, 3, 16, page_size=4, kv_dtype="int8")
     assert q.quantized and not f.quantized
     assert q.num_pages == 2 * (f.num_pages - 1) + 1
     assert q.page_bytes() < f.page_bytes()
@@ -116,8 +116,8 @@ def test_quantized_scale_leaves_shard_with_their_pages():
     """k_scale/v_scale take the k/v positional rule shifted one axis left:
     pages@dp, page rows@tp — codes and scales land on the same shard."""
     rules = make_rules(make_host_mesh())
-    pool = PagedCachePool(CFG_TINY, 2, 16, page_size=4, num_pages=16,
-                          kv_dtype="int8")
+    pool = CachePool(CFG_TINY, 2, 16, page_size=4, num_pages=16,
+                     kv_dtype="int8")
     specs = cache_specs(pool.make_cache(), rules)
     got = [(jtu.keystr(path), spec)
            for path, spec in jtu.tree_leaves_with_path(specs)
@@ -130,11 +130,18 @@ def test_quantized_scale_leaves_shard_with_their_pages():
 
 
 def test_kv_dtype_validation():
+    """What the pool and the engine refuse at construction: an unknown
+    ``kv_dtype``, and a page size that is not a whole number of tokens
+    (None, 0, negative) — every engine pages."""
     with pytest.raises(ValueError):
-        PagedCachePool(CFG_TINY, 2, 16, page_size=4, kv_dtype="fp8")
+        CachePool(CFG_TINY, 2, 16, page_size=4, kv_dtype="fp8")
     params = init_params(CFG_TINY, jax.random.PRNGKey(0))
     with pytest.raises(ValueError):
-        Engine(params, CFG_TINY, num_slots=2, max_len=16, kv_dtype="int8")
+        Engine(params, CFG_TINY, num_slots=2, max_len=16, kv_dtype="fp8")
+    for page_size in (None, 0, -4):
+        with pytest.raises(ValueError):
+            Engine(params, CFG_TINY, num_slots=2, max_len=16,
+                   page_size=page_size)
 
 
 # ------------------------------------------------------------ engine parity --
@@ -142,8 +149,8 @@ def test_quantized_engine_greedy_parity(family_setup):
     """Per family: the int8 engine drains the same workload as the f32
     paged engine with >= 75% greedy token agreement at equal stream lengths
     (exact in practice except where quantization noise crosses a logit
-    near-tie — the MoE router). Pure-SSM archs have no pageable leaves and
-    fall back to the unquantized slot pool."""
+    near-tie — the MoE router). Pure-SSM archs have no attention K/V: their
+    pool has no page leaves and nothing to quantize."""
     cfg, params = family_setup
     rng = np.random.RandomState(0)
     encs = [rng.randn(16, cfg.d_model).astype(np.float32)
@@ -159,8 +166,8 @@ def test_quantized_engine_greedy_parity(family_setup):
                            for i, p in enumerate(PROMPTS)])
         toks[dt] = {r.id: r.tokens for r in out}
     if cfg.family == "ssm":
-        assert not eng.paged and not getattr(eng.pool, "quantized", False)
-        assert toks["int8"] == toks["f32"]      # fell back: bit-identical
+        assert not eng.pool.has_paged and not eng.pool.quantized
+        assert toks["int8"] == toks["f32"]      # nothing paged: identical
         return
     assert eng.pool.quantized
     assert eng.pool.live_page_count() == 0
@@ -178,17 +185,17 @@ def test_quantized_pool_doubles_resident_requests_at_matched_bytes():
     pool admits >= 2x as many before PageError. The budget is sized in f32
     pages (2.5 request-spans' worth): page granularity strands the f32
     remainder while the cheaper int8 pages convert it into whole spans."""
-    span_pages = PagedCachePool(CFG_TINY, 1, MAX_LEN, page_size=4) \
+    span_pages = CachePool(CFG_TINY, 1, MAX_LEN, page_size=4) \
         .pages_per_slot
-    probe = PagedCachePool(CFG_TINY, 1, MAX_LEN, page_size=4)
-    probe_q = PagedCachePool(CFG_TINY, 1, MAX_LEN, page_size=4,
-                             kv_dtype="int8")
+    probe = CachePool(CFG_TINY, 1, MAX_LEN, page_size=4)
+    probe_q = CachePool(CFG_TINY, 1, MAX_LEN, page_size=4,
+                        kv_dtype="int8")
     budget = int(2.5 * span_pages) * probe.page_bytes()
 
     def resident(kv_dtype, page_bytes):
-        pool = PagedCachePool(CFG_TINY, 16, MAX_LEN, page_size=4,
-                              kv_dtype=kv_dtype,
-                              num_pages=1 + budget // page_bytes)
+        pool = CachePool(CFG_TINY, 16, MAX_LEN, page_size=4,
+                         kv_dtype=kv_dtype,
+                         num_pages=1 + budget // page_bytes)
         count = 0
         try:
             while True:

@@ -1,13 +1,15 @@
 """repro.serve: k-step fused decode parity, cache-pool invariants, admission.
 
 The load-bearing claim is token parity: the continuous-batching engine —
-per-slot positions, interleaved prefill, slot reuse, defrag — must produce
-exactly the tokens of the classical one-request-at-a-time per-token loop
-(greedy argmax, same params), for an attention arch and an SSM arch, at
-every k. The pool property test drives seeded random allocate/free/defrag
-sequences against a real cache and checks no slot is ever double-assigned
-and defrag never disturbs live contents.
+per-slot positions through page tables, interleaved prefill, slot reuse,
+defrag — must produce exactly the tokens of the classical
+one-request-at-a-time per-token loop (greedy argmax, same params, the
+model's plain decode over ``init_cache``), for an attention arch and an SSM
+arch, at every k. The pool property test drives seeded random
+allocate/free/defrag sequences against a real cache and checks no slot is
+ever double-assigned and defrag never disturbs live contents or pages.
 """
+import functools
 import random
 
 import jax
@@ -18,8 +20,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_arch, smoke_config
 from repro.dist import DeadlineGate
+from repro.kernels import registry
 from repro.launch.steps import make_serve_step
 from repro.models import init_params, init_cache, decode_step
+from repro.models.transformer import prefill_audio_cache
 from repro.serve import (Engine, Request, CachePool, SamplingParams,
                          Scheduler, SlotError,
                          FINISH_ERROR, FINISH_LENGTH, FINISH_SHED)
@@ -39,10 +43,27 @@ def arch_setup(request):
     return cfg, params
 
 
-def _classic_tokens(cfg, params, prompt, n_new):
+def _plain_cache(cfg, params, enc_embeds=None):
+    """A one-request cache of the model's plain decode (``init_cache``);
+    whisper's holds the cross K/V of ``enc_embeds``."""
+    if cfg.family != "audio":
+        return init_cache(cfg, 1, MAX_LEN)
+    cache = init_cache(cfg, 1, MAX_LEN, enc_len=len(enc_embeds))
+    return jax.jit(lambda p, c, e: prefill_audio_cache(p, cfg, c, e))(
+        params, cache, jnp.asarray(enc_embeds)[None].astype(jnp.bfloat16))
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_step(cfg, backend):
+    """The model's plain serve step, jitted once per arch and backend."""
+    with registry.use(backend):
+        return jax.jit(make_serve_step(cfg, None))
+
+
+def _classic_tokens(cfg, params, prompt, n_new, enc_embeds=None):
     """Reference: whole-prompt then per-token decode, one request, B=1."""
-    step = jax.jit(make_serve_step(cfg, None))
-    cache = init_cache(cfg, 1, MAX_LEN)
+    step = _plain_step(cfg, registry.resolved_backend())
+    cache = _plain_cache(cfg, params, enc_embeds)
     tok = None
     for t in prompt:
         tok, _, cache = step(params, cache, jnp.array([[t]], jnp.int32))
@@ -55,15 +76,24 @@ def _classic_tokens(cfg, params, prompt, n_new):
 
 # ------------------------------------------------------------------ parity --
 def test_vector_positions_match_scalar_ulp(arch_setup):
-    """decode_step with per-slot positions == scalar-pos path, bit for bit
-    (the fused block is built from the vector path; the classic loop from the
-    scalar path — ulp-identity here is what makes token parity exact)."""
+    """decode_step with per-slot positions through a page table (odd page
+    size 5, rows on scattered pages) == the scalar-pos path over slot rows,
+    bit for bit (the fused block is built from the paged path; the classic
+    loop from the scalar path — ulp-identity here is what makes token
+    parity exact). The SSM has no attention: no pages, no table."""
     cfg, params = arch_setup
     B = 3
-    c1, c2 = init_cache(cfg, B, MAX_LEN), init_cache(cfg, B, MAX_LEN)
+    pool = CachePool(cfg, B, MAX_LEN, page_size=5)
+    for i in range(B):
+        pool.allocate(f"r{i}")
+    for i in reversed(range(B)):                # rows on interleaved pages
+        pool.reserve(i, MAX_LEN)
+    table = jnp.asarray(pool.tables) if pool.has_paged else None
+    c1, c2 = init_cache(cfg, B, MAX_LEN), pool.make_cache()
     f1 = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t))
     f2 = jax.jit(lambda p, c, t, pos: decode_step(p, cfg, c, t,
-                                                  positions=pos))
+                                                  positions=pos,
+                                                  page_table=table))
     tok = jnp.array([[5], [7], [9]], jnp.int32)
     for step in range(3):
         l1, c1 = f1(params, c1, tok)
@@ -76,8 +106,9 @@ def test_vector_positions_match_scalar_ulp(arch_setup):
                          ids=["no-params", "default-params"])
 @pytest.mark.parametrize("k", [1, 4])
 def test_engine_matches_classic_loop(arch_setup, k, sampling):
-    """Continuous batching (5 ragged requests over 3 slots: admission waves,
-    slot reuse, defrag) is token-identical to the isolated per-token loop —
+    """Continuous batching (5 ragged requests over 3 slots of the default
+    paged pool: admission waves, slot reuse, defrag) is token-identical to
+    the isolated per-token loop —
     both without sampling params and with the default ``SamplingParams()``
     (the greedy fast path must be bit-identical to the pre-sampling argmax
     engine, for an attention arch and an SSM arch)."""
@@ -123,16 +154,23 @@ def _slot_values(pool, cache, slot):
         if v is not None]
 
 
+#: slot-row recurrent state beside paged shared attention
+CFG_HYBRID = smoke_config(get_arch("zamba2-2.7b"))
+
+
 @given(st.integers(0, 2 ** 31 - 1))
 def test_pool_allocate_free_defrag_invariants(seed):
-    """Seeded random op sequences: a slot is never double-assigned, frees
-    only release owned slots, and defrag relocates live rows — including the
-    per-slot request PRNG key — losslessly."""
+    """Seeded random op sequences on a pool with both layouts (zamba2's
+    slot-row mamba state and paged attention K/V): a slot is never
+    double-assigned, frees only release owned slots and their pages, and
+    defrag relocates live rows — the per-slot request PRNG key and the page
+    table included — losslessly."""
     rng = random.Random(seed)
-    pool = CachePool(CFG_TINY, 4, 8)
+    pool = CachePool(CFG_HYBRID, 4, 8, page_size=2)
     cache = pool.make_cache()
     owned = {}          # slot -> stamp value
     rng_seeds = {}      # slot -> seed bound via seed_slot
+    tables = {}         # slot -> its page table row
     stamp = 0
     for _ in range(20):
         op = rng.random()
@@ -145,19 +183,26 @@ def test_pool_allocate_free_defrag_invariants(seed):
             owned[slot] = stamp % 100
             pool.seed_slot(slot, stamp)
             rng_seeds[slot] = stamp
+            pool.reserve(slot, 1 + stamp % 8)
+            tables[slot] = pool.tables[slot].copy()
         elif op < 0.8 and owned:
             slot = rng.choice(sorted(owned))
             pool.free(slot)
             del owned[slot]
             del rng_seeds[slot]
+            del tables[slot]
         elif owned:
             cache, perm, mapping = pool.defrag(cache)
             assert sorted(mapping) == sorted(owned)
             owned = {mapping[s]: v for s, v in owned.items()}
             rng_seeds = {mapping[s]: v for s, v in rng_seeds.items()}
+            tables = {mapping[s]: t for s, t in tables.items()}
             # live slots are compacted to the front, in order
             assert pool.live_slots() == list(range(len(owned)))
         assert len(pool.live_slots()) + pool.free_count == pool.num_slots
+        # every live page is held by exactly one slot's table
+        held = np.concatenate([t[t > 0] for t in tables.values()] + [[]])
+        assert pool.live_page_count() == len(held) == len(set(held))
     for slot, value in owned.items():
         for leaf in _slot_values(pool, cache, slot):
             np.testing.assert_array_equal(
@@ -171,10 +216,14 @@ def test_pool_allocate_free_defrag_invariants(seed):
     for slot in range(pool.num_slots):
         if slot not in rng_seeds:
             np.testing.assert_array_equal(pool.slot_keys[slot], 0)
+            np.testing.assert_array_equal(pool.tables[slot], 0)
+        else:
+            np.testing.assert_array_equal(pool.tables[slot], tables[slot],
+                                          err_msg=f"slot {slot} pages lost")
 
 
 def test_pool_exhaustion_and_double_free_raise():
-    pool = CachePool(CFG_TINY, 2, 8)
+    pool = CachePool(CFG_TINY, 2, 8, page_size=4)
     a, b = pool.allocate("a"), pool.allocate("b")
     assert a != b
     with pytest.raises(SlotError):
@@ -238,7 +287,8 @@ def test_engine_sheds_via_gate():
 
 # ----------------------------------------------------------------- families --
 def test_engine_whisper_encdec():
-    """Enc-dec family: per-request cross-K/V prefill into the slot pool."""
+    """Enc-dec family: per-request cross-K/V prefill into the pool's slot
+    rows, decoder self-attention K/V in pages."""
     cfg = smoke_config(get_arch("whisper-medium"))
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(params, cfg, num_slots=2, max_len=16, k=4, enc_len=16)
@@ -292,11 +342,13 @@ def test_decode_block_retires_unservable_prompt():
     from repro.serve.decode import init_decode_state, make_decode_block
     params = init_params(CFG_TINY, jax.random.PRNGKey(0))
     block = make_decode_block(CFG_TINY, None, k=2, max_len=8)
-    state = init_decode_state(init_cache(CFG_TINY, 2, 8), 2)
+    pool = CachePool(CFG_TINY, 2, 8, page_size=4)
+    state = init_decode_state(pool.make_cache(), 2)
     prompts = jnp.zeros((2, 4), jnp.int32)
     prompt_len = jnp.asarray([30, 2], jnp.int32)   # slot 0 can never emit
     max_new = jnp.asarray([4, 4], jnp.int32)
     active = jnp.asarray([True, True])
-    state, out = block(params, state, prompts, prompt_len, max_new, active)
+    state, out = block(params, state, prompts, prompt_len, max_new, active,
+                       None, jnp.asarray(pool.tables))
     assert bool(state.done[0]) and not np.asarray(out.emitted)[:, 0].any()
     assert not bool(state.done[1])                 # healthy slot unaffected
